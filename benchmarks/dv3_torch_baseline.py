@@ -12,8 +12,8 @@ every 16 policy steps. The env is a synthetic 64x64x3 pixel source so both
 sides of the comparison step identical data.
 
 Run: ``python benchmarks/dv3_torch_baseline.py [total_steps]`` — prints
-env-steps/sec. The measured number on this host is recorded in BASELINE.md
-and consumed by bench.py as ``vs_baseline``.
+env-steps/sec. bench.py holds the number measured with it (``_DV3_TORCH_CPU_SPS``,
+with the command and date) and uses it as ``vs_baseline``.
 """
 
 from __future__ import annotations
@@ -36,7 +36,7 @@ CNN_MULT = 2
 SEQ_LEN = 64
 BATCH = 16
 HORIZON = 15
-REPLAY_RATIO = 0.5  # north-star walker-walk recipe (BASELINE.md)
+REPLAY_RATIO = 0.5  # north-star walker-walk recipe (BASELINE.json)
 ACTIONS = 6
 
 

@@ -1,9 +1,9 @@
 #!/bin/sh
-# Reward-trend learning checks recorded in BASELINE.md ("Learning checks —
-# round 3"). Each run prints per-episode rewards ("Rank-0: ... reward_env_N=R")
-# at metric.log_level=1; compare the first fifth of episodes to the last.
-# CPU runs force JAX_PLATFORMS=cpu; drop it to run on an attached accelerator
-# (the Dreamer rows in BASELINE.md were measured on the real TPU chip).
+# Reward-trend learning checks. Each run prints per-episode rewards
+# ("Rank-0: ... reward_env_N=R") at metric.log_level=1; compare the first fifth
+# of episodes to the last. The figures in the comments are from CPU runs of
+# earlier rounds; none has been repeated on the current code.
+# CPU runs force JAX_PLATFORMS=cpu; drop it to run on an attached accelerator.
 set -e
 LOGS=${LOGS:-/tmp/sheeprl_tpu_learning}
 
@@ -20,7 +20,7 @@ JAX_PLATFORMS=cpu python -m sheeprl_tpu fabric=cpu exp=droq env=gym env.id=Pendu
     checkpoint.save_last=False metric.log_level=1 metric.log_every=50000 \
     log_base_dir=$LOGS/droq
 
-# Plain SAC, Pendulum (CPU, ~15 min) — round-5 row, see BASELINE.md
+# Plain SAC, Pendulum (CPU, ~15 min)
 JAX_PLATFORMS=cpu python -m sheeprl_tpu fabric=cpu exp=sac env=gym env.id=Pendulum-v1 \
     env.num_envs=4 env.capture_video=False buffer.memmap=False \
     algo.total_steps=12000 algo.learning_starts=400 algo.run_test=False \

@@ -197,10 +197,10 @@ def _spawn_worker(code, argv, extra_env, device_count, timeout):
     env.pop("SHEEPRL_TPU_PROCESS_ID", None)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={device_count}"
-    # an inherited persistent trace cache is topology-poisoned across
-    # process-group sizes (see Fabric._configure_compilation_cache) —
-    # drop it rather than risk a single-process executable in the p2 group
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # the persistent trace cache is topology-poisoned across process-group
+    # sizes (see fabric.configure_compilation_cache) — switch it off rather
+    # than risk a single-process executable in the p2 group
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = os.pathsep.join(p for p in (REPO_ROOT, env.get("PYTHONPATH")) if p)
     env.update(extra_env)
     return subprocess.Popen(

@@ -1,6 +1,6 @@
-"""Measure the irreducible env-stepping floor of the PPO bench workload
-(VERDICT round-3 item 3): what does bare ``gym.vector`` CartPole stepping
-cost on this host, with zero learning on top?
+"""Measure the irreducible env-stepping floor of the PPO bench workload:
+what does bare ``gym.vector`` CartPole stepping cost on this host, with zero
+learning on top?
 
 Stages, each timed over ``--steps`` env steps (env-steps/s):
 

@@ -1,5 +1,4 @@
-"""Measured torch baseline for the PPO benchmark workload (VERDICT round-2
-item 6: the PPO bench number had no ratio).
+"""Measured torch baseline for the PPO benchmark workload.
 
 The reference framework cannot run in this image (lightning/hydra are not
 installed), so this standalone torch script reproduces the COMPUTE of the
@@ -10,8 +9,8 @@ workload shape bench.py drives through the CLI: 64 sync envs, rollout 128,
 with actor/critic heads, GAE(0.99, 0.95), clip 0.2, vf 1.0.
 
 Run: ``python benchmarks/ppo_torch_baseline.py [total_steps]`` — prints
-env-steps/sec. The measured number on this host is recorded in BASELINE.md
-and consumed by bench.py as the PPO ``vs_baseline``.
+env-steps/sec. bench.py holds the number measured with it (``_PPO_TORCH_CPU_SPS``,
+with the command and date) and uses it as the PPO ``vs_baseline``.
 """
 
 from __future__ import annotations

@@ -187,10 +187,10 @@ def main(fabric, cfg: Dict[str, Any]):
     if cfg.algo.max_grad_norm and float(cfg.algo.max_grad_norm) > 0:
         opt_cfg["max_grad_norm"] = float(cfg.algo.max_grad_norm)
     tx = instantiate(opt_cfg)
-    # remote-chip escape hatch (same as plain PPO): a tiny model's update
-    # runs on the host core so nothing in the A2C loop touches the link —
-    # the single-device train program has no mesh collectives, so committing
-    # params/opt/batch to the host is all it takes
+    # host-train escape hatch (same as plain PPO, resolve_train_device): when
+    # the dispatch round trip is above 5 ms a tiny model's update runs on the
+    # host core — the single-device train program has no mesh collectives, so
+    # committing params/opt/batch to the host is all it takes
     train_device = resolve_train_device(
         cfg.algo.get("train_device", "auto"), params, fabric.world_size
     )
@@ -475,9 +475,9 @@ def main(fabric, cfg: Dict[str, Any]):
 
             local_data = buf.arrays()
             next_values = np.asarray(player.get_values(next_obs))
-            # GAE on the player's device (host when the chip is remote-attached):
-            # rollout arrays are already host-side, so the advantage pass never
-            # pays a link round trip (same routing as plain PPO)
+            # GAE on the player's device (the host when the player is pinned
+            # there): rollout arrays are already host-side, so the advantage
+            # pass never pays a device round trip (same routing as plain PPO)
             returns, advantages = gae_fn(
                 put_tree(local_data["rewards"], player.device),
                 put_tree(local_data["values"], player.device),

@@ -622,7 +622,7 @@ class PlayerDV2(HostPlayerParams):
         self.num_envs = num_envs
         self.expl_rng = np.random.default_rng(seed)
         # recurrent state lives on device between steps (one less host round
-        # trip per env step on a remote-attached chip); exploration noise is
+        # trip per env step); exploration noise is
         # host-side, so the action still crosses to host every step
         self.h: Optional[Any] = None
         self.z: Optional[Any] = None

@@ -303,8 +303,7 @@ def make_train_fn(
     # donate only optimizer/aux state: param buffers stay un-donated because
     # concurrent readers (async param streaming to the host player, the ema /
     # hard-copy target refresh) may still be in flight when the next train
-    # dispatch would otherwise alias over them (observed on the remote chip
-    # as spurious INVALID_ARGUMENT errors surfacing at unrelated fetches)
+    # dispatch would otherwise alias over them
     return jax.jit(train_fn, donate_argnums=(4, 5, 6))
 
 

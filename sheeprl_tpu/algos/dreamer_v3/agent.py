@@ -388,15 +388,13 @@ class WorldModel(nn.Module):
                 dtype=self.dtype,
             )
         gru_in_dim = self.stoch_state_size + int(sum(self.actions_dim))
-        use_pallas, interpret = resolve_backend(
+        # the RSSM params are stored fp32 under every precision policy
+        # (_DenseParams), which is the dtype resolve_backend sizes by default
+        if resolve_backend(
             self.fused_recurrent, gru_in_dim, self.recurrent_dense_units, self.recurrent_state_size
-        )
-        if use_pallas:
+        ):
             self.recurrent_model = FusedRecurrentModel(
-                self.recurrent_state_size,
-                self.recurrent_dense_units,
-                dtype=self.dtype,
-                interpret=interpret,
+                self.recurrent_state_size, self.recurrent_dense_units, dtype=self.dtype
             )
         else:
             self.recurrent_model = RecurrentModel(
@@ -734,16 +732,17 @@ class PlayerDV3(HostPlayerParams):
     agent.py:596-691): keeps (h, z, prev_action) per env and advances them
     with one jitted observe+act step.
 
-    The recurrent state lives ON DEVICE between steps — with a
-    remote-attached chip, pulling (h, z) to host every step doubles the
-    per-step round trips; only the action is downloaded. Per-env resets are
-    a jitted masked blend instead of host-side indexing.
+    The recurrent state lives ON DEVICE between steps — pulling (h, z) to
+    host every step would double the per-step round trips; only the action
+    is downloaded. Per-env resets are a jitted masked blend instead of
+    host-side indexing.
 
     ``device`` (see ``parallel.fabric.resolve_player_device``) optionally
     pins the player to the host CPU backend: the observe+act step then runs
-    host-side with zero chip round trips per env step, and ``update_params``
-    streams fresh learner params chip→host once per train block — the
-    learner-on-chip/actor-on-host split for remote-attached chips."""
+    host-side with no accelerator round trip per env step, and
+    ``update_params`` streams fresh learner params accelerator→host once per
+    train block — the learner-on-accelerator/actor-on-host split taken when
+    the dispatch round trip measures above 5 ms."""
 
     _placed_attrs = ("wm_params", "actor_params")
 
@@ -804,7 +803,7 @@ class PlayerDV3(HostPlayerParams):
         (``fabric.HostPlayerParams.stream_attr``): the call returns
         immediately and the player flips to the new params a train block or
         two later, once the async device→host copy lands — the env loop
-        never stalls on the link."""
+        never stalls on the transfer."""
         self.stream_attr("wm_params", wm_params)
         self.stream_attr("actor_params", actor_params)
 

@@ -465,8 +465,7 @@ def make_train_fn(
     # donate only optimizer/aux state: param buffers stay un-donated because
     # concurrent readers (async param streaming to the host player, the ema /
     # hard-copy target refresh) may still be in flight when the next train
-    # dispatch would otherwise alias over them (observed on the remote chip
-    # as spurious INVALID_ARGUMENT errors surfacing at unrelated fetches)
+    # dispatch would otherwise alias over them
     return jax.jit(train_fn, donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
 
 
@@ -581,6 +580,10 @@ def main(fabric, cfg: Dict[str, Any]):
         moments_task = MomentsState(
             low=jnp.asarray(state["moments_task"]["low"]), high=jnp.asarray(state["moments_task"]["high"])
         )
+    # committed to the mesh like the rest of the train state, or window 2
+    # compiles a SECOND executable of the whole step (see dreamer_v3.main)
+    moments_task = fabric.replicate(moments_task)
+    moments_expl = {k: fabric.replicate(m) for k, m in moments_expl.items()}
 
     if fabric.is_global_zero:
         save_configs(cfg, log_dir)

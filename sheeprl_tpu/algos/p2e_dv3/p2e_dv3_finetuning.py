@@ -136,6 +136,9 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
         moments_state = MomentsState(
             low=jnp.asarray(state["moments_task"]["low"]), high=jnp.asarray(state["moments_task"]["high"])
         )
+    # committed to the mesh like the rest of the train state, or window 2
+    # compiles a SECOND executable of the whole step (see dreamer_v3.main)
+    moments_state = fabric.replicate(moments_state)
 
     if fabric.is_global_zero:
         save_configs(cfg, log_dir)

@@ -215,7 +215,7 @@ class PPOPlayer(HostPlayerParams):
     jitted action/value functions (reference PPOPlayer, agent.py:194-251).
 
     ``device`` optionally pins inference to the host CPU backend so env
-    stepping never waits on a remote-chip round trip; ``update_params``
+    stepping never waits on an accelerator round trip; ``update_params``
     streams learner params across (see ``parallel.fabric.resolve_player_device``)."""
 
     _placed_attrs = ("params",)

@@ -137,7 +137,7 @@ class SACPlayer(HostPlayerParams):
     """Rollout/eval policy handle (reference SACPlayer, agent.py:270-314).
 
     ``device`` optionally pins inference to the host CPU backend
-    (learner-on-chip/actor-on-host for remote-attached chips; see
+    (learner-on-accelerator/actor-on-host; see
     ``parallel.fabric.resolve_player_device``)."""
 
     _placed_attrs = ("params",)

@@ -195,12 +195,12 @@ def _player(fabric, cfg, state=None):
         # sample the trainers' batches from the player-owned buffer
         # (reference :303-330)
         data = None
-        # NOTE (round-4 item): this path still ships per_rank_gradient_steps
+        # NOTE: this path still ships per_rank_gradient_steps
         # in ONE [G, B, ...] block — the trainer's fused scan recompiles per
         # distinct G and the first post-warmup G repays the whole warmup debt
         # (see utils.gradient_step_chunks, applied to the coupled loops);
         # chunking here needs a protocol change (multiple data broadcasts
-        # per update), so keep learning_starts small on remote chips.
+        # per update), so keep learning_starts small where compiles are slow.
         if update >= learning_starts:
             per_rank_gradient_steps = ratio(policy_step)
             if per_rank_gradient_steps > 0:

@@ -258,6 +258,9 @@ def run_algorithm(cfg: dotdict) -> None:
             elif algo_cfg.get("overlap_collection"):
                 variant = "overlap_collection"
         extra = {"variant": variant} if variant else {}
+        # what fabric.accelerator asked for and what the devices are: `auto`
+        # takes what is there, and the record says what that was
+        extra["accelerator"] = {"requested": fabric.accelerator, "platform": fabric.platform}
         register_run(cfg, kind="train", outcome=outcome, error=error, **extra)
         shutdown_telemetry()
 

@@ -6,9 +6,9 @@ accelerator: at replay ratio 0.5 each stored frame crosses the host→device
 link ~16 times over its lifetime (batch 16 × seq 64 resamples). On TPU the
 natural layout is the opposite — the ring lives in HBM, each env step uploads
 its ~KB-sized transition exactly once, and sequence sampling is an on-chip
-gather (HBM→HBM at memory bandwidth, no host link traffic at all). With a
-remote-attached chip this turns the dominant per-update transfer
-(megabytes of pixels) into a few kilobytes of gather indices.
+gather (HBM→HBM at memory bandwidth, no host link traffic at all): the
+dominant per-update transfer (megabytes of pixels) becomes a few kilobytes
+of gather indices.
 
 Semantics mirror ``EnvIndependentReplayBuffer(buffer_cls=SequentialReplayBuffer)``
 (per-env ring cursors, contiguous windows that never straddle an env's write
@@ -277,6 +277,13 @@ class DeviceReplayBuffer:
     @property
     def device(self) -> Optional[jax.Device]:
         return self._device
+
+    def devices(self) -> List[str]:
+        """``platform:id`` of every device holding a piece of the ring
+        (empty until the first ``add`` allocates it)."""
+        from sheeprl_tpu.parallel.fabric import tree_devices
+
+        return tree_devices(self._bufs)
 
     @property
     def sharded(self) -> bool:
@@ -980,6 +987,8 @@ def resolve_device_buffer(
     sharded ring that budget is per the whole mesh — each device holds
     ``1/data_parallel_size`` of it).
     """
+    from sheeprl_tpu.obs.telemetry import telemetry_resolved
+
     spec = cfg.buffer.get("device", "auto")
     unsupported_reason = None
     if fabric.num_processes != 1:
@@ -999,19 +1008,25 @@ def resolve_device_buffer(
     if spec in (True, "true", "True"):
         if unsupported_reason is not None:
             raise ValueError(f"buffer.device=true is impossible here: {unsupported_reason}")
-        return True
-    if spec in (False, "false", "False", None):
-        return False
-    if spec != "auto":
+        on_device, why = True, "forced"
+    elif spec in (False, "false", "False", None):
+        on_device, why = False, "forced"
+    elif spec != "auto":
         raise ValueError(f"unknown buffer.device spec {spec!r}; use auto/true/false")
-    if unsupported_reason is not None or jax.default_backend() == "cpu":
-        return False
-    est = (
-        estimated_bytes
-        if estimated_bytes is not None
-        else estimate_ring_bytes(obs_space, actions_dim, buffer_size, n_envs)
-    )
-    return est <= int(cfg.buffer.get("device_max_bytes", 8_000_000_000))
+    elif unsupported_reason is not None:
+        on_device, why = False, unsupported_reason
+    elif jax.default_backend() == "cpu":
+        on_device, why = False, "the default backend is the host"
+    else:
+        est = (
+            estimated_bytes
+            if estimated_bytes is not None
+            else estimate_ring_bytes(obs_space, actions_dim, buffer_size, n_envs)
+        )
+        budget = int(cfg.buffer.get("device_max_bytes", 8_000_000_000))
+        on_device, why = est <= budget, f"estimated {est} bytes against buffer.device_max_bytes={budget}"
+    telemetry_resolved("buffer_device", "device" if on_device else "host", spec=str(spec), why=why)
+    return on_device
 
 
 def _mesh_kwargs(fabric: Any) -> Dict[str, Any]:

@@ -6,9 +6,12 @@ holds the *host-side* native pieces — currently the fused replay-buffer
 gather (`gather.cpp`) that feeds the host→HBM pipeline.
 
 Build model: no pybind11/pip in this image, so the shared object is compiled
-lazily with g++ the first time it's needed and cached next to a content hash
-(rebuilds only when the source changes). Everything degrades gracefully: if
-there is no compiler or the build fails, callers fall back to numpy.
+lazily with g++ from the committed ``gather.cpp`` the first time it's needed
+and cached under a content hash (rebuilds only when the source changes) in
+``SHEEPRL_TPU_NATIVE_CACHE`` when that is set, else in one fixed git-ignored
+directory inside the checkout. If there is no compiler or the build or load
+fails, callers fall back to numpy — and :func:`status` (folded into every run
+record as ``native_gather``) says which of the two ran and why.
 """
 
 from __future__ import annotations
@@ -17,7 +20,6 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 from typing import Optional
 
@@ -27,19 +29,24 @@ _SRC = os.path.join(os.path.dirname(__file__), "gather.cpp")
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+_WHY_NUMPY = ""  # set when a load attempt ended on the numpy path
+
+#: the .so directory when SHEEPRL_TPU_NATIVE_CACHE does not place it
+REPO_NATIVE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), ".native_cache"
+)
 
 DEFAULT_THREADS = min(8, os.cpu_count() or 1)
 
 
 def _build_dir() -> str:
-    d = os.environ.get("SHEEPRL_TPU_NATIVE_CACHE") or os.path.join(
-        tempfile.gettempdir(), "sheeprl_tpu_native"
-    )
+    d = os.environ.get("SHEEPRL_TPU_NATIVE_CACHE") or REPO_NATIVE_CACHE_DIR
     os.makedirs(d, exist_ok=True)
     return d
 
 
 def _compile() -> Optional[str]:
+    global _WHY_NUMPY
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
     so_path = os.path.join(_build_dir(), f"gather_{digest}.so")
@@ -63,7 +70,9 @@ def _compile() -> Optional[str]:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp_path, so_path)
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as err:
+        detail = getattr(err, "stderr", b"") or b""
+        _WHY_NUMPY = f"g++ build failed: {err!r} {detail[-300:].decode(errors='replace')}".strip()
         return None
     finally:
         if os.path.exists(tmp_path):
@@ -75,7 +84,7 @@ def _compile() -> Optional[str]:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _WHY_NUMPY
     if _LIB is not None or _TRIED:
         return _LIB
     with _LOCK:
@@ -83,13 +92,15 @@ def _load() -> Optional[ctypes.CDLL]:
             return _LIB
         _TRIED = True
         if os.environ.get("SHEEPRL_TPU_DISABLE_NATIVE"):
+            _WHY_NUMPY = "SHEEPRL_TPU_DISABLE_NATIVE is set"
             return None
         so_path = _compile()
         if so_path is None:
             return None
         try:
             lib = ctypes.CDLL(so_path)
-        except OSError:
+        except OSError as err:
+            _WHY_NUMPY = f"loading {so_path} failed: {err!r}"
             return None
         i64p = ctypes.POINTER(ctypes.c_int64)
         lib.gather_sequences.restype = ctypes.c_int
@@ -127,6 +138,16 @@ def _load() -> Optional[ctypes.CDLL]:
 def available() -> bool:
     """True when the native gather library is (or can be) loaded."""
     return _load() is not None
+
+
+def status() -> str:
+    """Which gather path this process runs: ``native`` (the g++ build is
+    loaded), ``numpy (<why>)`` (a load was attempted and failed — callers are
+    on the numpy fallback), or ``not loaded`` (nothing asked for a host
+    gather yet, e.g. the replay ring lives on the device)."""
+    if _LIB is not None:
+        return "native"
+    return f"numpy ({_WHY_NUMPY})" if _TRIED else "not loaded"
 
 
 def gather_sequences(

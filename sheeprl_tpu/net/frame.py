@@ -15,7 +15,7 @@ Wire layout of one frame::
 
 The decoder is an incremental state machine over a byte buffer, so it is
 indifferent to how the kernel chops the stream (partial reads are the normal
-case, not an error path). Failure taxonomy:
+case, not an error path). Failure classes:
 
 - **short buffer** — not an error; bytes stay buffered until the rest lands.
 - **corrupt payload** (magic + length intact, CRC mismatch) — the frame is

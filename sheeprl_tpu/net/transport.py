@@ -395,7 +395,7 @@ class TcpLearnerTransport(LearnerTransport):
         hdr = np.frombuffer(payload, dtype=np.int64, count=HEADER_WORDS)
         if int(hdr[CHECKSUM]) != _checksum(hdr[SEQ:CHECKSUM]):
             # frame CRC passed but the slab header mix did not: stale or
-            # recycled meta — the ring's torn taxonomy, over the wire
+            # recycled meta — the ring's torn-slab classes, over the wire
             self.torn_detected += 1
             self.stats.checksum_rejects += 1
             tid = int(hdr[TRACE_ID])

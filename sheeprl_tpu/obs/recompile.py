@@ -30,7 +30,7 @@ from typing import Any, Dict, Optional
 _LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 # persistent-compilation-cache outcomes (plain events, no duration): one per
-# backend-compile request when ``jax_compilation_cache_dir`` is configured
+# backend-compile request while the persistent cache is on
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
@@ -115,18 +115,10 @@ class CompileWatchdog:
         if not self._started:
             return
         self._started = False
-        try:
-            from jax._src import monitoring as _mon  # no public unregister API
+        import jax
 
-            _mon._unregister_event_duration_listener_by_callback(self._on_event)
-        except Exception:
-            pass
-        try:
-            from jax._src import monitoring as _mon
-
-            _mon._unregister_event_listener_by_callback(self._on_plain_event)
-        except Exception:
-            pass
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
+        jax.monitoring.unregister_event_listener(self._on_plain_event)
         self._logger.removeHandler(self._handler)
         if self._saved_level is not None:
             self._logger.setLevel(self._saved_level)
@@ -136,7 +128,11 @@ class CompileWatchdog:
             self._saved_propagate = None
 
     def mark_warm(self) -> None:
-        self.warm = True
+        if not self.warm:
+            self.warm = True
+            # one event at the flip, so a reader of the stream can tell "no
+            # recompile" from "the warm point was never reached"
+            self._emit("warm", compiles=self.compiles)
 
     @contextmanager
     def deliberate(self, reason: str):
@@ -169,7 +165,7 @@ class CompileWatchdog:
     def _on_plain_event(self, event: str, **kwargs: Any) -> None:
         """Persistent-compilation-cache outcome: one ``compile_cache`` event
         per backend-compile request, so a resumed run can show its retraces
-        were served from ``fabric.compilation_cache_dir``."""
+        were served from the persistent compilation cache."""
         if event == _CACHE_HIT_EVENT:
             self.cache_hits += 1
             hit = True

@@ -44,6 +44,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Mapping, Optional
 
+from sheeprl_tpu.native import status as native_status
 from sheeprl_tpu.obs.profile import TriggeredProfiler
 from sheeprl_tpu.obs.recompile import CompileWatchdog
 
@@ -202,6 +203,9 @@ class RunTelemetry:
         self._total_masked_slots = 0
         # why fused supersteps fell back to per-step dispatch (reason -> count)
         self._fused_fallbacks: Dict[str, int] = {}
+        # what each `auto` (player/train/buffer placement) resolved to on this
+        # machine, and from which measurement (name -> {value, ...})
+        self._resolved: Dict[str, Dict[str, Any]] = {}
         # actor-learner accounting (sheeprl_tpu.actor_learner): staleness-
         # bounded slab admission (histogram keyed by staleness-in-updates),
         # dropped-stale/torn counters, ring occupancy samples, per-actor
@@ -405,6 +409,14 @@ class RunTelemetry:
         self._fused_fallbacks[reason] = self._fused_fallbacks.get(reason, 0) + 1
         self.emit("fused_fallback", reason=reason, detail=detail, **fields)
         self.writer.flush()
+
+    def record_resolved(self, name: str, value: Any, **fields: Any) -> None:
+        """A placement choice the program made from what it observed
+        (``algo.player_device``, ``algo.train_device``, ``buffer.device``):
+        one ``resolved`` event, and the last outcome per name in the run
+        record's ``resolved`` section."""
+        self._resolved[name] = {"value": value, **fields}
+        self.emit("resolved", name=name, value=value, **fields)
 
     def record_ckpt_commit(self, path: str, step: int, backend: str, emergency: bool = False, **fields: Any) -> None:
         """A checkpoint committed (manifest landed): one ``ckpt_committed``
@@ -712,9 +724,9 @@ class RunTelemetry:
             devices.append(entry)
         fields: Dict[str, Any] = {"devices": devices}
         if self.poll_rtt and self._jax.default_backend() != "cpu":
-            # Link-health probe for remote-attached chips. It is a real sync
-            # point, so it is opt-in (metric.telemetry.poll_rtt) and rides the
-            # same low-rate schedule as the memory poll.
+            # Dispatch round-trip probe. It is a real sync point, so it is
+            # opt-in (metric.telemetry.poll_rtt) and rides the same low-rate
+            # schedule as the memory poll.
             try:
                 from sheeprl_tpu.utils.profiler import tiny_op_rtt_seconds
 
@@ -909,6 +921,10 @@ class RunTelemetry:
             "train_dispatches": self._total_train_dispatches,
             "train_gradient_steps": self._total_train_gradient_steps,
             "fused_fallbacks": dict(self._fused_fallbacks),
+            "resolved": {k: dict(v) for k, v in self._resolved.items()},
+            "native_gather": native_status(),
+            "compile_cache_hits": self.watchdog.cache_hits,
+            "compile_cache_misses": self.watchdog.cache_misses,
             "worker_restarts": self._total_worker_restarts,
             "masked_slots": self._total_masked_slots,
             "ckpt_commits": self._total_ckpt_commits,
@@ -1250,6 +1266,14 @@ def telemetry_fused_fallback(reason: str, detail: str = "", **fields: Any) -> No
         tel.record_fused_fallback(reason, detail, **fields)
 
 
+def telemetry_resolved(name: str, value: Any, **fields: Any) -> None:
+    """Record what an ``auto`` placement resolved to (see
+    :meth:`RunTelemetry.record_resolved`); no-op when telemetry is off."""
+    tel = _active_telemetry
+    if tel is not None:
+        tel.record_resolved(name, value, **fields)
+
+
 def telemetry_masked_slot(worker: int, slots: Any, reason: str, **fields: Any) -> None:
     """Record env slots masked dead (see
     :meth:`RunTelemetry.record_masked_slot`); no-op when telemetry is off."""
@@ -1382,7 +1406,13 @@ def telemetry_register_flops(jitted_fn: Any, *args: Any, scale: float = 1.0) -> 
     import jax
 
     def as_shape(x: Any) -> Any:
-        return jax.ShapeDtypeStruct(x.shape, x.dtype) if hasattr(x, "shape") and hasattr(x, "dtype") else x
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        # keep a committed array's sharding: the analysis then lowers the
+        # very program the loop dispatched, and its compile is a persistent
+        # cache hit instead of a second full compile of the train step
+        committed = isinstance(x, jax.Array) and getattr(x, "committed", False)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding if committed else None)
 
     shapes = jax.tree.map(as_shape, args)
 

@@ -1,6 +1,6 @@
 """AOT executable cache: serialize compiled XLA executables, skip the compile.
 
-The opportunistic ``fabric.compilation_cache_dir`` trace cache (PR 2) still
+The persistent XLA trace cache (``fabric.configure_compilation_cache``) still
 re-traces, re-lowers, and round-trips XLA on every boot. This module caches
 the *final product* — the loaded executable — via
 ``jax.experimental.serialize_executable``, so a replica restart, fleet
@@ -53,7 +53,7 @@ import numpy as np
 from sheeprl_tpu.obs.telemetry import telemetry_aot_cache, telemetry_aot_load
 from sheeprl_tpu.resilience.manifest import tree_digest
 
-CACHE_VERSION = 1
+CACHE_VERSION = 2  # 2: entries carry the device ids they were compiled for
 ENTRY_SUFFIX = ".aotx"
 # staging prefix for atomic entry promotes (matches the manifest discipline)
 TMP_PREFIX = ".tmp-"
@@ -63,32 +63,35 @@ def _canonical(obj: Any) -> str:
     return json.dumps(obj, sort_keys=True, separators=(",", ":"), default=str)
 
 
-# serializes toggles of the global trace-cache config in _compile_serializable
+# serializes toggles of the global trace-cache switch in _compile_serializable
 _COMPILE_CONFIG_LOCK = threading.Lock()
 
 
 def _compile_serializable(compile_fn: Callable[[], Any]) -> Any:
-    """Run ``compile_fn`` with the persistent XLA trace cache disabled.
+    """Run ``compile_fn`` with the persistent XLA trace cache switched off.
 
     An executable whose compile *hits* that cache deserializes fine for
     dispatch but does not survive ``serialize_executable`` — the payload
     loads with "Symbols not found" (CPU backend), so :meth:`AotCache.store`'s
     round-trip verification refuses it and the AOT tier silently never
     populates. The trace cache buys nothing here anyway: this tier caches
-    the final executable, one level above it. Restored on exit so every
-    other compile in the process keeps the trace cache."""
+    the final executable, one level above it. The switch is
+    ``jax_enable_compilation_cache`` (the cache's directory is never
+    touched), and JAX latches "cache in use" at its first compile, so each
+    flip is followed by ``reset_cache()``. Restored on exit so every other
+    compile in the process keeps the trace cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     with _COMPILE_CONFIG_LOCK:
-        try:
-            prev = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            return compile_fn()
-        if prev is None:
+        if not jax.config.jax_enable_compilation_cache:
             return compile_fn()
         try:
-            jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_enable_compilation_cache", False)
+            compilation_cache.reset_cache()
             return compile_fn()
         finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
 
 
 def _leaf_aval(leaf: Any) -> Tuple[Any, ...]:
@@ -155,6 +158,20 @@ class CacheKey(NamedTuple):
     tag: str
     parts: Dict[str, Any]
     digest: str
+
+
+def _compiled_device_ids(compiled: Any) -> List[int]:
+    """Ids of the devices ``compiled`` was built for, in assignment order —
+    the same ``_unloaded_executable`` that ``serialize_executable.serialize``
+    pickles. A serialized executable must be loaded onto exactly these: left
+    to its default, ``deserialize_and_load`` loads over EVERY device of the
+    backend and a one-device executable then refuses its arguments."""
+    return [int(d.id) for d in compiled._executable._unloaded_executable.device_list]
+
+
+def _devices_by_id(device_ids: List[int]) -> List[Any]:
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in device_ids]
 
 
 def _sanitize(tag: str) -> str:
@@ -263,7 +280,12 @@ class AotCache:
             # are neither recompiles nor `deliberate:` compiles — classify
             # them under the aot-load window so the watchdog stays quiet
             with telemetry_aot_load(key.tag):
-                fn = _se.deserialize_and_load(doc["payload"], doc["in_tree"], doc["out_tree"])
+                fn = _se.deserialize_and_load(
+                    doc["payload"],
+                    doc["in_tree"],
+                    doc["out_tree"],
+                    execution_devices=_devices_by_id(doc["device_ids"]),
+                )
         except Exception as err:
             self.errors += 1
             telemetry_aot_cache("corrupt_gc", key.tag, digest=key.digest, error=repr(err))
@@ -320,15 +342,19 @@ class AotCache:
             from jax.experimental import serialize_executable as _se
 
             payload, in_tree, out_tree = _se.serialize(compiled)
+            device_ids = _compiled_device_ids(compiled)
             # verify the payload round-trips BEFORE committing: an executable
             # that itself came out of the XLA persistent trace cache can
             # serialize into an unloadable payload (CPU backend: "Symbols not
             # found") — committed, it would cost every future boot a
             # corrupt_gc + recompile instead of a hit
-            _se.deserialize_and_load(payload, in_tree, out_tree)
+            _se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=_devices_by_id(device_ids)
+            )
             doc = {
                 "cache_version": CACHE_VERSION,
                 "key": key.parts,
+                "device_ids": device_ids,
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,

@@ -97,16 +97,11 @@ def rmsprop(
     max_grad_norm: float = 0.0,
     schedule: Optional[Any] = None,
 ) -> optax.GradientTransformation:
-    """torch.optim.RMSprop-style (epsilon outside the sqrt where supported)."""
-    try:
-        opt = optax.rmsprop(
-            _lr(lr, schedule), decay=alpha, eps=eps, centered=centered, momentum=momentum or None,
-            eps_in_sqrt=False,
-        )
-    except TypeError:  # older optax without eps_in_sqrt
-        opt = optax.rmsprop(
-            _lr(lr, schedule), decay=alpha, eps=eps, centered=centered, momentum=momentum or None
-        )
+    """torch.optim.RMSprop-style (epsilon outside the sqrt)."""
+    opt = optax.rmsprop(
+        _lr(lr, schedule), decay=alpha, eps=eps, centered=centered, momentum=momentum or None,
+        eps_in_sqrt=False,
+    )
     if weight_decay:
         opt = optax.chain(optax.add_decayed_weights(weight_decay), opt)
     if max_grad_norm and max_grad_norm > 0:

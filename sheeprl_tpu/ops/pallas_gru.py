@@ -23,11 +23,12 @@ saves only the kernel *inputs* and the backward re-derives intermediates via
 rematerialisation trade (HBM bandwidth is the TPU bottleneck, recompute is
 MXU-cheap) and keeps the backward graph fully fused by XLA.
 
-The kernel targets the fits-in-VMEM regime (weights + one batch tile under
-~12 MB) which covers the Dreamer-V3 XS/S/M recipes; larger models fall back
-to the flax cell automatically (`fits_vmem`).  On non-TPU backends the
-kernel runs in interpreter mode when explicitly requested (tests) and is
-otherwise bypassed.
+The kernel targets the fits-in-VMEM regime (weights at their storage dtype +
+one fp32 batch tile under ~12 MB): Dreamer-V3 XS/S with fp32 weights, M with
+bf16 weights. :func:`fits_vmem` is the one sizing verdict — the config gate
+(:func:`resolve_backend`) and the kernel wrappers both ask it, with the dtype
+the weights are stored in. Interpreter mode is entered only when a caller
+passes ``interpret=True`` (tests do); the program never infers it.
 """
 
 from __future__ import annotations
@@ -89,11 +90,15 @@ def _kernel(x_ref, h_ref, w1_ref, b1_ref, g1_ref, be1_ref, w2_ref, g2_ref, be2_r
         var = jnp.mean(jnp.square(v - mu), axis=-1, keepdims=True)
         return (v - mu) * jax.lax.rsqrt(var + eps) * g + b
 
-    pre = jnp.dot(x, w1_ref[:], preferred_element_type=jnp.float32) + b1_ref[:]
+    # weights stay in VMEM at their storage dtype (what fits_vmem sized); the
+    # activation operand is cast to it and the MXU accumulates fp32 — with
+    # fp32 weights every cast is the identity
+    wdt = w1_ref.dtype
+    pre = jnp.dot(x.astype(wdt), w1_ref[:], preferred_element_type=jnp.float32) + b1_ref[:]
     feat = jax.nn.silu(_ln(pre, g1_ref[:], be1_ref[:], eps1))
     # [h, feat] @ W2 without materialising the concat: split W2 by rows
-    proj = jnp.dot(h, w2_ref[:hidden, :], preferred_element_type=jnp.float32) + jnp.dot(
-        feat, w2_ref[hidden:, :], preferred_element_type=jnp.float32
+    proj = jnp.dot(h.astype(wdt), w2_ref[:hidden, :], preferred_element_type=jnp.float32) + jnp.dot(
+        feat.astype(wdt), w2_ref[hidden:, :], preferred_element_type=jnp.float32
     )
     proj = _ln(proj, g2_ref[:], be2_ref[:], eps2)
     reset = proj[:, :hidden]
@@ -163,7 +168,8 @@ def _make_fused_step(eps1: float, eps2: float, interpret: bool):
 
         batch, hidden = h.shape
         pad_b = _round_up(max(batch, _SUBLANE), _SUBLANE)
-        tile_b = best_tile_b(x.shape[1], w1.shape[1], hidden)
+        wdt = jnp.result_type(w1.dtype, w2.dtype)
+        tile_b = best_tile_b(x.shape[1], w1.shape[1], hidden, wdt)
         if tile_b is None:
             raise ValueError(
                 "fused_recurrent_step: model too large for VMEM-resident kernel; "
@@ -195,11 +201,11 @@ def _make_fused_step(eps1: float, eps2: float, interpret: bool):
         )(
             x.astype(jnp.float32),
             h.astype(jnp.float32),
-            w1.astype(jnp.float32),
+            w1.astype(wdt),
             b1.astype(jnp.float32),
             g1.astype(jnp.float32),
             be1.astype(jnp.float32),
-            w2.astype(jnp.float32),
+            w2.astype(wdt),
             g2.astype(jnp.float32),
             be2.astype(jnp.float32),
         )
@@ -256,18 +262,6 @@ def fused_recurrent_step(
 # --------------------------------------------------------------------------- #
 
 
-def _proj_tile_b(rows: int, cols: int, hidden: int, dense_units: int, w_itemsize: int) -> Optional[int]:
-    """Batch tile for the sharded projection kernel: the per-device W2 slice
-    ``[rows, cols]`` at its storage dtype + fp32 ``h``/``feat``/``out``
-    tiles must fit the VMEM budget."""
-    tile = _MAX_TILE_B
-    while tile >= _SUBLANE:
-        if w_itemsize * rows * cols + 4 * tile * (hidden + dense_units + cols) <= _VMEM_BUDGET_BYTES:
-            return tile
-        tile //= 2
-    return None
-
-
 def _proj_kernel(h_ref, f_ref, w2_ref, out_ref, *, hidden):
     # [h, feat] @ W2_slice without materialising the concat: W2 split by rows.
     # Weights load at their storage dtype (bf16 VMEM footprint) and upcast in
@@ -280,11 +274,12 @@ def _proj_kernel(h_ref, f_ref, w2_ref, out_ref, *, hidden):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_sharded_proj(interpret: bool):
+def _make_sharded_proj(interpret: bool, max_tile_b: int):
     """Custom-VJP pallas projection ``(h [B,H], feat [B,D], w2 [H+D, C]) ->
-    [B, C]`` — the weight-stationary piece of the sharded step. The backward
-    is three plain matmuls (XLA), matching the recompute philosophy of the
-    full fused kernel."""
+    [B, C]`` — the weight-stationary piece of the sharded step, over batch
+    tiles of at most ``max_tile_b`` rows (:func:`best_tile_b`'s verdict for
+    the whole step). The backward is three plain matmuls (XLA), matching the
+    recompute philosophy of the full fused kernel."""
 
     def _forward(h, feat, w2):
         from jax.experimental import pallas as pl
@@ -292,14 +287,8 @@ def _make_sharded_proj(interpret: bool):
         batch, hidden = h.shape
         dense_units = feat.shape[1]
         cols = w2.shape[1]
-        tile_b = _proj_tile_b(w2.shape[0], cols, hidden, dense_units, jnp.dtype(w2.dtype).itemsize)
-        if tile_b is None:
-            raise ValueError(
-                "sharded_recurrent_step: per-device W2 slice too large for the "
-                "VMEM-resident kernel; gate on fits_vmem(..., model_shards=mp)"
-            )
         pad_b = _round_up(max(batch, _SUBLANE), _SUBLANE)
-        tile_b = min(pad_b, tile_b)
+        tile_b = min(pad_b, max_tile_b)
         pad_b = _round_up(pad_b, tile_b)
         if pad_b != batch:
             h = jnp.pad(h, ((0, pad_b - batch), (0, 0)))
@@ -390,6 +379,12 @@ def sharded_recurrent_step(
     mp = mesh.shape[model_axis]
     if hidden % mp != 0:
         raise ValueError(f"hidden ({hidden}) must divide by the model axis ({mp})")
+    tile_b = best_tile_b(x.shape[-1], w1.shape[1], hidden, w2.dtype, mp) if use_pallas else None
+    if use_pallas and tile_b is None:
+        raise ValueError(
+            "sharded_recurrent_step: per-device W2 slice too large for the "
+            "VMEM-resident kernel; gate on fits_vmem(..., model_shards=mp)"
+        )
     w2g = w2.reshape(w2.shape[0], 3, hidden)
     g2g = g2.reshape(3, hidden)
     be2g = be2.reshape(3, hidden)
@@ -408,7 +403,7 @@ def sharded_recurrent_step(
         hs = hidden // mp
         w2l = w2g.reshape(w2g.shape[0], 3 * hs)
         if use_pallas:
-            pre = _make_sharded_proj(interpret)(h, feat, w2l)
+            pre = _make_sharded_proj(interpret, tile_b)(h, feat, w2l)
         else:
             pre = h @ w2l[:hidden, :] + feat @ w2l[hidden:, :]
         pre = pre.reshape(-1, 3, hs)
@@ -449,42 +444,34 @@ def resolve_backend(
     hidden: int,
     dtype: Any = jnp.float32,
     model_shards: int = 1,
-) -> Tuple[bool, bool]:
-    """Map a config flag to ``(use_pallas, interpret)``.
+) -> bool:
+    """Map a config flag to ``use_pallas``.
 
-    ``mode``: ``"auto"`` (see below), ``True``/``"pallas"`` (force;
-    interpreter off-TPU — for tests), ``False``/``"flax"`` (never).
-    ``dtype``/``model_shards`` size the VMEM verdict for the weights'
-    storage dtype and a model-axis-sharded W2 slice.
+    ``mode``: ``"auto"`` (see below), ``True``/``"pallas"`` (force — an error
+    when the step does not fit VMEM, never a silent flax cell), ``False``/
+    ``"flax"`` (never). ``dtype``/``model_shards`` size the VMEM verdict for
+    the weights' storage dtype and a model-axis-sharded W2 slice.
 
-    ``auto`` on a replicated (mp=1) layout resolves to the flax cell: the
-    round-3 on-chip A/B (``benchmarks/pallas_gru_ab.py``, TPU v5e) measured
-    the kernel at parity with XLA's own fusion at the XS scale (1.01–1.03x)
-    and SLOWER at S (0.62x forward) — XLA already fuses the
-    Dense→LN→SiLU→GRU body well and the replicated kernel just re-streams
-    the same HBM bytes. On a model-sharded layout (``model_shards`` > 1) the
-    economics invert — the per-shard slice is weight-stationary in VMEM
-    while the XLA baseline still streams it — so ``auto`` picks the sharded
-    kernel whenever the slice fits on-chip.
+    ``auto`` on a replicated (mp=1) layout resolves to the flax cell: XLA
+    already fuses the Dense→LN→SiLU→GRU body and the replicated kernel
+    re-streams the same HBM bytes (``benchmarks/pallas_gru_ab.py`` is the
+    A/B; not measured on the current code). On a model-sharded layout
+    (``model_shards`` > 1) the per-shard slice is weight-stationary in VMEM
+    while the XLA baseline still streams it, so ``auto`` picks the sharded
+    kernel whenever the backend is a TPU and the slice fits.
     """
     if mode in (False, None, "flax", "off"):
-        return False, False
-    on_tpu = jax.default_backend() == "tpu"
+        return False
     fits = fits_vmem(in_dim, dense_units, hidden, dtype, model_shards)
     if mode in (True, "pallas", "force"):
         if not fits:
-            import warnings
-
-            warnings.warn(
+            raise ValueError(
                 f"fused={mode!r} requested but the RSSM step (in={in_dim}, "
-                f"dense={dense_units}, hidden={hidden}, shards={model_shards}) "
-                "exceeds the VMEM-resident kernel's budget — falling back to "
-                "the flax cell",
-                stacklevel=2,
+                f"dense={dense_units}, hidden={hidden}, dtype={jnp.dtype(dtype).name}, "
+                f"shards={model_shards}) exceeds the VMEM-resident kernel's budget; "
+                "use fused=flax (or auto)"
             )
-        return fits, not on_tpu
+        return True
     if str(mode).lower() == "auto":
-        if model_shards > 1:
-            return on_tpu and fits, False
-        return False, False  # replicated: measured, XLA fusion ties/wins
+        return model_shards > 1 and fits and jax.default_backend() == "tpu"
     raise ValueError(f"unknown fused-recurrent mode {mode!r}")

@@ -1,10 +1,8 @@
-"""Version-stable ``shard_map`` wrapper.
+"""``jax.shard_map`` with replication checking off.
 
-jax >= 0.7 promotes ``shard_map`` to ``jax.shard_map`` and renames
-``check_rep`` to ``check_vma``; older versions only have
-``jax.experimental.shard_map.shard_map``. Every algorithm shards its fused
-train step through this wrapper (replication checking off: train steps mix
-replicated params with data-sharded batches and per-device RNG folding).
+Every algorithm shards its fused train step through this wrapper: train steps
+mix replicated params with data-sharded batches and per-device RNG folding,
+which the ``check_vma`` pass rejects.
 """
 
 from __future__ import annotations
@@ -13,8 +11,4 @@ import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)

@@ -16,6 +16,7 @@ behaviour — Ctrl-C twice still means "stop NOW".
 
 from __future__ import annotations
 
+import os
 import signal
 import threading
 import time
@@ -35,6 +36,7 @@ class PreemptionWatcher:
         self.signum: Optional[int] = None
         self.signal_time: Optional[float] = None
         self._old_handlers: dict = {}
+        self._owner_pid: Optional[int] = None
         self.installed = False
 
     def install(self) -> "PreemptionWatcher":
@@ -42,6 +44,7 @@ class PreemptionWatcher:
         allows signal handlers there) so helper threads can share the code."""
         if self.installed or threading.current_thread() is not threading.main_thread():
             return self
+        self._owner_pid = os.getpid()
         for sig in _SIGNALS:
             self._old_handlers[sig] = signal.signal(sig, self._handle)
         self.installed = True
@@ -59,6 +62,15 @@ class PreemptionWatcher:
         self.installed = False
 
     def _handle(self, signum, frame) -> None:
+        if os.getpid() != self._owner_pid:
+            # a forked child (gymnasium's AsyncVectorEnv workers fork after
+            # the train loop installed this) inherited the handler. Nothing
+            # polls the flag there, so swallowing the signal would make the
+            # parent's terminate()+join() of its workers wait forever — the
+            # child dies as it would have without the watcher.
+            signal.signal(signum, signal.SIG_DFL)
+            os.kill(os.getpid(), signum)
+            return
         if self._requested and signum == signal.SIGINT:
             # second Ctrl-C: the user wants out immediately
             self.uninstall()

@@ -201,8 +201,8 @@ class SteadyStateProbe:
     A loop constructs one probe, calls :meth:`mark` once it considers itself
     warm (compiles done — each loop picks its own rule), and :meth:`finish`
     after its final update with a zero-arg ``sync`` callable that genuinely
-    waits for the device (a materializing fetch — ``block_until_ready`` is
-    advisory on remote-attached chips)."""
+    waits for the device (a materializing fetch: the value cannot arrive
+    before the device is done)."""
 
     def __init__(self) -> None:
         import os
@@ -308,8 +308,8 @@ def gradient_step_chunks(n_steps: int, algo_cfg: Mapping[str, Any]) -> list:
     The SAC-family loops fuse all G gradient steps of an update into one
     scanned jit whose length is G — but ``Ratio`` varies G (most brutally on
     the first post-warmup update, which repays the whole warmup debt: G in
-    the hundreds), and every distinct G compiles a fresh executable (the
-    observed 20-minute stall on the remote chip). Chunking caps the set of
+    the hundreds), and every distinct G compiles a fresh executable.
+    Chunking caps the set of
     compiled lengths at {chunk} ∪ {possible remainders}: full chunks are
     shape-identical, the scan math is unchanged (scans compose), and only
     the remainder varies. The chunk size comes from

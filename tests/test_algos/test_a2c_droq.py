@@ -36,7 +36,7 @@ def test_a2c_cartpole(tmp_path, monkeypatch):
 
 def test_a2c_host_pinned_training(tmp_path, monkeypatch):
     """algo.train_device=cpu runs the whole A2C update on the host backend
-    (remote-chip escape hatch shared with plain PPO) — full run + resume."""
+    (the host-train escape hatch shared with plain PPO) — full run + resume."""
     monkeypatch.chdir(tmp_path)
     args = a2c_args(tmp_path) + ["fabric.devices=1", "algo.train_device=cpu"]
     run(args)

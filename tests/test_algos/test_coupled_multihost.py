@@ -1,7 +1,6 @@
 """Coupled (SPMD) multi-host e2e tests: full PPO and Dreamer-V3 ``main()``
 across 2 real ``jax.distributed`` CPU processes × 2 virtual devices each —
-the exact topology of the milestone multi-host configs (BASELINE.md (2)/(4)),
-which round 3 had only covered with unit-level collective tests.
+the exact topology of the milestone multi-host configs (BASELINE.json (2)/(4)).
 
 Each process owns its own envs, samples its block of the global batch,
 assembles mesh-global arrays (``fabric.make_global`` — for DV3 through the

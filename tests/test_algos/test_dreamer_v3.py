@@ -87,8 +87,16 @@ def test_dreamer_v3_model_axis_mesh(tmp_path, monkeypatch):
 
 
 def test_dreamer_v3_fused_pallas_recurrent(tmp_path, monkeypatch):
-    """Full train update through the Pallas RSSM-step kernel (interpreter
-    mode on the CPU test mesh; Mosaic-compiled on a real TPU)."""
+    """Full train update through the Pallas RSSM-step kernel. The program
+    never infers interpreter mode, so on the CPU test mesh the TEST asks for
+    it, at the one place the world model builds its fused cell."""
+    import functools
+
+    from sheeprl_tpu.algos.dreamer_v3 import agent
+
+    monkeypatch.setattr(
+        agent, "FusedRecurrentModel", functools.partial(agent.FusedRecurrentModel, interpret=True)
+    )
     monkeypatch.chdir(tmp_path)
     run(dv3_args(tmp_path) + ["algo.world_model.recurrent_model.fused=pallas"])
     assert find_checkpoints(tmp_path)

@@ -1,5 +1,5 @@
 """Elastic checkpoint restore: a run saved on one mesh size resumes on
-another (VERDICT round-4 item 5b; reference semantics: the checkpoint stores
+another (reference semantics: the checkpoint stores
 the GLOBAL batch — ``dreamer_v3.py`` writes ``batch_size = per_rank *
 world_size`` and resume divides by the NEW world size — while the reference
 itself refuses world-size changes, callback.py:87-142).

@@ -1,6 +1,5 @@
-"""Plan2Explore intrinsic-reward sanity (round-2 VERDICT item 4: nothing
-checked that ensemble disagreement actually behaves like an exploration
-signal). Two properties of the P2E-DV3 ensemble machinery:
+"""Plan2Explore intrinsic-reward sanity: does ensemble disagreement actually
+behave like an exploration signal? Two properties of the P2E-DV3 ensemble machinery:
 
 1. training the ensemble on a fixed transition set DRIVES DISAGREEMENT DOWN
    on that set (seen data stops being interesting),

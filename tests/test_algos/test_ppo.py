@@ -50,7 +50,7 @@ def test_ppo_cartpole_vector(tmp_path, monkeypatch):
 
 def test_ppo_host_pinned_training(tmp_path, monkeypatch):
     """algo.train_device=cpu: the whole fused update runs on the host
-    backend (the remote-chip escape hatch, resolve_train_device) — full
+    backend (the host-train escape hatch, resolve_train_device) — full
     run + resume through the host-jitted no-mesh train path."""
     monkeypatch.chdir(tmp_path)
     args = standard_args(tmp_path) + ["fabric.devices=1", "algo.train_device=cpu"]

@@ -52,7 +52,7 @@ def test_sac_decoupled_two_process(tmp_path):
 @pytest.mark.slow
 def test_sac_decoupled_resume(tmp_path):
     """Decoupled SAC restores agent, optimizers, replay buffer and counters
-    from a player-written checkpoint (round-2 VERDICT: resume was refused)."""
+    from a player-written checkpoint."""
     base = [
         "exp=sac_decoupled",
         "env=dummy",

@@ -104,3 +104,24 @@ def test_buffer_sample_native_equals_numpy(monkeypatch, dtype):
     for k in with_native:
         np.testing.assert_array_equal(with_native[k], without[k])
         assert with_native[k].dtype == without[k].dtype
+
+
+def test_build_dir_is_fixed_inside_the_checkout_and_status_names_the_path(monkeypatch, tmp_path):
+    """The .so is built from the committed gather.cpp into one fixed
+    git-ignored directory of the checkout (SHEEPRL_TPU_NATIVE_CACHE moves
+    it), and ``status()`` — folded into every run record — says which gather
+    ran and, for the numpy path, why."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    monkeypatch.delenv("SHEEPRL_TPU_NATIVE_CACHE", raising=False)
+    assert native._build_dir() == os.path.join(repo, ".native_cache") == native.REPO_NATIVE_CACHE_DIR
+    monkeypatch.setenv("SHEEPRL_TPU_NATIVE_CACHE", str(tmp_path))
+    assert native._build_dir() == str(tmp_path)
+    assert native.status() == "native"  # this module is skipped when the build is unavailable
+    # a load that fails lands on numpy and says why
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_TRIED", False)
+    monkeypatch.setenv("SHEEPRL_TPU_DISABLE_NATIVE", "1")
+    assert native.gather_rows(np.zeros((2, 1)), np.zeros(1, np.int64), np.zeros(1, np.int64)) is None
+    assert native.status() == "numpy (SHEEPRL_TPU_DISABLE_NATIVE is set)"

@@ -129,20 +129,64 @@ def test_fused_module_trains():
 
 def test_resolve_backend_policy():
     # off: never pallas
-    assert resolve_backend(False, 64, 64, 64) == (False, False)
-    assert resolve_backend("flax", 64, 64, 64) == (False, False)
-    # auto off-TPU (CPU test mesh): stays flax
-    on_tpu = jax.default_backend() == "tpu"
-    use, interp = resolve_backend("auto", 64, 64, 64)
-    assert use == on_tpu and interp is False
-    # forced: pallas with interpret off-TPU
-    use, interp = resolve_backend("pallas", 64, 64, 64)
-    assert use is True and interp == (not on_tpu)
-    # forced but too large for VMEM: falls back
-    use, _ = resolve_backend("pallas", 4096, 8192, 8192)
-    assert use is False
-    with pytest.raises(ValueError):
+    assert resolve_backend(False, 64, 64, 64) is False
+    assert resolve_backend("flax", 64, 64, 64) is False
+    # auto on a replicated layout: the flax cell, on every backend
+    assert resolve_backend("auto", 64, 64, 64) is False
+    # forced: pallas — and never an inferred interpreter (the caller of the
+    # kernel says interpret=True, or the kernel compiles for the device)
+    assert resolve_backend("pallas", 64, 64, 64) is True
+    # forced but too large for VMEM: an error, not a silent flax cell
+    with pytest.raises(ValueError, match="exceeds the VMEM-resident kernel's budget"):
+        resolve_backend("pallas", 4096, 8192, 8192)
+    with pytest.raises(ValueError, match="unknown fused-recurrent mode"):
         resolve_backend("bogus", 64, 64, 64)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("size", ["S", "M", "L", "XL"])
+def test_gate_and_kernel_agree_on_vmem(size, dtype):
+    """One sizing function, one answer: wherever ``fits_vmem`` says yes at
+    the weights' storage dtype the kernel wrapper traces, and wherever it
+    says no the wrapper refuses — the M bf16 case used to pass the gate and
+    die in the kernel, which sized VMEM as fp32."""
+    from benchmarks.pallas_gru_ab import SIZES  # the Dreamer-V3 size table
+
+    (in_dim, dense, hidden), batch = SIZES[size], 16
+    shapes = [
+        jax.ShapeDtypeStruct(s, d)
+        for s, d in (
+            ((batch, in_dim), jnp.float32),
+            ((batch, hidden), jnp.float32),
+            ((in_dim, dense), dtype),
+            ((dense,), dtype),
+            ((dense,), dtype),
+            ((dense,), dtype),
+            ((hidden + dense, 3 * hidden), dtype),
+            ((3 * hidden,), dtype),
+            ((3 * hidden,), dtype),
+        )
+    ]
+    trace = lambda: jax.eval_shape(lambda *a: fused_recurrent_step(*a, interpret=True), *shapes)  # noqa: E731
+    if fits_vmem(in_dim, dense, hidden, dtype):
+        assert trace().shape == (batch, hidden)
+        assert resolve_backend("pallas", in_dim, dense, hidden, dtype) is True
+    else:
+        with pytest.raises(ValueError, match="too large for VMEM-resident kernel"):
+            trace()
+        with pytest.raises(ValueError, match="exceeds the VMEM-resident kernel's budget"):
+            resolve_backend("pallas", in_dim, dense, hidden, dtype)
+
+
+def test_fused_bf16_weights_stay_bf16_in_the_kernel():
+    """bf16-stored weights reach the kernel as bf16 (that is what the gate
+    sized) and the result stays close to the fp32 reference."""
+    args = _random_args(jax.random.PRNGKey(3), batch=8, in_dim=16, dense=16, hidden=8)
+    cast = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+    x, h, *weights = args
+    got = fused_recurrent_step(x, h, *[cast(w) for w in weights], interpret=True)
+    want = reference_step(x, h, *[cast(w).astype(jnp.float32) for w in weights])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-2)
 
 
 def test_fits_vmem_regimes():
@@ -174,15 +218,12 @@ def test_resolve_backend_model_shards():
     on-TPU and the per-shard slice fits VMEM (the ISSUE-14 adoption hook);
     forced pallas honors the sharded budget the same way."""
     on_tpu = jax.default_backend() == "tpu"
-    use, interp = resolve_backend("auto", 32 * 32 + 6, 1024, 4096, jnp.bfloat16, 16)
-    assert use == on_tpu and interp is False
+    assert resolve_backend("auto", 32 * 32 + 6, 1024, 4096, jnp.bfloat16, 16) is on_tpu
     # sharded but the slice does NOT fit: stays flax
-    use, _ = resolve_backend("auto", 8192, 8192, 8192, jnp.float32, 2)
-    assert use is False
-    use, interp = resolve_backend("pallas", 1536, 768, 2048, jnp.bfloat16, 4)
-    assert use is True and interp == (not on_tpu)
-    use, _ = resolve_backend("pallas", 1536, 768, 2048, jnp.float32, 4)
-    assert use is False  # the L fp32 4-shard flip case falls back
+    assert resolve_backend("auto", 8192, 8192, 8192, jnp.float32, 2) is False
+    assert resolve_backend("pallas", 1536, 768, 2048, jnp.bfloat16, 4) is True
+    with pytest.raises(ValueError):  # the L fp32 4-shard flip case does not fit
+        resolve_backend("pallas", 1536, 768, 2048, jnp.float32, 4)
 
 
 # --------------------------------------------------------------------------

@@ -130,21 +130,60 @@ def test_grad_pmean_matches_single_device():
     np.testing.assert_allclose(jax.jit(dp_grad)(w, x), full_grad, rtol=1e-5)
 
 
-def test_fabric_compilation_cache_dir(tmp_path):
-    """fabric.compilation_cache_dir points JAX's persistent compile cache at
-    the given directory, creating it; the default (None) leaves the global
-    config untouched."""
+@pytest.fixture()
+def _cache_config():
+    """Snapshot and restore the process-wide compile-cache settings."""
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def test_compilation_cache_unset_env_is_the_fixed_checkout_path(monkeypatch, _cache_config):
+    """No JAX_COMPILATION_CACHE_DIR: the cache is ON at one fixed git-ignored
+    path inside the checkout — not a temp name, not per pid, not per run."""
     import os
 
-    saved = jax.config.jax_compilation_cache_dir
-    try:
-        cache = str(tmp_path / "xla-cache")
-        f = Fabric(devices=1, compilation_cache_dir=cache)
-        assert f.compilation_cache_dir == cache
-        assert os.path.isdir(cache)
-        assert jax.config.jax_compilation_cache_dir == cache
-        # None is a no-op: the previously configured dir stays in force
-        assert Fabric(devices=1).compilation_cache_dir is None
-        assert jax.config.jax_compilation_cache_dir == cache
-    finally:
-        jax.config.update("jax_compilation_cache_dir", saved)
+    from sheeprl_tpu.parallel import fabric as fabric_mod
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    want = os.path.join(repo, ".jax_cache")
+    assert fabric_mod.REPO_COMPILATION_CACHE_DIR == want
+    first = Fabric(devices=1).compilation_cache_dir
+    assert first == want == Fabric(devices=2).compilation_cache_dir
+    assert jax.config.jax_compilation_cache_dir == want and os.path.isdir(want)
+    # on the CPU backend JAX's own thresholds stay (only an accelerator
+    # persists every tiny program)
+    assert jax.config.jax_persistent_cache_min_compile_time_secs > 0.0
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compilation_cache_set_env_is_left_alone(monkeypatch, tmp_path, _cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: that directory is the cache and Fabric
+    never writes jax_compilation_cache_dir (JAX read the variable itself)."""
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    writes = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: (writes.append(name), real_update(name, value))[1]
+    )
+    assert Fabric(devices=1).compilation_cache_dir == placed
+    assert "jax_compilation_cache_dir" not in writes, writes
+
+
+def test_fabric_accelerator_tpu_refuses_cpu_devices():
+    """`tpu` is a demand, not a hint: on CPU devices it raises instead of
+    training there; `auto` takes what is there and records what that was."""
+    with pytest.raises(RuntimeError, match="'tpu' was requested but JAX found 'cpu'"):
+        Fabric(devices=1, accelerator="tpu")
+    assert Fabric(devices=1, accelerator="auto").platform == "cpu"
+    assert Fabric(devices=1, accelerator="cpu").platform == "cpu"

@@ -1,8 +1,9 @@
 """Host-player placement specs (learner-on-chip / actor-on-host split).
 
 No reference counterpart — the torch player always shares the trainer's
-device; this framework adds ``algo.player_device`` for remote-attached chips
-(parallel/fabric.py ``resolve_player_device`` / ``HostPlayerParams``).
+device; this framework adds ``algo.player_device`` for backends whose dispatch
+round trip measures above 5 ms (parallel/fabric.py ``resolve_player_device`` /
+``HostPlayerParams``).
 """
 
 import jax
@@ -49,8 +50,7 @@ def test_resolve_cpu_on_cpu_backend_is_none():
 
 def test_resolve_auto_on_cpu_backend_is_none():
     assert resolve_player_device("auto") is None
-    # conv policies too: auto depends only on the measured link latency
-    # (a host pixel forward is ~ms, far under a remote chip's round trip)
+    # conv policies too: auto depends only on the measured round trip
     assert resolve_player_device("auto") is None
 
 
@@ -138,7 +138,7 @@ def test_resolve_unknown_spec_raises():
 
 
 def test_dispatch_roundtrip_is_fast_locally():
-    # virtual CPU devices are in-process: far below the 5 ms remote threshold
+    # virtual CPU devices are in-process: far below the 5 ms threshold
     assert dispatch_roundtrip_seconds() < 0.005
 
 
@@ -219,11 +219,11 @@ def test_player_on_explicit_device_end_to_end():
     assert leaf.devices() == {dev}
 
 
-def test_age_threshold_scales_with_pack_size_on_remote_links(monkeypatch):
+def test_age_threshold_scales_with_pack_size_above_the_rtt_threshold(monkeypatch):
     """The stream gate waits for the landing estimate (bytes/bandwidth + RTT)
-    on remote links, and keeps the cheap RTT-only gate locally — polling a
-    large pack early turns the 'free' finish into a blocking partial-transfer
-    wait (the round-4 SAC-AE 1.5 s/update regression)."""
+    when the dispatch round trip is above the 5 ms threshold, and keeps the
+    cheap RTT-only gate below it — polling a large pack early turns the
+    'free' finish into a blocking partial-transfer wait."""
     import jax.numpy as jnp
 
     from sheeprl_tpu.parallel import fabric as fabric_mod
@@ -234,16 +234,16 @@ def test_age_threshold_scales_with_pack_size_on_remote_links(monkeypatch):
     big = {"w": jnp.zeros((1_000_000,), jnp.float32)}  # 4 MB pack
     pipe = _StreamPipe(_ParamStreamer(big, dev))
 
-    # local link (sub-threshold RTT): old cheap gate, bytes ignored
+    # sub-threshold RTT: the cheap gate, bytes ignored
     monkeypatch.setitem(fabric_mod._rtt_cache, "rtt", 0.0001)
     assert pipe._age_threshold() == pytest.approx(0.02)
 
-    # remote link: the 4 MB pack cannot land before bytes/bandwidth + RTT
+    # above the threshold: the 4 MB pack cannot land before bytes/bandwidth + RTT
     monkeypatch.setitem(fabric_mod._rtt_cache, "rtt", 0.1)
     expected = 4_000_000 / _StreamPipe._link_bytes_per_s() + 0.1
     assert pipe._age_threshold() == pytest.approx(expected)
 
-    # a tiny pack on a remote link keeps the RTT-dominated gate
+    # a tiny pack above the threshold keeps the RTT-dominated gate
     small = _StreamPipe(_ParamStreamer({"w": jnp.zeros((4,), jnp.float32)}, dev))
     assert small._age_threshold() == pytest.approx(0.15)
 
@@ -259,3 +259,28 @@ def test_link_bytes_per_s_env_validation(monkeypatch):
     assert _StreamPipe._link_bytes_per_s() == 5e7
     monkeypatch.setenv("SHEEPRL_TPU_LINK_BYTES_PER_S", "nan")
     assert _StreamPipe._link_bytes_per_s() == 1e3  # nan must not disable the gate
+
+
+def test_resolvers_record_what_auto_became(monkeypatch):
+    """Each placement the program picks from what it observes lands in the
+    run record's ``resolved`` section through the active telemetry."""
+    from sheeprl_tpu.obs import telemetry as telemetry_mod
+    from sheeprl_tpu.parallel.fabric import tree_devices
+
+    class _Tel:
+        def __init__(self):
+            self.seen = {}
+
+        def record_resolved(self, name, value, **fields):
+            self.seen[name] = {"value": value, **fields}
+
+    tel = _Tel()
+    monkeypatch.setattr(telemetry_mod, "_active_telemetry", tel)
+    assert resolve_player_device("auto") is None
+    assert resolve_train_device("auto", {"w": np.zeros((8, 8), np.float32)}, 1) is None
+    assert resolve_train_device("cpu", {"w": np.zeros((8, 8), np.float32)}, 1).platform == "cpu"
+    assert tel.seen["player_device"] == {"value": "cpu", "spec": "auto"}
+    assert tel.seen["train_device"] == {"value": "cpu", "spec": "cpu"}
+    # where a tree actually is: .devices() of its leaves, host leaves ignored
+    tree = {"a": jax.device_put(jnp.ones(3), jax.devices()[2]), "b": np.ones(2), "c": jnp.zeros(1)}
+    assert tree_devices(tree) == ["cpu:0", "cpu:2"]

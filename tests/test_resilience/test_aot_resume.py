@@ -67,7 +67,7 @@ def _child_env():
     serialize into an unloadable payload (CPU backend), which AotCache's
     store-time verification rejects — the drill needs committed entries."""
     env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
     return env
 

@@ -7,8 +7,8 @@ docstring — a deserialized executable registers generically-named kernel
 symbols process-wide, and the cache's on/off/dir state latches at the first
 compile). A shared pytest session cannot guarantee that: even collection
 imports compile. So each launcher here boots a fresh interpreter with the
-persistent cache stripped from the environment and runs the real drills
-there, asserting the child's verdict.
+persistent cache switched off (``JAX_ENABLE_COMPILATION_CACHE=false``) and
+runs the real drills there, asserting the child's verdict.
 """
 
 import os
@@ -26,10 +26,10 @@ DRILLS = os.path.join("tests", "test_serve", "test_aotcache_serving.py")
 def _run_hermetic(extra_args, timeout=420):
     env = dict(os.environ)
     env["SHEEPRL_TPU_AOT_HERMETIC"] = "1"
-    # a clean room, not merely a disabled flag: the child must never see the
-    # shared warm cache dir, or its first compile latches onto it
-    env["SHEEPRL_TPU_NO_COMPILE_CACHE"] = "1"
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # a clean room: the child runs with the persistent cache switched off
+    # from its first import on (JAX's own switch), so nothing it compiles is
+    # ever read back from the shared warm cache
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [

@@ -42,7 +42,7 @@ def _real_compiles():
     persistent trace cache (tests/conftest.py) so a trace-cache HIT cannot
     hand these drills an executable whose serialized payload is unloadable
     (CPU backend, "Symbols not found"). The hermetic child already strips
-    the cache via SHEEPRL_TPU_NO_COMPILE_CACHE=1; see the module docstring
+    the cache via JAX_ENABLE_COMPILATION_CACHE=false; see the module docstring
     for why a shared warm-cache process can still poison same-named kernels
     in ways this fixture cannot undo."""
     import jax

@@ -94,7 +94,7 @@ def test_checkpoint_plain_replay_buffer_fixup(tmp_path):
 def test_dv3_orbax_resume_restores_buffer_and_counters(tmp_path, monkeypatch):
     """End to end: train tiny DV3 with the orbax backend + buffer checkpoint,
     resume, and verify the restored buffer contents and counters match the
-    saved run (VERDICT weak #6 done-criterion)."""
+    saved run."""
     from sheeprl_tpu.cli import run
 
     args = [
@@ -229,7 +229,7 @@ def test_orbax_per_process_sidecars_single(tmp_path):
 def test_orbax_multiprocess_per_rank_buffers(tmp_path):
     """2 real processes save ONE orbax checkpoint: shared arrays plus one
     buffer sidecar per process; the reload yields a 2-entry rb list
-    (VERDICT round-2 item 7: no gathered process-0 pickle)."""
+    (no gathered process-0 pickle)."""
     from tests.conftest import run_multi_process
 
     code = """

@@ -188,7 +188,7 @@ def test_run_lifecycle_events(telemetry):
 def test_watchdog_counts_compile_cache_events(telemetry):
     """Persistent-compilation-cache outcomes arrive as plain jax.monitoring
     events; the watchdog counts them and mirrors each as a compile_cache
-    telemetry event (fabric.compilation_cache_dir observability)."""
+    telemetry event (persistent compilation cache observability)."""
     pre_hits, pre_misses = telemetry.watchdog.cache_hits, telemetry.watchdog.cache_misses
     jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
     jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
@@ -247,3 +247,25 @@ def test_train_window_counters_roll_into_heartbeat(telemetry):
     )
     hb2 = [e for e in _events(telemetry) if e["event"] == "heartbeat"][-1]
     assert "window_train_windows" not in hb2
+
+
+def test_device_peaks_come_from_the_table_and_unknown_kinds_raise():
+    from sheeprl_tpu.utils.profiler import PEAK_BF16_FLOPS, device_peaks
+
+    assert device_peaks("TPU v5 lite") == {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    assert PEAK_BF16_FLOPS["TPU v5e"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks recorded for device kind 'cpu'"):
+        device_peaks("cpu")
+
+
+def test_watchdog_emits_one_warm_event():
+    """The stream says when the warm point was reached, so "no recompile" can
+    be told from "never warm"."""
+    from sheeprl_tpu.obs.recompile import CompileWatchdog
+
+    events = []
+    dog = CompileWatchdog(lambda kind, **f: events.append((kind, f)))
+    dog.compiles = 7
+    dog.mark_warm()
+    dog.mark_warm()
+    assert events == [("warm", {"compiles": 7})] and dog.warm
