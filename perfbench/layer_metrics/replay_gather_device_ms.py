@@ -1,0 +1,8 @@
+"""Device time of one ``ring_gather_sequences`` execution (one replay batch,
+one a gradient step), from the ``XLA Modules`` line of the traced stretch."""
+
+from perfbench import device_time
+
+
+def read(run):
+    return device_time.program_ms(device_time.of_run(run), "ring_gather_sequences")

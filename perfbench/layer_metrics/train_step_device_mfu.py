@@ -1,0 +1,13 @@
+"""The train step's share of the chip's peak inside the step: the configuration's
+model FLOPs per gradient step over ``train_step.device_ms`` times the chip's
+bf16 peak. ``train_step.mfu`` is the share of the wall clock."""
+
+from perfbench import device_time
+
+
+def read(run):
+    ms = device_time.program_ms(device_time.of_run(run), device_time.TRAIN)
+    if ms is None or run.peak is None:
+        return None
+    flops = run.cell.config["model_flops_per_grad_step"]
+    return 100.0 * flops / (ms / 1e3 * run.peak["bf16_flops_per_s"] * run.cell.chips)

@@ -503,13 +503,15 @@ class WorldModel(nn.Module):
         """Policy-time posterior update: encode a single obs and run one
         dynamic-like step WITHOUT is_first gating (the player resets its own
         states — reference PlayerDV3.get_actions, agent.py:661-691)."""
-        embedded = self.encode(obs)
-        h = self.recurrent_model(jnp.concatenate([z, action], axis=-1).astype(self.dtype), h)
-        post_in = jnp.concatenate([h, embedded], axis=-1)
-        post_logits = _uniform_mix(
-            self.representation_model(post_in.astype(self.dtype)), self.discrete_size, self.unimix
-        )
-        z = compute_stochastic_state(post_logits, key)
+        with jax.named_scope("dv3/player/encode"):
+            embedded = self.encode(obs)
+        with jax.named_scope("dv3/player/rssm"):
+            h = self.recurrent_model(jnp.concatenate([z, action], axis=-1).astype(self.dtype), h)
+            post_in = jnp.concatenate([h, embedded], axis=-1)
+            post_logits = _uniform_mix(
+                self.representation_model(post_in.astype(self.dtype)), self.discrete_size, self.unimix
+            )
+            z = compute_stochastic_state(post_logits, key)
         return z, h
 
 
@@ -767,21 +769,27 @@ class PlayerDV3(HostPlayerParams):
         self.z: Optional[Any] = None  # device [E, S]
         self.actions: Optional[Any] = None  # device [E, A]
 
-        def _step(wm_params, actor_params, obs, h, z, prev_action, key, greedy):
+        # the jitted programs carry stable names of their own (the XLA module
+        # is ``jit_<name>``; howto/telemetry.md lists them), and the step's
+        # three parts a ``jax.named_scope`` each: ``dv3/player/encode`` and
+        # ``dv3/player/rssm`` (in ``WorldModel.observe_step``), ``dv3/player/actor``
+        def dv3_player_step(wm_params, actor_params, obs, h, z, prev_action, key, greedy):
             k1, k2 = jax.random.split(key)
             z, h = wm.apply(wm_params, z, h, prev_action, obs, k1, method=WorldModel.observe_step)
-            latent = jnp.concatenate([z, h], axis=-1)
-            action = sample_actor_actions(actor, actor_params, latent, k2, greedy)
+            with jax.named_scope("dv3/player/actor"):
+                latent = jnp.concatenate([z, h], axis=-1)
+                action = sample_actor_actions(actor, actor_params, latent, k2, greedy)
             return action, h, z
 
-        def _step_masked(wm_params, actor_params, obs, h, z, prev_action, key, mask, greedy):
+        def dv3_player_step_masked(wm_params, actor_params, obs, h, z, prev_action, key, mask, greedy):
             k1, k2 = jax.random.split(key)
             z, h = wm.apply(wm_params, z, h, prev_action, obs, k1, method=WorldModel.observe_step)
-            latent = jnp.concatenate([z, h], axis=-1)
-            action = sample_minedojo_actions(actor, actor_params, latent, k2, mask, greedy)
+            with jax.named_scope("dv3/player/actor"):
+                latent = jnp.concatenate([z, h], axis=-1)
+                action = sample_minedojo_actions(actor, actor_params, latent, k2, mask, greedy)
             return action, h, z
 
-        def _masked_reset(wm_params, h, z, actions, mask):
+        def dv3_player_reset(wm_params, h, z, actions, mask):
             # mask [E, 1]: 1 where the env restarts
             h0, z0 = wm.apply(wm_params, (h.shape[0],), method=WorldModel.initial_state)
             return (
@@ -790,12 +798,13 @@ class PlayerDV3(HostPlayerParams):
                 jnp.where(mask, 0.0, actions),
             )
 
-        self._step = jax.jit(_step, static_argnames="greedy")
-        self._step_masked = jax.jit(_step_masked, static_argnames="greedy")
-        self._initial = jax.jit(
-            lambda p, n: wm.apply(p, (n,), method=WorldModel.initial_state), static_argnums=1
-        )
-        self._masked_reset = jax.jit(_masked_reset)
+        def dv3_player_initial(wm_params, n):
+            return wm.apply(wm_params, (n,), method=WorldModel.initial_state)
+
+        self._step = jax.jit(dv3_player_step, static_argnames="greedy")
+        self._step_masked = jax.jit(dv3_player_step_masked, static_argnames="greedy")
+        self._initial = jax.jit(dv3_player_initial, static_argnums=1)
+        self._masked_reset = jax.jit(dv3_player_reset)
 
     def update_params(self, wm_params: Any, actor_params: Any) -> None:
         """Refresh the player's weights from the learner's. In host-player

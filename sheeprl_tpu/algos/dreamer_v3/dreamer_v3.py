@@ -116,7 +116,15 @@ def make_train_step(
     sequence batch (replaces reference train(), dreamer_v3.py:48-354).
     Returns ``(local_train, use_shard_map)`` — :func:`make_train_fn` wraps it
     in shard_map/jit for the per-step path, :func:`make_fused_train_fn`
-    scans it inside one fused superstep dispatch."""
+    scans it inside one fused superstep dispatch.
+
+    The body's parts carry one ``jax.named_scope`` each, so a device trace
+    says where the step's time goes (howto/telemetry.md lists the names):
+    ``dv3/wm/encode``, ``dv3/wm/rssm_scan``, ``dv3/wm/decode``,
+    ``dv3/wm/optimizer``, ``dv3/behaviour/imagine``,
+    ``dv3/behaviour/actor_loss``, ``dv3/behaviour/optimizer``,
+    ``dv3/critic/loss``, ``dv3/critic/optimizer``. A scope's backward ops
+    carry it inside ``transpose(jvp(...))``."""
     algo = cfg.algo
     wmc = algo.world_model
     cnn_keys = tuple(algo.cnn_keys.encoder)
@@ -175,39 +183,43 @@ def make_train_step(
 
         # ---------------- world model step (Eq. 4/5) ---------------- #
         def world_loss_fn(p):
-            embedded = wm.apply(p, batch_obs, method=WorldModel.encode)
-            hs, zs, post_logits, prior_logits = rssm_scan(wm, p, embedded, batch_actions, is_first, k_scan)
-            latents = jnp.concatenate([zs, hs], axis=-1)
-            recon = wm.apply(p, latents, method=WorldModel.decode)
-            po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec_keys}
-            po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec_keys})
-            pr = TwoHotEncodingDistribution(wm.apply(p, latents, method=WorldModel.reward_logits), dims=1)
-            pc = Independent(Bernoulli(logits=wm.apply(p, latents, method=WorldModel.continue_logits)), 1)
-            loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po,
-                obs_targets,
-                pr,
-                data["rewards"],
-                prior_logits,
-                post_logits,
-                kl_dynamic,
-                kl_representation,
-                kl_free_nats,
-                kl_regularizer,
-                pc,
-                1 - data["terminated"],
-                continue_scale,
-            )
+            with jax.named_scope("dv3/wm/encode"):
+                embedded = wm.apply(p, batch_obs, method=WorldModel.encode)
+            with jax.named_scope("dv3/wm/rssm_scan"):
+                hs, zs, post_logits, prior_logits = rssm_scan(wm, p, embedded, batch_actions, is_first, k_scan)
+            with jax.named_scope("dv3/wm/decode"):
+                latents = jnp.concatenate([zs, hs], axis=-1)
+                recon = wm.apply(p, latents, method=WorldModel.decode)
+                po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec_keys}
+                po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec_keys})
+                pr = TwoHotEncodingDistribution(wm.apply(p, latents, method=WorldModel.reward_logits), dims=1)
+                pc = Independent(Bernoulli(logits=wm.apply(p, latents, method=WorldModel.continue_logits)), 1)
+                loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                    po,
+                    obs_targets,
+                    pr,
+                    data["rewards"],
+                    prior_logits,
+                    post_logits,
+                    kl_dynamic,
+                    kl_representation,
+                    kl_free_nats,
+                    kl_regularizer,
+                    pc,
+                    1 - data["terminated"],
+                    continue_scale,
+                )
             aux = (hs, zs, post_logits, prior_logits, kl, state_loss, reward_loss, observation_loss, continue_loss)
             return loss, aux
 
         (rec_loss, aux), wm_grads = jax.value_and_grad(world_loss_fn, has_aux=True)(wm_params)
         hs, zs, post_logits, prior_logits = aux[:4]
         kl, state_loss, reward_loss, observation_loss, continue_loss = aux[4:]
-        wm_grads = pmean(wm_grads)
-        wm_gnorm = optax.global_norm(wm_grads)
-        wm_updates, world_opt = world_tx.update(wm_grads, world_opt, wm_params)
-        wm_params = optax.apply_updates(wm_params, wm_updates)
+        with jax.named_scope("dv3/wm/optimizer"):
+            wm_grads = pmean(wm_grads)
+            wm_gnorm = optax.global_norm(wm_grads)
+            wm_updates, world_opt = world_tx.update(wm_grads, world_opt, wm_params)
+            wm_params = optax.apply_updates(wm_params, wm_updates)
 
         # ---------------- behaviour learning ---------------- #
         # imagination starts from every (t, b) posterior, flattened
@@ -219,7 +231,6 @@ def make_train_step(
             """Imagination rollout (reference dreamer_v3.py:203-241):
             ``lats[i]`` is the i-th latent, ``acts[i]`` the action sampled at
             it; the scan body advances to ``lats[i+1]`` — H+1 entries."""
-            lat0 = jnp.concatenate([start_z, start_h], axis=-1)
 
             def step(carry, _):
                 z, h, lat, key = carry
@@ -229,69 +240,75 @@ def make_train_step(
                 new_lat = jnp.concatenate([z, h], axis=-1)
                 return (z, h, new_lat, key), (lat, action)
 
-            _, (lats, acts) = lax.scan(step, (start_z, start_h, lat0, key), None, length=horizon + 1)
+            with jax.named_scope("dv3/behaviour/imagine"):
+                lat0 = jnp.concatenate([start_z, start_h], axis=-1)
+                _, (lats, acts) = lax.scan(step, (start_z, start_h, lat0, key), None, length=horizon + 1)
             return lats, acts
 
         def actor_loss_fn(p):
             trajectories, imagined_actions = imagine(p, k_img)  # [H+1, N, L] / [H+1, N, A]
 
-            values = TwoHotEncodingDistribution(critic.apply(critic_params, trajectories), dims=1).mean
-            rewards = TwoHotEncodingDistribution(
-                wm.apply(wm_params, trajectories, method=WorldModel.reward_logits), dims=1
-            ).mean
-            continues = Independent(
-                Bernoulli(logits=wm.apply(wm_params, trajectories, method=WorldModel.continue_logits)), 1
-            ).mode
-            continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
+            with jax.named_scope("dv3/behaviour/actor_loss"):
+                values = TwoHotEncodingDistribution(critic.apply(critic_params, trajectories), dims=1).mean
+                rewards = TwoHotEncodingDistribution(
+                    wm.apply(wm_params, trajectories, method=WorldModel.reward_logits), dims=1
+                ).mean
+                continues = Independent(
+                    Bernoulli(logits=wm.apply(wm_params, trajectories, method=WorldModel.continue_logits)), 1
+                ).mode
+                continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
 
-            lambda_values = compute_lambda_values(rewards[1:], values[1:], continues[1:] * gamma, lmbda)
-            discount = sg(jnp.cumprod(continues * gamma, axis=0) / gamma)
+                lambda_values = compute_lambda_values(rewards[1:], values[1:], continues[1:] * gamma, lmbda)
+                discount = sg(jnp.cumprod(continues * gamma, axis=0) / gamma)
 
-            new_moments, (offset, invscale) = update_moments(
-                moments_state,
-                lambda_values,
-                decay=float(moments_cfg.decay),
-                max_=float(moments_cfg.max),
-                percentile_low=float(moments_cfg.percentile.low),
-                percentile_high=float(moments_cfg.percentile.high),
-                axis_name=data_axis if use_shard_map else None,
-            )
-            baseline = values[:-1]
-            normed_lambda = (lambda_values - offset) / invscale
-            normed_baseline = (baseline - offset) / invscale
-            advantage = normed_lambda - normed_baseline
-            logp, entropy = actor_logprob_entropy(actor, p, sg(trajectories), sg(imagined_actions))
-            if is_continuous:
-                objective = advantage
-            else:
-                objective = logp[..., None][:-1] * sg(advantage)
-            policy_loss = -jnp.mean(
-                sg(discount[:-1]) * (objective + ent_coef * entropy[..., None][:-1])
-            )
+                new_moments, (offset, invscale) = update_moments(
+                    moments_state,
+                    lambda_values,
+                    decay=float(moments_cfg.decay),
+                    max_=float(moments_cfg.max),
+                    percentile_low=float(moments_cfg.percentile.low),
+                    percentile_high=float(moments_cfg.percentile.high),
+                    axis_name=data_axis if use_shard_map else None,
+                )
+                baseline = values[:-1]
+                normed_lambda = (lambda_values - offset) / invscale
+                normed_baseline = (baseline - offset) / invscale
+                advantage = normed_lambda - normed_baseline
+                logp, entropy = actor_logprob_entropy(actor, p, sg(trajectories), sg(imagined_actions))
+                if is_continuous:
+                    objective = advantage
+                else:
+                    objective = logp[..., None][:-1] * sg(advantage)
+                policy_loss = -jnp.mean(
+                    sg(discount[:-1]) * (objective + ent_coef * entropy[..., None][:-1])
+                )
             return policy_loss, (trajectories, lambda_values, discount, new_moments)
 
         (policy_loss, (trajectories, lambda_values, discount, moments_state)), actor_grads = jax.value_and_grad(
             actor_loss_fn, has_aux=True
         )(actor_params)
-        actor_grads = pmean(actor_grads)
-        actor_gnorm = optax.global_norm(actor_grads)
-        actor_updates, actor_opt = actor_tx.update(actor_grads, actor_opt, actor_params)
-        actor_params = optax.apply_updates(actor_params, actor_updates)
+        with jax.named_scope("dv3/behaviour/optimizer"):
+            actor_grads = pmean(actor_grads)
+            actor_gnorm = optax.global_norm(actor_grads)
+            actor_updates, actor_opt = actor_tx.update(actor_grads, actor_opt, actor_params)
+            actor_params = optax.apply_updates(actor_params, actor_updates)
 
         # ---------------- critic step (Eq. 10) ---------------- #
-        traj_in = sg(trajectories[:-1])
-        target_values = TwoHotEncodingDistribution(critic.apply(target_params, traj_in), dims=1).mean
+        with jax.named_scope("dv3/critic/loss"):
+            traj_in = sg(trajectories[:-1])
+            target_values = TwoHotEncodingDistribution(critic.apply(target_params, traj_in), dims=1).mean
 
-        def critic_loss_fn(p):
-            qv = TwoHotEncodingDistribution(critic.apply(p, traj_in), dims=1)
-            value_loss = -qv.log_prob(sg(lambda_values)) - qv.log_prob(sg(target_values))
-            return jnp.mean(value_loss * sg(discount[:-1]).squeeze(-1))
+            def critic_loss_fn(p):
+                qv = TwoHotEncodingDistribution(critic.apply(p, traj_in), dims=1)
+                value_loss = -qv.log_prob(sg(lambda_values)) - qv.log_prob(sg(target_values))
+                return jnp.mean(value_loss * sg(discount[:-1]).squeeze(-1))
 
-        value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(critic_params)
-        critic_grads = pmean(critic_grads)
-        critic_gnorm = optax.global_norm(critic_grads)
-        critic_updates, critic_opt = critic_tx.update(critic_grads, critic_opt, critic_params)
-        critic_params = optax.apply_updates(critic_params, critic_updates)
+            value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(critic_params)
+        with jax.named_scope("dv3/critic/optimizer"):
+            critic_grads = pmean(critic_grads)
+            critic_gnorm = optax.global_norm(critic_grads)
+            critic_updates, critic_opt = critic_tx.update(critic_grads, critic_opt, critic_params)
+            critic_params = optax.apply_updates(critic_params, critic_updates)
 
         post_ent = Independent(OneHotCategorical(logits=sg(post_logits)), 1).entropy().mean()
         prior_ent = Independent(OneHotCategorical(logits=sg(prior_logits)), 1).entropy().mean()
@@ -361,7 +378,12 @@ def make_train_fn(
     # concurrent readers (async param streaming to the host player, the ema /
     # hard-copy target refresh) may still be in flight when the next train
     # dispatch would otherwise alias over them
-    return jax.jit(train_fn, donate_argnums=(4, 5, 6, 7))
+    def dv3_train_step(*args):
+        # the jitted function's name is the XLA module's (``jit_dv3_train_step``):
+        # what a device trace files the step's ops under
+        return train_fn(*args)
+
+    return jax.jit(dv3_train_step, donate_argnums=(4, 5, 6, 7))
 
 
 def make_fused_train_fn(
@@ -455,6 +477,13 @@ def make_fused_train_fn(
         cache_tag="superstep.dreamer_v3",
         cache_fingerprint=cache_fingerprint,
     )
+
+
+@jax.jit
+def dv3_target_ema(cp, tcp, tau):
+    """EMA update for the target critic (reference dreamer_v3.py:670-675);
+    ``jit_dv3_target_ema`` in a device trace."""
+    return jax.tree.map(lambda c, t: tau * c + (1 - tau) * t, cp, tcp)
 
 
 @register_algorithm()
@@ -571,11 +600,6 @@ def main(fabric, cfg: Dict[str, Any]):
             memmap=cfg.buffer.memmap,
             memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}"),
         )
-
-    # EMA update for the target critic (reference dreamer_v3.py:670-675)
-    @jax.jit
-    def ema(cp, tcp, tau):
-        return jax.tree.map(lambda c, t: tau * c + (1 - tau) * t, cp, tcp)
 
     train_fn = make_train_fn(
         fabric, wm, actor, critic, world_tx, actor_tx, critic_tx, cfg, is_continuous, actions_dim
@@ -846,7 +870,9 @@ def main(fabric, cfg: Dict[str, Any]):
                 player_key, action_key = jax.random.split(player_key)
                 prepared = prepare_obs(obs, cnn_keys=cnn_keys, num_envs=num_envs)
                 mask = {k: v for k, v in prepared.items() if k.startswith("mask")}
-                actions = player.get_actions(prepared, action_key, mask=mask or None)
+                with timer("player/get_actions"):
+                    # player dispatch and the action's device_get: the turn's one sync
+                    actions = player.get_actions(prepared, action_key, mask=mask or None)
                 if is_continuous:
                     real_actions = actions
                 else:
@@ -858,78 +884,84 @@ def main(fabric, cfg: Dict[str, Any]):
                         real_actions = real_actions[..., 0]
 
             step_data["actions"] = np.asarray(actions, np.float32).reshape(1, num_envs, -1)
-            rb.add(step_data, validate_args=cfg.buffer.validate_args)
+            with timer("ring/add"):
+                rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                real_actions.reshape(envs.action_space.shape)
-            )
+            with timer("env/step"):
+                next_obs, rewards, terminated, truncated, infos = envs.step(
+                    real_actions.reshape(envs.action_space.shape)
+                )
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            for i, roe in enumerate(np.asarray(infos["restart_on_exception"]).reshape(-1)):
-                if roe and not dones[i]:
-                    # patch the last stored step to a truncation and restart the
-                    # episode (reference dreamer_v3.py:591-604)
-                    if use_device_rb:
-                        rb.amend_last(i, terminated=0.0, truncated=1.0, is_first=0.0)
-                    else:
-                        sub = rb.buffer[i]
-                        last_idx = (sub._pos - 1) % sub.buffer_size
-                        sub["terminated"][last_idx] = 0.0
-                        sub["truncated"][last_idx] = 1.0
-                        sub["is_first"][last_idx] = 0.0
-                    step_data["is_first"][0, i] = 1.0
+        # host work between the env step and the train section: final-obs copy,
+        # prepare_obs, the next step_data, the reset add, the player's reset
+        with timer("loop/store_step"):
+            step_data["is_first"] = np.zeros_like(step_data["terminated"])
+            if "restart_on_exception" in infos:
+                for i, roe in enumerate(np.asarray(infos["restart_on_exception"]).reshape(-1)):
+                    if roe and not dones[i]:
+                        # patch the last stored step to a truncation and restart the
+                        # episode (reference dreamer_v3.py:591-604)
+                        if use_device_rb:
+                            rb.amend_last(i, terminated=0.0, truncated=1.0, is_first=0.0)
+                        else:
+                            sub = rb.buffer[i]
+                            last_idx = (sub._pos - 1) % sub.buffer_size
+                            sub["terminated"][last_idx] = 0.0
+                            sub["truncated"][last_idx] = 1.0
+                            sub["is_first"][last_idx] = 0.0
+                        step_data["is_first"][0, i] = 1.0
 
-        if cfg.metric.log_level > 0 and "final_info" in infos:
-            ep = infos["final_info"].get("episode")
-            if ep is not None:
-                for i in np.nonzero(ep.get("_r", []))[0]:
-                    aggregator.update("Rewards/rew_avg", float(ep["r"][i]))
-                    aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
-                    print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep['r'][i]}")
+            if cfg.metric.log_level > 0 and "final_info" in infos:
+                ep = infos["final_info"].get("episode")
+                if ep is not None:
+                    for i in np.nonzero(ep.get("_r", []))[0]:
+                        aggregator.update("Rewards/rew_avg", float(ep["r"][i]))
+                        aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
+                        print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep['r'][i]}")
 
-        # the final obs of finished episodes (SAME_STEP autoreset provides it)
-        real_next_obs = {k: np.asarray(v).copy() for k, v in next_obs.items()}
-        if "final_obs" in infos:
-            for idx, final_obs in enumerate(infos["final_obs"]):
-                if final_obs is not None:
-                    for k, v in final_obs.items():
-                        real_next_obs[k][idx] = v
+            # the final obs of finished episodes (SAME_STEP autoreset provides it)
+            real_next_obs = {k: np.asarray(v).copy() for k, v in next_obs.items()}
+            if "final_obs" in infos:
+                for idx, final_obs in enumerate(infos["final_obs"]):
+                    if final_obs is not None:
+                        for k, v in final_obs.items():
+                            real_next_obs[k][idx] = v
 
-        prepared_next = prepare_obs(next_obs, cnn_keys=cnn_keys, num_envs=num_envs)
-        for k in obs_keys:
-            step_data[k] = prepared_next[k][np.newaxis]
-        obs = next_obs
+            prepared_next = prepare_obs(next_obs, cnn_keys=cnn_keys, num_envs=num_envs)
+            for k in obs_keys:
+                step_data[k] = prepared_next[k][np.newaxis]
+            obs = next_obs
 
-        rewards = np.asarray(rewards, np.float32).reshape(1, num_envs, 1)
-        step_data["terminated"] = np.asarray(terminated, np.float32).reshape(1, num_envs, 1)
-        step_data["truncated"] = np.asarray(truncated, np.float32).reshape(1, num_envs, 1)
-        step_data["rewards"] = clip_rewards_fn(rewards)
+            rewards = np.asarray(rewards, np.float32).reshape(1, num_envs, 1)
+            step_data["terminated"] = np.asarray(terminated, np.float32).reshape(1, num_envs, 1)
+            step_data["truncated"] = np.asarray(truncated, np.float32).reshape(1, num_envs, 1)
+            step_data["rewards"] = clip_rewards_fn(rewards)
 
-        dones_idxes = dones.nonzero()[0].tolist()
-        if dones_idxes:
-            # store the terminal transition with the true final obs, zero
-            # action, then reset per-env episode state
-            # (reference dreamer_v3.py:635-653)
-            prepared_final = prepare_obs(
-                {k: real_next_obs[k][dones_idxes] for k in obs_keys},
-                cnn_keys=cnn_keys,
-                num_envs=len(dones_idxes),
-            )
-            reset_data = {k: prepared_final[k][np.newaxis] for k in obs_keys}
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, len(dones_idxes), int(np.sum(actions_dim))), np.float32)
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+            dones_idxes = dones.nonzero()[0].tolist()
+            if dones_idxes:
+                # store the terminal transition with the true final obs, zero
+                # action, then reset per-env episode state
+                # (reference dreamer_v3.py:635-653)
+                prepared_final = prepare_obs(
+                    {k: real_next_obs[k][dones_idxes] for k in obs_keys},
+                    cnn_keys=cnn_keys,
+                    num_envs=len(dones_idxes),
+                )
+                reset_data = {k: prepared_final[k][np.newaxis] for k in obs_keys}
+                reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
+                reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
+                reset_data["actions"] = np.zeros((1, len(dones_idxes), int(np.sum(actions_dim))), np.float32)
+                reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
+                reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
+                with timer("ring/add"):
+                    rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
 
-            step_data["rewards"][:, dones_idxes] = 0.0
-            step_data["terminated"][:, dones_idxes] = 0.0
-            step_data["truncated"][:, dones_idxes] = 0.0
-            step_data["is_first"][:, dones_idxes] = 1.0
-            player.init_states(dones_idxes)
+                step_data["rewards"][:, dones_idxes] = 0.0
+                step_data["terminated"][:, dones_idxes] = 0.0
+                step_data["truncated"][:, dones_idxes] = 0.0
+                step_data["is_first"][:, dones_idxes] = 1.0
+                player.init_states(dones_idxes)
 
         # ---------------- training ---------------- #
         if update >= learning_starts:
@@ -967,15 +999,16 @@ def main(fabric, cfg: Dict[str, Any]):
                                 (params, aux, counter, ctx, key),
                             )
                             bench_superstep = (superstep, chunk, shapes)
-                        if resil.finite_checks:
-                            # the sentinel rides the same dispatch: a [chunk]
-                            # finite vector instead of an extra program
-                            params, aux, key, metrics, chunk_finite = superstep(
-                                params, aux, counter, ctx, key
-                            )
-                            window_finite.append(chunk_finite)
-                        else:
-                            params, aux, key, metrics = superstep(params, aux, counter, ctx, key)
+                        with timer("train/dispatch"):
+                            if resil.finite_checks:
+                                # the sentinel rides the same dispatch: a [chunk]
+                                # finite vector instead of an extra program
+                                params, aux, key, metrics, chunk_finite = superstep(
+                                    params, aux, counter, ctx, key
+                                )
+                                window_finite.append(chunk_finite)
+                            else:
+                                params, aux, key, metrics = superstep(params, aux, counter, ctx, key)
                         wm_params, actor_params, critic_params, target_critic_params = params
                         world_opt, actor_opt, critic_opt, moments_state = aux
                         cumulative_per_rank_gradient_steps += chunk
@@ -985,7 +1018,8 @@ def main(fabric, cfg: Dict[str, Any]):
                             # per log interval for the whole window
                             pending_metrics.append(metrics)
                     if not timer.disabled:
-                        jax.block_until_ready(wm_params)
+                        with timer("train/block"):
+                            jax.block_until_ready(wm_params)
                     train_step += num_processes
                 telemetry_train_window(window_dispatches, per_rank_gradient_steps)
                 player.update_params(wm_params, actor_params)
@@ -1019,30 +1053,31 @@ def main(fabric, cfg: Dict[str, Any]):
                             == 0
                         ):
                             tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else float(cfg.algo.critic.tau)
-                            target_critic_params = ema(critic_params, target_critic_params, tau)
+                            target_critic_params = dv3_target_ema(critic_params, target_critic_params, tau)
                             window_ema_dispatches += 1
                         key, train_key = jax.random.split(key)
-                        (
-                            wm_params,
-                            actor_params,
-                            critic_params,
-                            world_opt,
-                            actor_opt,
-                            critic_opt,
-                            moments_state,
-                            metrics,
-                        ) = train_fn(
-                            wm_params,
-                            actor_params,
-                            critic_params,
-                            target_critic_params,
-                            world_opt,
-                            actor_opt,
-                            critic_opt,
-                            moments_state,
-                            batch,
-                            train_key,
-                        )
+                        with timer("train/dispatch"):
+                            (
+                                wm_params,
+                                actor_params,
+                                critic_params,
+                                world_opt,
+                                actor_opt,
+                                critic_opt,
+                                moments_state,
+                                metrics,
+                            ) = train_fn(
+                                wm_params,
+                                actor_params,
+                                critic_params,
+                                target_critic_params,
+                                world_opt,
+                                actor_opt,
+                                critic_opt,
+                                moments_state,
+                                batch,
+                                train_key,
+                            )
                         cumulative_per_rank_gradient_steps += 1
                         if probe.active and bench_batch is None:
                             bench_batch = batch
@@ -1063,8 +1098,12 @@ def main(fabric, cfg: Dict[str, Any]):
                             )
                     if not timer.disabled:
                         # only when timing: wait so Time/train_time measures
-                        # the chip, not the async dispatch
-                        jax.block_until_ready(wm_params)
+                        # the chip, not the async dispatch. What the wait is
+                        # for is whatever the device still has queued: the
+                        # turn's ring write and the gathers as well as the
+                        # train steps (``train/block`` is the host's wait)
+                        with timer("train/block"):
+                            jax.block_until_ready(wm_params)
                     train_step += num_processes
                 # per-step dispatch shape: one train call per gradient step,
                 # plus the on-device gather per batch and the EMA refreshes

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.obs.span import span
 from sheeprl_tpu.parallel.shard_map import shard_map
 
 
@@ -341,6 +342,12 @@ class DeviceReplayBuffer:
         self._build_kernels()
 
     def _build_kernels(self) -> None:
+        # Each jitted program carries a stable name of its own (the XLA
+        # module is ``jit_<name>``), so a device trace says which program an
+        # op belongs to: ``ring_write``, ``ring_amend``,
+        # ``ring_gather_sequences``, ``ring_gather_transitions`` and
+        # ``ring_gather_transitions_next`` (howto/telemetry.md lists them).
+        #
         # under shard_map every operand arrives as its per-device block, so
         # the kernels index with the LOCAL env count — per-device cursor
         # arithmetic falls out of the same code that serves the 1-device ring
@@ -349,7 +356,7 @@ class DeviceReplayBuffer:
         pixel_keys = self._pixel_keys
         small_keys = self._small_keys
 
-        def write(bufs, pixels, smalls, pos):
+        def ring_write(bufs, pixels, smalls, pos):
             env_ids = jnp.arange(n_envs)
             out = dict(bufs)
             for k in pixel_keys:
@@ -362,14 +369,20 @@ class DeviceReplayBuffer:
 
         obs_keys = self._obs_keys
 
-        def gather_transitions_next(bufs, env_idx, time_idx, next_idx):
-            out = {k: b[env_idx, time_idx] for k, b in bufs.items()}
+        def ring_gather_sequences(bufs, env_idx, time_idx):
+            return gather_sequences(bufs, env_idx, time_idx)
+
+        def ring_gather_transitions(bufs, env_idx, time_idx):
+            return gather_transition_items(bufs, env_idx, time_idx)
+
+        def ring_gather_transitions_next(bufs, env_idx, time_idx, next_idx):
+            out = gather_transition_items(bufs, env_idx, time_idx)
             for k in obs_keys:
                 if k in bufs:
                     out[f"next_{k}"] = bufs[k][env_idx, next_idx]
             return out
 
-        def amend(bufs, env_i, slot, terminated, truncated, is_first):
+        def ring_amend(bufs, env_i, slot, terminated, truncated, is_first):
             out = dict(bufs)
             for k, v in (("terminated", terminated), ("truncated", truncated), ("is_first", is_first)):
                 if k in out:
@@ -378,53 +391,44 @@ class DeviceReplayBuffer:
                     )
             return out
 
-        import os
-
-        gather_seq = gather_sequences
-        gather_items = gather_transition_items
-        gather_next = gather_transitions_next
         if self.sharded:
             mesh, ax = self._mesh, self._data_axis
             # write: every operand (ring, staging arrays, cursor vector) is
             # env-axis sharded, so each device scatters its own env block —
             # no collective appears in the program
-            write = shard_map(write, mesh, in_specs=(P(ax), P(ax), P(ax), P(ax)), out_specs=P(ax))
+            ring_write = shard_map(ring_write, mesh, in_specs=(P(ax), P(ax), P(ax), P(ax)), out_specs=P(ax))
             # host-path gathers: the draw is stratified per shard (see
             # draw_indices), index arrays arrive batch-axis sharded with
             # SHARD-LOCAL env ids, and the batch comes out pre-sharded along
             # the batch axis — exactly the layout the data-parallel train
             # step consumes
-            gather_seq = shard_map(
-                gather_seq, mesh, in_specs=(P(ax), P(ax), P(ax)), out_specs=P(None, ax)
+            ring_gather_sequences = shard_map(
+                ring_gather_sequences, mesh, in_specs=(P(ax), P(ax), P(ax)), out_specs=P(None, ax)
             )
-            gather_items = shard_map(
-                gather_items, mesh, in_specs=(P(ax), P(None, ax), P(None, ax)), out_specs=P(None, ax)
+            ring_gather_transitions = shard_map(
+                ring_gather_transitions,
+                mesh,
+                in_specs=(P(ax), P(None, ax), P(None, ax)),
+                out_specs=P(None, ax),
             )
-            gather_next = shard_map(
-                gather_next,
+            ring_gather_transitions_next = shard_map(
+                ring_gather_transitions_next,
                 mesh,
                 in_specs=(P(ax), P(None, ax), P(None, ax), P(None, ax)),
                 out_specs=P(None, ax),
             )
 
-        if os.environ.get("SHEEPRL_TPU_RING_NO_DONATE"):
-            # debug switch: in-place aliasing off — every write copies the ring
-            self._write = jax.jit(write)
-        else:
-            self._write = jax.jit(write, donate_argnums=0)
+        # writes donate the ring: XLA aliases the update in place
+        self._write = jax.jit(ring_write, donate_argnums=0)
         # amend is the rare failure-recovery patch path (one env, one slot):
         # on a sharded ring the plain jit lets GSPMD route the scalar scatter
         # to whichever shard owns the env row — not worth a shard_map
-        self._amend = (
-            jax.jit(amend)
-            if os.environ.get("SHEEPRL_TPU_RING_NO_DONATE")
-            else jax.jit(amend, donate_argnums=0)
-        )
-        # the gathers are the module-level pure kernels (also callable from
+        self._amend = jax.jit(ring_amend, donate_argnums=0)
+        # the gathers wrap the module-level pure kernels (also callable from
         # inside a fused superstep's scan body), jitted here for the host paths
-        self._gather = jax.jit(gather_seq)
-        self._gather_transitions = jax.jit(gather_items)
-        self._gather_transitions_next = jax.jit(gather_next)
+        self._gather = jax.jit(ring_gather_sequences)
+        self._gather_transitions = jax.jit(ring_gather_transitions)
+        self._gather_transitions_next = jax.jit(ring_gather_transitions_next)
 
     # ------------------------------------------------------------------ write
     def add(
@@ -584,17 +588,21 @@ class DeviceReplayBuffer:
             raise ValueError(f"'n_samples' ({n_samples}) must be greater than 0")
         offsets = np.arange(sequence_length, dtype=np.int64)
         for _ in range(n_samples):
-            env_idx, starts = self.draw_indices(batch_size, sequence_length)
-            time_idx = (starts[:, None] + offsets[None, :]) % self._buffer_size
-            if self.sharded:
-                # the sharded gather indexes each device's env block, so the
-                # (per-block stratified) env ids are rebased shard-locally
-                env_idx = env_idx % (self._n_envs // self._n_shards)
-            ei, ti = jax.device_put(
-                (env_idx.astype(np.int32), time_idx.astype(np.int32)),
-                self._sharding or self._device,
-            )
-            yield self._gather(self._bufs, ei, ti)
+            # host time only: index draw, device_put, dispatch of the gather
+            # (the gather's device time is ``ring_gather_sequences`` in a trace)
+            with span("replay/draw"):
+                env_idx, starts = self.draw_indices(batch_size, sequence_length)
+                time_idx = (starts[:, None] + offsets[None, :]) % self._buffer_size
+                if self.sharded:
+                    # the sharded gather indexes each device's env block, so the
+                    # (per-block stratified) env ids are rebased shard-locally
+                    env_idx = env_idx % (self._n_envs // self._n_shards)
+                ei, ti = jax.device_put(
+                    (env_idx.astype(np.int32), time_idx.astype(np.int32)),
+                    self._sharding or self._device,
+                )
+                batch = self._gather(self._bufs, ei, ti)
+            yield batch
 
     # ------------------------------------------------- transition sampling
     def _valid_items(self, env: int, sample_next_obs: bool) -> np.ndarray:
@@ -701,28 +709,30 @@ class DeviceReplayBuffer:
         this by construction."""
         if self._bufs is None:
             raise RuntimeError("The buffer has not been initialized. Try to add some data first.")
-        for env in range(self._n_envs):
-            if sequence_length is not None:
-                if len(self._valid_starts(env, int(sequence_length))) == 0:
+        # host time only: the validity check and the cursors' device_put
+        with span("replay/draw"):
+            for env in range(self._n_envs):
+                if sequence_length is not None:
+                    if len(self._valid_starts(env, int(sequence_length))) == 0:
+                        raise ValueError(
+                            f"Cannot sample a sequence of length {sequence_length} from env {env}. "
+                            f"Data added so far: {self._pos[env]}"
+                        )
+                elif len(self._valid_items(env, sample_next_obs)) == 0:
                     raise ValueError(
-                        f"Cannot sample a sequence of length {sequence_length} from env {env}. "
-                        f"Data added so far: {self._pos[env]}"
+                        "You want to sample the next observations, but not enough samples have been "
+                        f"added to env {env}. Make sure that at least two samples are added."
+                        if sample_next_obs
+                        else "No sample has been added to the buffer. Please add at least one sample "
+                        "calling 'self.add()'"
                     )
-            elif len(self._valid_items(env, sample_next_obs)) == 0:
-                raise ValueError(
-                    "You want to sample the next observations, but not enough samples have been "
-                    f"added to env {env}. Make sure that at least two samples are added."
-                    if sample_next_obs
-                    else "No sample has been added to the buffer. Please add at least one sample "
-                    "calling 'self.add()'"
-                )
-        # copies: on CPU device_put may alias the host mirrors zero-copy, and
-        # add() mutates them in place while the superstep is still queued.
-        # On a sharded ring the cursors land env-axis sharded like the bufs,
-        # so the superstep's shard_map hands each device its own cursor block
-        pos, full = jax.device_put(
-            (self._pos.astype(np.int32), self._full.copy()), self._sharding or self._device
-        )
+            # copies: on CPU device_put may alias the host mirrors zero-copy, and
+            # add() mutates them in place while the superstep is still queued.
+            # On a sharded ring the cursors land env-axis sharded like the bufs,
+            # so the superstep's shard_map hands each device its own cursor block
+            pos, full = jax.device_put(
+                (self._pos.astype(np.int32), self._full.copy()), self._sharding or self._device
+            )
         return self._bufs, pos, full
 
     def flag_last_truncated(self) -> Optional[np.ndarray]:

@@ -7,7 +7,14 @@ over this class) and, when run telemetry is configured, additionally:
 - wraps the block in ``jax.profiler.TraceAnnotation(name)`` so the section
   shows up by the same name in the XLA/Perfetto trace, and
 - emits one ``span`` JSON event per close to the per-process
-  ``telemetry.jsonl`` (name, t_start, dur, step, process_index, attrs).
+  ``telemetry.jsonl`` (name, t_start, t_mono_ns, dur, step, process_index,
+  attrs). ``t_mono_ns`` is ``time.monotonic_ns()`` at entry: the clock a
+  device trace is aligned to, which a wall-clock step cannot move.
+
+Spans nest: the Dreamer-V3 loop puts leaf spans (``player/get_actions``,
+``ring/add``, ``env/step``, ...; howto/telemetry.md has the vocabulary) inside
+its two window spans. A span never waits for the device: it times the host,
+and the device's own time is read from the profiler's trace by program name.
 
 With telemetry off the hot path is byte-for-byte the old timer plus a single
 module-global read, so ``metric.telemetry.enabled=False`` costs nothing.
@@ -45,6 +52,7 @@ class span(ContextDecorator):
         self.attrs = attrs
         self._start_time: Optional[float] = None
         self._wall_start: Optional[float] = None
+        self._mono_start_ns: Optional[int] = None
         self._annotation = None
         if not span.disabled and name is not None and name not in span.timers:
             span.timers[name] = make_metric(metric) if metric is not None else SumMetric()
@@ -78,6 +86,7 @@ class span(ContextDecorator):
         tel = get_telemetry()
         if tel is not None:
             self._wall_start = time.time()
+            self._mono_start_ns = time.monotonic_ns()
             self._annotation = tel.trace_annotation(self.name)
             if self._annotation is not None:
                 self._annotation.__enter__()
@@ -100,5 +109,6 @@ class span(ContextDecorator):
             self._annotation.__exit__(*exc_info)
             self._annotation = None
         if tel is not None and elapsed is not None:
-            tel.emit_span(self.name, self._wall_start, elapsed, self.attrs)
+            tel.emit_span(self.name, self._wall_start, elapsed, self.attrs, t_mono_ns=self._mono_start_ns)
         self._wall_start = None
+        self._mono_start_ns = None

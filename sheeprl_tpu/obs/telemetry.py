@@ -292,8 +292,19 @@ class RunTelemetry:
         if ring is not None:
             ring.append(record)
 
-    def emit_span(self, name: str, t_start: Optional[float], dur: float, attrs: Mapping[str, Any]) -> None:
+    def emit_span(
+        self,
+        name: str,
+        t_start: Optional[float],
+        dur: float,
+        attrs: Mapping[str, Any],
+        t_mono_ns: Optional[int] = None,
+    ) -> None:
         fields: Dict[str, Any] = {"t_start": t_start, "dur": dur}
+        if t_mono_ns is not None:
+            # the span's start on time.monotonic_ns(): the clock that env
+            # stamps and a device trace share (t_start is the wall clock)
+            fields["t_mono_ns"] = int(t_mono_ns)
         if attrs:
             fields["attrs"] = dict(attrs)
         self.emit("span", name=name, **fields)

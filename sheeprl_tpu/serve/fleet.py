@@ -750,8 +750,10 @@ class FleetServer:
             for slot in standby[: fleet.min_replicas - len(device_slots)]:
                 slot.retiring = False
                 slot.active = True
-                self._spawn(slot)
+                # counted before the spawn: the new replica may answer a
+                # request before this thread runs its next line
                 self.scale_ups += 1
+                self._spawn(slot)
                 self._event(
                     "fleet_scale_up",
                     {"replica": slot.index, "reason": "below_min_replicas"},
@@ -780,8 +782,8 @@ class FleetServer:
                 slot = standby[0]
                 slot.retiring = False
                 slot.active = True
-                self._spawn(slot)  # compiles its ladder before it is routable
                 self.scale_ups += 1
+                self._spawn(slot)  # compiles its ladder before it is routable
                 self._event(
                     "fleet_scale_up",
                     {"replica": slot.index, "depth_per_replica": depth_per},

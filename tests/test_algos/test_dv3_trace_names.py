@@ -1,0 +1,126 @@
+"""The names a device trace files the Dreamer-V3 path's work under
+(howto/telemetry.md, "Program and scope names"): every jitted program the loop
+dispatches is lowered at tiny widths and its own name, and each
+``jax.named_scope`` inside it, is found in the lowered text with debug info.
+Nothing runs and nothing compiles here."""
+
+import gymnasium as gym
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+#: program -> the scopes its ops carry
+NAMES = {
+    "dv3_train_step": (
+        "dv3/wm/encode",
+        "dv3/wm/rssm_scan",
+        "dv3/wm/decode",
+        "dv3/wm/optimizer",
+        "dv3/behaviour/imagine",
+        "dv3/behaviour/actor_loss",
+        "dv3/behaviour/optimizer",
+        "dv3/critic/loss",
+        "dv3/critic/optimizer",
+    ),
+    "dv3_player_step": ("dv3/player/encode", "dv3/player/rssm", "dv3/player/actor"),
+    "dv3_player_reset": (),
+    "dv3_target_ema": (),
+    "ring_write": (),
+    "ring_amend": (),
+    "ring_gather_sequences": (),
+}
+CASES = [(program, None) for program in NAMES] + [(p, scope) for p, scopes in NAMES.items() for scope in scopes]
+
+TINY = [
+    "exp=dreamer_v3",
+    "env=dummy",
+    "env.id=dummy_continuous",
+    "env.num_envs=2",
+    "env.screen_size=16",
+    "algo.per_rank_batch_size=2",
+    "algo.per_rank_sequence_length=4",
+    "algo.horizon=3",
+    "algo.dense_units=8",
+    "algo.mlp_layers=1",
+    "algo.world_model.encoder.cnn_channels_multiplier=2",
+    "algo.world_model.recurrent_model.recurrent_state_size=8",
+    "algo.world_model.representation_model.hidden_size=8",
+    "algo.world_model.transition_model.hidden_size=8",
+    "algo.world_model.discrete_size=4",
+    "algo.world_model.stochastic_size=4",
+    "algo.cnn_keys.encoder=[rgb]",
+    "algo.mlp_keys.encoder=[]",
+    "algo.cnn_keys.decoder=[rgb]",
+    "algo.mlp_keys.decoder=[]",
+    "fabric.accelerator=cpu",
+    "fabric.devices=1",
+]
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """``program name -> lowered text with debug info`` for the seven programs."""
+    from sheeprl_tpu.algos.dreamer_v3 import dreamer_v3 as program
+    from sheeprl_tpu.config import compose
+    from sheeprl_tpu.data.device_buffer import DeviceReplayBuffer
+    from sheeprl_tpu.ops.math import init_moments
+    from sheeprl_tpu.ops.optim import build_tx
+    from sheeprl_tpu.parallel.fabric import Fabric
+    from sheeprl_tpu.utils.utils import dotdict
+
+    cfg = dotdict(compose("config", TINY))
+    fabric = Fabric(devices=1, precision="fp32", accelerator="cpu")
+    envs, act_dim, size = 2, 3, 16
+    obs_space = gym.spaces.Dict({"rgb": gym.spaces.Box(0, 255, (size, size, 3), np.uint8)})
+    wm, wm_p, actor, actor_p, critic, critic_p, target_p, player = program.build_agent(fabric, (act_dim,), True, cfg, obs_space)
+    txs = [build_tx(cfg.algo[n].optimizer, cfg.algo[n].clip_gradients) for n in ("world_model", "actor", "critic")]
+    opts = [tx.init(p) for tx, p in zip(txs, (wm_p, actor_p, critic_p))]
+    train = program.make_train_fn(fabric, wm, actor, critic, *txs, cfg, True, (act_dim,))
+    T, B = 4, 2
+    batch = {
+        "rgb": jnp.zeros((T, B, size, size, 3), jnp.uint8),
+        "actions": jnp.zeros((T, B, act_dim)),
+        **{k: jnp.zeros((T, B, 1)) for k in ("rewards", "terminated", "truncated", "is_first")},
+    }
+    key = jax.random.PRNGKey(0)
+
+    def text(jitted, *args, **kwargs):
+        return jitted.lower(*args, **kwargs).as_text(debug_info=True)
+
+    out = {"dv3_train_step": text(train, wm_p, actor_p, critic_p, target_p, *opts, init_moments(), batch, key)}
+    player.init_states()
+    obs = {"rgb": jnp.zeros((envs, size, size, 3), jnp.uint8)}
+    out["dv3_player_step"] = text(player._step, player.wm_params, player.actor_params, obs, player.h, player.z, player.actions, key, greedy=False)
+    mask = np.zeros((envs, 1), np.float32)
+    out["dv3_player_reset"] = text(player._masked_reset, player.wm_params, player.h, player.z, player.actions, mask)
+    out["dv3_target_ema"] = text(program.dv3_target_ema, critic_p, target_p, 0.02)
+
+    rb = DeviceReplayBuffer(8, n_envs=envs, obs_keys=("rgb",))
+    step = {
+        "rgb": np.zeros((1, envs, size, size, 3), np.uint8),
+        "actions": np.zeros((1, envs, act_dim), np.float32),
+        **{k: np.zeros((1, envs, 1), np.float32) for k in ("rewards", "terminated", "truncated", "is_first")},
+    }
+    rb._allocate(step)
+    smalls = jnp.zeros((envs, sum(hi - lo for lo, hi, _ in rb._small_slices.values())))
+    out["ring_write"] = text(rb._write, rb._bufs, {"rgb": jnp.zeros((envs, size, size, 3), jnp.uint8)}, smalls, jnp.zeros((envs,), jnp.int32))
+    out["ring_amend"] = text(rb._amend, rb._bufs, jnp.int32(0), jnp.int32(0), jnp.float32(0), jnp.float32(1), jnp.float32(0))
+    out["ring_gather_sequences"] = text(rb._gather, rb._bufs, jnp.zeros((B,), jnp.int32), jnp.zeros((B, T), jnp.int32))
+    return out
+
+
+@pytest.mark.parametrize("program,scope", CASES, ids=[scope or program for program, scope in CASES])
+def test_the_lowered_program_carries_the_name(lowered, program, scope):
+    text = lowered[program]
+    assert f"@jit_{program} " in text, text[:200]
+    if scope is not None:
+        # the op_name path of an op: jit(<program>)/.../<scope>/...
+        assert f"/{scope}/" in text or f"{scope})" in text, f"no op of {program} is under {scope}"
+
+
+def test_a_scope_marks_its_backward_ops_too(lowered):
+    """The world model's scopes sit inside ``value_and_grad``: their forward
+    ops carry ``jvp(<scope>)`` and their backward ops ``transpose(jvp(<scope>))``."""
+    text = lowered["dv3_train_step"]
+    assert "transpose(jvp(dv3/wm/rssm_scan))" in text and "jvp(dv3/wm/rssm_scan)" in text

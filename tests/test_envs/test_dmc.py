@@ -14,6 +14,16 @@ import os
 os.environ.setdefault("MUJOCO_GL", "egl")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _tensorboard_before_egl():
+    """In one process, TensorFlow's extension modules (which the loggers'
+    ``torch.utils.tensorboard`` import pulls in) segfault at import once
+    mujoco's EGL stack is loaded. A CLI run builds its logger before its envs,
+    so the program never meets that order; an xdist worker that runs this file
+    and later its first CLI test does. Load them in the program's order."""
+    import torch.utils.tensorboard  # noqa: F401
+
+
 @pytest.fixture(scope="module")
 def vector_env():
     from sheeprl_tpu.envs.dmc import DMCWrapper
