@@ -5,13 +5,17 @@ One process, no arguments, one TPU chip. It drives the flagship recipe
 ratio 0.5, 64x64x3 pixels, batch 16 x sequence 64 — widths untouched) through
 the two normal entry points, in this order:
 
-1. ``kernel``  the compiled (Mosaic, not interpreted) Pallas RSSM step at S
+1. ``ring``    the replay ring's write and sequence gather compiled (nothing
+               allocated, nothing run) at the benchmark cells' ring sizes and
+               at the recipe's own 500,000 frames: no program may copy or
+               relayout the ring;
+2. ``kernel``  the compiled (Mosaic, not interpreted) Pallas RSSM step at S
                width against ``ops.pallas_gru.reference_step``, forward and
                gradient;
-2. ``train``   ``sheeprl_tpu.cli.run``: random-action prefill, then gradient
+3. ``train``   ``sheeprl_tpu.cli.run``: random-action prefill, then gradient
                steps past the recompile watchdog's warm point, two
                checkpoints;
-3. ``eval``    ``sheeprl_tpu.cli.evaluation`` of the last checkpoint.
+4. ``eval``    ``sheeprl_tpu.cli.evaluation`` of the last checkpoint.
 
 Every phase raises on the first thing that is not right, so the script exits
 non-zero; nothing here catches a phase's failure. It has no CPU mode: without
@@ -32,6 +36,7 @@ import glob
 import json
 import math
 import os
+import re
 import sys
 import time
 from typing import Any, Dict, List, Sequence
@@ -50,6 +55,16 @@ RECIPE = (
 
 #: Dreamer-V3 S RSSM step: 32x32 latents + a 6-d action in, dense 512, GRU 512
 S_KERNEL = {"in_dim": 32 * 32 + 6, "dense": 512, "hidden": 512}
+
+#: replay rings of 64x64x3 frames as ``(envs, slots an env, action width)``:
+#: the two benchmark cells' (``buffer.size`` 250,000) and the walker recipe's
+#: own 500,000 frames over its 4 envs (6.4 GB, which a program that copied
+#: the ring could not fit into the chip's 16 GB)
+RINGS = {
+    "dv3_S_walker.train": (4, 62_500, 6),
+    "dv3_XL_crafter.train": (1, 250_000, 17),
+    "walker recipe, buffer.size=500000": (4, 125_000, 6),
+}
 
 
 def say(msg: str) -> None:
@@ -79,6 +94,74 @@ def cache_since(watch, mark: Sequence[int] = (0, 0)) -> Dict[str, int]:
 # --------------------------------------------------------------------------- #
 # phase: kernel
 # --------------------------------------------------------------------------- #
+
+
+def ring_relayouts(hlo: str, slots: int) -> List[str]:
+    """Instructions of an optimised HLO module that produce an array with a
+    ring's slot dimension other than in place: anything but the parameters,
+    the (fused) ``dynamic-update-slice``s and the tuples that pass them on.
+    A ``copy`` here is a relayout of the whole ring."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if m is None or not re.search(rf"[\[,]{slots}[,\]]", m.group(2)):
+            continue
+        name, shape, op = m.groups()
+        in_place = op in ("parameter", "dynamic-update-slice", "tuple", "get-tuple-element", "bitcast")
+        if not in_place and not (op == "fusion" and "dynamic-update-slice" in name):
+            found.append(f"{op} {name} {shape}")
+    return found
+
+
+def describe_ring_programs(
+    n_envs: int, slots: int, action_dim: int, *, frame=(64, 64, 3), batch: int = 16, sequence: int = 64, device=None
+) -> Dict[str, Dict[str, Any]]:
+    """``ring_write`` and ``ring_gather_sequences`` of a Dreamer-style ring,
+    compiled from shapes alone: what ``memory_analysis()`` and the optimised
+    HLO say about each (``device``: the programs' target, default the first
+    attached one)."""
+    import jax
+    import numpy as np
+
+    from sheeprl_tpu.data.device_buffer import lower_ring_programs
+
+    step = {
+        "rgb": jax.ShapeDtypeStruct((1, n_envs, *frame), np.uint8),
+        "actions": jax.ShapeDtypeStruct((1, n_envs, action_dim), np.float32),
+        **{k: jax.ShapeDtypeStruct((1, n_envs, 1), np.float32) for k in ("rewards", "terminated", "truncated", "is_first")},
+    }
+    out = {}
+    for name, lowered in lower_ring_programs(step, slots, n_envs, batch, sequence, device=device).items():
+        compiled = lowered.compile()
+        memory = compiled.memory_analysis()
+        out[name] = {
+            "argument_bytes": int(memory.argument_size_in_bytes),
+            "temp_bytes": int(memory.temp_size_in_bytes),
+            "alias_bytes": int(memory.alias_size_in_bytes),
+            "relayouts": ring_relayouts(compiled.as_text(), slots + 1),
+        }
+    return out
+
+
+def phase_ring_programs(rings: Dict[str, Sequence[int]], *, max_temp_bytes: int, device=None) -> Dict[str, Any]:
+    """No program of the replay ring copies it: at every size in ``rings``
+    the write and the gather compile for the chip, hold no instruction of
+    the ring's shape but the in-place updates, and ask for under
+    ``max_temp_bytes`` of temporaries; the write aliases the whole ring."""
+    summary = {}
+    for label, (n_envs, slots, action_dim) in rings.items():
+        summary[label] = programs = describe_ring_programs(n_envs, slots, action_dim, device=device)
+        for name, got in programs.items():
+            say(
+                f"[ring] {label} ({n_envs} x {slots + 1} slots) {name}: arguments {got['argument_bytes']} B, "
+                f"temp_size_in_bytes {got['temp_bytes']}, aliased {got['alias_bytes']} B, "
+                f"ring-shaped copies {got['relayouts'] or 'none'}"
+            )
+            require(not got["relayouts"], f"{name} at {label} relayouts the ring: {got['relayouts']}")
+            require(got["temp_bytes"] < max_temp_bytes, f"{name} at {label} asks for {got['temp_bytes']} B of temporaries")
+        write = programs["ring_write"]
+        require(write["alias_bytes"] >= write["argument_bytes"] - (1 << 20), f"ring_write at {label} does not alias the ring it is given")
+    return summary
 
 
 def phase_kernel(
@@ -577,6 +660,7 @@ def main_one_chip() -> None:
     from sheeprl_tpu import native
 
     t0 = time.perf_counter()
+    ring = phase_ring_programs(RINGS, max_temp_bytes=64 << 20)
     kernel = phase_kernel(**S_KERNEL, batches=(16, 1024), fwd_atol=1e-2, grad_rtol=1e-3)
     mark = (cache.cache_hits, cache.cache_misses)
     # 70 updates of random actions fill 64-step sequences; the watchdog's warm
@@ -593,7 +677,7 @@ def main_one_chip() -> None:
     say(f"[done] all phases in {time.perf_counter() - t0:.1f}s")
     finish(
         devices,
-        {"kernel": kernel, "train": train, "eval": evaluated, "cache": cache_since(cache)},
+        {"ring": ring, "kernel": kernel, "train": train, "eval": evaluated, "cache": cache_since(cache)},
         "chip_smoke.json",
     )
 

@@ -17,17 +17,29 @@ Dreamer-family loops can swap buffers without touching their math. Index
 drawing stays on the host (the host mirrors the cursors; drawing needs no
 device data), only the draw result crosses the link.
 
-Storage layout: one array per key, ``[n_envs, capacity + 1, *item]`` —
-env-major so a sampled window is a contiguous HBM run; the extra slot at
+Storage layout: the ring is stored in the form its programs address, so no
+program relayouts it (:class:`RingArrays`). A ``uint8`` key of ``n`` bytes
+an item is ``uint8[n_envs, capacity + 1, ceil(n / 128), 128]`` — whole
+lane-dense rows, the last one zero-padded: one frame is one contiguous run
+(64 x 64 x 3 is ``[96, 128]``, three 32 x 128 tiles, no padding). Every other
+key is a column range of one packed ``float32[n_envs, capacity + 1, width]``
+array, ``width`` rounded up to whole 128-lane rows: the row ``add`` stages
+them as. The indexed dimensions (env, slot) are untiled major dimensions and
+the tiled ones are always taken whole: a write is one
+``dynamic_update_slice`` a local env and array, under a donated ``jit`` that
+aliases the ring in place, and a gather reads whole rows and restores the
+items' shapes on the gathered batch only. (An item whose minor dimension is
+3 has no good tiled layout on the chip: stored as ``[..., 64, 64, 3]`` the
+runtime kept the slots minor-most, and every write and gather copied the
+whole ring to reach the layout it was emitted for.) The extra slot at
 ``capacity`` is a scratch row that absorbs writes of envs excluded from a
-partial ``add`` (every write is a fixed-shape scatter, so one compiled
-program serves full and partial adds alike). Writes donate the buffer state
-to XLA, which aliases the update in place — adding a step never copies the
-ring.
+partial ``add``, so one compiled program serves full and partial adds alike.
+Outside the ring nothing shows: ``host_arrays``, pickles and the host-buffer
+conversions keep ``[n_envs, capacity, *item]`` arrays.
 
 On a pure data-parallel mesh the ring shards along the env axis
 (``NamedSharding`` over ``data_axis``, ``n_envs`` divisible by the axis
-size): every device owns a contiguous block of env rows, ``add`` scatters
+size): every device owns a contiguous block of env rows, ``add`` writes
 each device's env slice into its own shard under ``shard_map`` (per-device
 cursor arithmetic, no cross-device traffic), and the pure sampling kernels
 run shard-locally at fixed shapes — both from the host paths (gathers come
@@ -37,20 +49,129 @@ a fused superstep's scan (each device draws its own batch shard).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from sheeprl_tpu.obs.span import span
 from sheeprl_tpu.parallel.shard_map import shard_map
 
 
-def _is_pixel(v: np.ndarray) -> bool:
-    return v.dtype == np.uint8
+#: lanes of a TPU tile: the minor dimension of every stored array is a whole
+#: multiple of it
+_LANES = 128
+
+
+def _lane_rows(n: int) -> int:
+    return -(-int(n) // _LANES)
+
+
+class RingLayout(NamedTuple):
+    """What the stored arrays hold, read from the first ``add`` (static under
+    ``jit``: it rides as the aux data of :class:`RingArrays`)."""
+
+    #: ``(key, item shape)`` of the ``uint8`` keys
+    pixels: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    #: ``(key, first column, end column, item shape)`` of the ``float32`` keys
+    smalls: Tuple[Tuple[str, int, int, Tuple[int, ...]], ...]
+
+    @classmethod
+    def of(cls, arrays: Dict[str, np.ndarray]) -> "RingLayout":
+        """From ``[a, b, *item]`` arrays a key (a step dict or a checkpoint's
+        ``[E, cap, *item]``); keys in sorted order."""
+        pixels, smalls, offset = [], [], 0
+        for k in sorted(arrays):
+            v = arrays[k]
+            item = tuple(int(d) for d in v.shape[2:])
+            if v.dtype == np.uint8:
+                pixels.append((k, item))
+            else:
+                width = int(np.prod(item))
+                smalls.append((k, offset, offset + width, item))
+                offset += width
+        return cls(tuple(pixels), tuple(smalls))
+
+    @property
+    def keys(self) -> Tuple[str, ...]:
+        return tuple(k for k, _ in self.pixels) + tuple(s[0] for s in self.smalls)
+
+    @property
+    def columns(self) -> Dict[str, Tuple[int, int]]:
+        """``key -> (first column, end column)`` in the packed array."""
+        return {k: (o0, o1) for k, o0, o1, _ in self.smalls}
+
+    @property
+    def small_width(self) -> int:
+        """Columns of the packed array: the keys' widths, in whole lane rows."""
+        return _lane_rows(self.smalls[-1][2] if self.smalls else 0) * _LANES
+
+    def stored_shapes(self, *lead: int) -> Tuple[Dict[str, Tuple[int, ...]], Tuple[int, ...]]:
+        """Shapes of the stored form under the leading dimensions ``lead``."""
+        pixels = {k: (*lead, _lane_rows(np.prod(item)), _LANES) for k, item in self.pixels}
+        return pixels, (*lead, self.small_width)
+
+    def store(self, arrays: Dict[str, np.ndarray], pixels: Dict[str, np.ndarray], smalls: np.ndarray, at: tuple) -> None:
+        """Write ``[*lead, *item]`` arrays into host arrays of the stored
+        form, at the leading index ``at`` (ints and slices: the targets are
+        views, filled through a flat view of their rows)."""
+        rows = smalls[at]
+        lead = rows.shape[:-1]
+        for k, o0, o1, _ in self.smalls:
+            rows[..., o0:o1] = np.asarray(arrays[k]).reshape(*lead, -1)
+        for k, item in self.pixels:
+            flat = pixels[k].reshape(*pixels[k].shape[:-2], -1)[at]
+            flat[..., : int(np.prod(item))] = np.asarray(arrays[k]).reshape(*lead, -1)
+
+    def restore(self, pixels: Dict[str, Any], smalls: Any) -> Dict[str, Any]:
+        """The items' own shapes back on arrays of the stored form, numpy or
+        jax, whatever their leading dimensions: the one inverse of the
+        storage rule (every gather and ``host_arrays`` end here)."""
+        lead = smalls.shape[:-1]
+        out = {}
+        for k, item in self.pixels:
+            out[k] = pixels[k].reshape(*lead, -1)[..., : int(np.prod(item))].reshape(*lead, *item)
+        for k, o0, o1, item in self.smalls:
+            out[k] = smalls[..., o0:o1].reshape(*lead, *item)
+        return out
+
+
+@jax.tree_util.register_pytree_node_class
+class RingArrays:
+    """The ring as its programs address it: ``pixels[k]`` is
+    ``uint8[n_envs, capacity + 1, rows, 128]``, ``smalls`` the packed
+    ``float32[n_envs, capacity + 1, width]``. A pytree whose static part is
+    the :class:`RingLayout`, so the items' shapes travel with the arrays
+    through ``jit`` and ``shard_map`` into the in-graph draws."""
+
+    def __init__(self, pixels: Dict[str, jax.Array], smalls: jax.Array, layout: RingLayout) -> None:
+        self.pixels = pixels
+        self.smalls = smalls
+        self.layout = layout
+
+    def tree_flatten(self):
+        return (self.pixels, self.smalls), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+    @property
+    def capacity(self) -> int:
+        # static under jit: the trailing scratch slot is excluded from sampling
+        return self.smalls.shape[1] - 1
+
+    def rows(self, env_idx: jax.Array, time_idx: jax.Array) -> Dict[str, jax.Array]:
+        """HBM→HBM gather of the slots ``(env_idx, time_idx)`` (broadcast
+        against each other): only the two leading dimensions are indexed,
+        and the items' shapes come back on the gathered batch."""
+        return self.layout.restore(
+            {k: b[env_idx, time_idx] for k, b in self.pixels.items()}, self.smalls[env_idx, time_idx]
+        )
 
 
 # --------------------------------------------------------------------------- #
@@ -59,17 +180,12 @@ def _is_pixel(v: np.ndarray) -> bool:
 # Everything below is a plain function of device arrays — callable from inside
 # another jitted program (the fused training supersteps scan these to draw a
 # fresh replay batch per gradient step without a host round trip) as well as
-# from the buffer's own jitted methods. The ring arrays are ``[n_envs,
-# capacity + 1, ...]`` (slot ``capacity`` is the partial-add scratch row and
-# is never sampled); validity is recomputed on device from the two tiny
-# cursor arrays ``pos``/``full``, so the mask shapes are fixed and nothing
-# recompiles as the ring fills.
+# from the buffer's own jitted methods. ``bufs`` is the :class:`RingArrays`
+# (slot ``capacity`` is the partial-add scratch row and is never sampled);
+# validity is recomputed on device from the two tiny cursor arrays
+# ``pos``/``full``, so the mask shapes are fixed and nothing recompiles as
+# the ring fills.
 # --------------------------------------------------------------------------- #
-
-
-def _ring_capacity(bufs: Dict[str, jax.Array]) -> int:
-    # static under jit: the trailing scratch slot is excluded from sampling
-    return next(iter(bufs.values())).shape[1] - 1
 
 
 def sequence_start_mask(
@@ -122,28 +238,31 @@ def draw_from_mask(key: jax.Array, mask: jax.Array, n: int) -> Tuple[jax.Array, 
     return env_idx, item.astype(jnp.int32)
 
 
-def gather_sequences(
-    bufs: Dict[str, jax.Array], env_idx: jax.Array, time_idx: jax.Array
-) -> Dict[str, jax.Array]:
+def gather_sequences(bufs: RingArrays, env_idx: jax.Array, time_idx: jax.Array) -> Dict[str, jax.Array]:
     """HBM→HBM sequence gather: ``env_idx [B]``, ``time_idx [B, T]`` →
     ``[T, B, ...]`` values (time-major, the layout the fused train steps
     consume)."""
-    out = {}
-    for k, b in bufs.items():
-        g = b[env_idx[:, None], time_idx]  # [B, T, ...]
-        out[k] = jnp.swapaxes(g, 0, 1)
+    return {k: jnp.swapaxes(g, 0, 1) for k, g in bufs.rows(env_idx[:, None], time_idx).items()}
+
+
+def gather_transitions(
+    bufs: RingArrays,
+    env_idx: jax.Array,
+    time_idx: jax.Array,
+    next_idx: Optional[jax.Array] = None,
+    obs_keys: Sequence[str] = (),
+) -> Dict[str, jax.Array]:
+    """Transition gather: ``env_idx``/``time_idx [...]`` → ``[..., *item]``,
+    with ``next_<k>`` of the ``obs_keys`` read at ``next_idx`` when given."""
+    out = bufs.rows(env_idx, time_idx)
+    if next_idx is not None:
+        nxt = bufs.rows(env_idx, next_idx)
+        out.update({f"next_{k}": nxt[k] for k in obs_keys if k in nxt})
     return out
 
 
-def gather_transition_items(
-    bufs: Dict[str, jax.Array], env_idx: jax.Array, time_idx: jax.Array
-) -> Dict[str, jax.Array]:
-    """Flat transition gather: ``env_idx``/``time_idx [N]`` → ``[N, ...]``."""
-    return {k: b[env_idx, time_idx] for k, b in bufs.items()}
-
-
 def draw_sequence_batch(
-    bufs: Dict[str, jax.Array],
+    bufs: RingArrays,
     pos: jax.Array,
     full: jax.Array,
     key: jax.Array,
@@ -152,7 +271,7 @@ def draw_sequence_batch(
 ) -> Dict[str, jax.Array]:
     """One ``[T, B, ...]`` sequence batch drawn and gathered entirely
     in-graph — the Dreamer-family replay read of a fused superstep."""
-    capacity = _ring_capacity(bufs)
+    capacity = bufs.capacity
     mask = sequence_start_mask(pos, full, capacity, sequence_length)
     env_idx, starts = draw_from_mask(key, mask, batch_size)
     offsets = jnp.arange(sequence_length, dtype=jnp.int32)
@@ -161,7 +280,7 @@ def draw_sequence_batch(
 
 
 def draw_transition_batch(
-    bufs: Dict[str, jax.Array],
+    bufs: RingArrays,
     pos: jax.Array,
     full: jax.Array,
     key: jax.Array,
@@ -173,16 +292,12 @@ def draw_transition_batch(
     in-graph — the SAC-family replay read of a fused superstep. Matches the
     :meth:`DeviceReplayBuffer.sample_transitions` output contract
     (``next_<k>`` at item+1 when ``sample_next_obs``)."""
-    capacity = _ring_capacity(bufs)
+    capacity = bufs.capacity
     mask = transition_item_mask(pos, full, capacity, sample_next_obs)
     env_idx, items = draw_from_mask(key, mask, batch_size)
-    out = {k: b[env_idx, items] for k, b in bufs.items()}
-    if sample_next_obs:
-        next_idx = (items + 1) % capacity
-        for k in obs_keys:
-            if k in bufs:
-                out[f"next_{k}"] = bufs[k][env_idx, next_idx]
-    return out
+    return gather_transitions(
+        bufs, env_idx, items, (items + 1) % capacity if sample_next_obs else None, obs_keys
+    )
 
 
 class DeviceReplayBuffer:
@@ -245,11 +360,8 @@ class DeviceReplayBuffer:
         # to report them back)
         self._pos = np.zeros((n_envs,), np.int64)
         self._full = np.zeros((n_envs,), bool)
-        self._bufs: Optional[Dict[str, jax.Array]] = None
+        self._bufs: Optional[RingArrays] = None
         self._pending_arrays: Optional[Dict[str, np.ndarray]] = None
-        self._small_keys: Tuple[str, ...] = ()
-        self._small_slices: Dict[str, Tuple[int, int, Tuple[int, ...]]] = {}
-        self._pixel_keys: Tuple[str, ...] = ()
         self._write = None
         self._gather = None
         self._amend = None
@@ -314,89 +426,96 @@ class DeviceReplayBuffer:
         )
 
     # ------------------------------------------------------------- allocation
-    def _allocate(self, data: Dict[str, np.ndarray]) -> None:
-        cap1 = self._buffer_size + 1
-        smalls: List[str] = []
-        pixels: List[str] = []
-        bufs: Dict[str, jax.Array] = {}
-        for k in sorted(data):
-            v = np.asarray(data[k])
-            item = tuple(v.shape[2:])
-            if _is_pixel(v):
-                pixels.append(k)
-                dtype = jnp.uint8
-            else:
-                smalls.append(k)
-                dtype = jnp.float32
-            shape = (self._n_envs, cap1, *item)
-            bufs[k] = jax.device_put(jnp.zeros(shape, dtype), self._sharding or self._device)
-        offset = 0
-        for k in smalls:
-            item = tuple(np.asarray(data[k]).shape[2:])
-            width = int(np.prod(item)) if item else 1
-            self._small_slices[k] = (offset, offset + width, item)
-            offset += width
-        self._small_keys = tuple(smalls)
-        self._pixel_keys = tuple(pixels)
-        self._bufs = bufs
-        self._build_kernels()
+    def _allocate(self, layout: RingLayout, arrays: Optional[Dict[str, np.ndarray]] = None) -> None:
+        """Put the ring on its device(s) in the stored form: zeros, or the
+        ``[E, cap, *item]`` ``arrays`` of a checkpoint or a host buffer."""
+        lead = (self._n_envs, self._buffer_size + 1)
+        pixel_shapes, small_shape = layout.stored_shapes(*lead)
+        placement = self._sharding or self._device
+        if arrays is None:
+            pixels = {k: jnp.zeros(shape, jnp.uint8, device=placement) for k, shape in pixel_shapes.items()}
+            smalls = jnp.zeros(small_shape, jnp.float32, device=placement)
+        else:
+            pixels = {k: np.zeros(shape, np.uint8) for k, shape in pixel_shapes.items()}
+            smalls = np.zeros(small_shape, np.float32)
+            layout.store(arrays, pixels, smalls, (slice(None), slice(0, self._buffer_size)))
+            pixels, smalls = jax.device_put((pixels, smalls), placement)
+        self._bufs = RingArrays(pixels, smalls, layout)
+        # staging arrays of one step, in the stored form: allocated once and
+        # overwritten in place by every add() through the flat views
+        stage_shapes, stage_small = layout.stored_shapes(self._n_envs)
+        self._stage_pos = np.empty((self._n_envs,), np.int32)
+        self._stage_smalls = np.zeros(stage_small, np.float32)
+        self._stage_pixels = {k: np.zeros(shape, np.uint8) for k, shape in stage_shapes.items()}
+        self._build_kernels(layout)
 
-    def _build_kernels(self) -> None:
+    def _build_kernels(self, layout: RingLayout) -> None:
         # Each jitted program carries a stable name of its own (the XLA
         # module is ``jit_<name>``), so a device trace says which program an
         # op belongs to: ``ring_write``, ``ring_amend``,
-        # ``ring_gather_sequences``, ``ring_gather_transitions`` and
-        # ``ring_gather_transitions_next`` (howto/telemetry.md lists them).
+        # ``ring_set_truncated``, ``ring_gather_sequences``,
+        # ``ring_gather_transitions`` and ``ring_gather_transitions_next``
+        # (howto/telemetry.md lists them).
         #
         # under shard_map every operand arrives as its per-device block, so
         # the kernels index with the LOCAL env count — per-device cursor
         # arithmetic falls out of the same code that serves the 1-device ring
         n_envs = self._n_envs // self._n_shards
-        small_slices = dict(self._small_slices)
-        pixel_keys = self._pixel_keys
-        small_keys = self._small_keys
+        capacity = self._buffer_size
+        columns = layout.columns
+        obs_keys = self._obs_keys
+        ax = self._data_axis
 
         def ring_write(bufs, pixels, smalls, pos):
-            env_ids = jnp.arange(n_envs)
-            out = dict(bufs)
-            for k in pixel_keys:
-                out[k] = out[k].at[env_ids, pos].set(pixels[k])
-            for k in small_keys:
-                o0, o1, item = small_slices[k]
-                seg = smalls[:, o0:o1].reshape((n_envs, *item) if item else (n_envs,))
-                out[k] = out[k].at[env_ids, pos].set(seg)
-            return out
-
-        obs_keys = self._obs_keys
+            # one row a local env and array, updated in place: the form XLA
+            # reaches by itself for a one-env scatter
+            out, packed = dict(bufs.pixels), bufs.smalls
+            for e in range(n_envs):
+                for k in out:
+                    out[k] = lax.dynamic_update_slice(out[k], pixels[k][e][None, None], (e, pos[e], 0, 0))
+                packed = lax.dynamic_update_slice(packed, smalls[e][None, None], (e, pos[e], 0))
+            return RingArrays(out, packed, layout)
 
         def ring_gather_sequences(bufs, env_idx, time_idx):
             return gather_sequences(bufs, env_idx, time_idx)
 
         def ring_gather_transitions(bufs, env_idx, time_idx):
-            return gather_transition_items(bufs, env_idx, time_idx)
+            return gather_transitions(bufs, env_idx, time_idx)
 
         def ring_gather_transitions_next(bufs, env_idx, time_idx, next_idx):
-            out = gather_transition_items(bufs, env_idx, time_idx)
-            for k in obs_keys:
-                if k in bufs:
-                    out[f"next_{k}"] = bufs[k][env_idx, next_idx]
-            return out
+            return gather_transitions(bufs, env_idx, time_idx, next_idx, obs_keys)
 
         def ring_amend(bufs, env_i, slot, terminated, truncated, is_first):
-            out = dict(bufs)
+            if ax is not None:
+                # the env's row lives in one device's block; the others send
+                # their copy of the patch to the scratch slot
+                env_i = env_i - lax.axis_index(ax) * n_envs
+                slot = jnp.where((env_i >= 0) & (env_i < n_envs), slot, capacity)
+                env_i = jnp.clip(env_i, 0, n_envs - 1)
+            pixels, packed = bufs.pixels, bufs.smalls
             for k, v in (("terminated", terminated), ("truncated", truncated), ("is_first", is_first)):
-                if k in out:
-                    out[k] = out[k].at[env_i, slot].set(
-                        jnp.full(out[k].shape[2:], v, out[k].dtype)
+                if k in columns:
+                    o0, o1 = columns[k]
+                    packed = lax.dynamic_update_slice(
+                        packed, jnp.full((1, 1, o1 - o0), v, packed.dtype), (env_i, slot, o0)
                     )
-            return out
+            return RingArrays(pixels, packed, layout)
+
+        def ring_set_truncated(bufs, slots, values):
+            o0, _ = columns["truncated"]
+            pixels, packed = bufs.pixels, bufs.smalls
+            for e in range(n_envs):
+                packed = lax.dynamic_update_slice(packed, values[e][None, None], (e, slots[e], o0))
+            return RingArrays(pixels, packed, layout)
 
         if self.sharded:
-            mesh, ax = self._mesh, self._data_axis
+            mesh = self._mesh
             # write: every operand (ring, staging arrays, cursor vector) is
-            # env-axis sharded, so each device scatters its own env block —
+            # env-axis sharded, so each device updates its own env block —
             # no collective appears in the program
             ring_write = shard_map(ring_write, mesh, in_specs=(P(ax), P(ax), P(ax), P(ax)), out_specs=P(ax))
+            ring_set_truncated = shard_map(ring_set_truncated, mesh, in_specs=(P(ax), P(ax), P(ax)), out_specs=P(ax))
+            ring_amend = shard_map(ring_amend, mesh, in_specs=(P(ax), P(), P(), P(), P(), P()), out_specs=P(ax))
             # host-path gathers: the draw is stratified per shard (see
             # draw_indices), index arrays arrive batch-axis sharded with
             # SHARD-LOCAL env ids, and the batch comes out pre-sharded along
@@ -420,10 +539,10 @@ class DeviceReplayBuffer:
 
         # writes donate the ring: XLA aliases the update in place
         self._write = jax.jit(ring_write, donate_argnums=0)
-        # amend is the rare failure-recovery patch path (one env, one slot):
-        # on a sharded ring the plain jit lets GSPMD route the scalar scatter
-        # to whichever shard owns the env row — not worth a shard_map
+        # amend is the rare failure-recovery patch path (one env, one slot),
+        # set_truncated the checkpoint's flag fix-up (every env's last slot)
         self._amend = jax.jit(ring_amend, donate_argnums=0)
+        self._set_truncated = jax.jit(ring_set_truncated, donate_argnums=0)
         # the gathers wrap the module-level pure kernels (also callable from
         # inside a fused superstep's scan body), jitted here for the host paths
         self._gather = jax.jit(ring_gather_sequences)
@@ -456,33 +575,21 @@ class DeviceReplayBuffer:
                 f"arrays in 'data' ({first.shape[1]})"
             )
         if self._bufs is None:
-            self._allocate(data)
-        if set(data) != set(self._bufs):
+            self._allocate(RingLayout.of({k: np.asarray(v) for k, v in data.items()}))
+        layout = self._bufs.layout
+        if set(data) != set(layout.keys):
             raise ValueError(
-                f"add() keys {sorted(data)} do not match the allocated keys {sorted(self._bufs)}"
+                f"add() keys {sorted(data)} do not match the allocated keys {sorted(layout.keys)}"
             )
 
-        # scatter targets: the env's cursor, or the scratch slot for envs not
-        # in this (partial) add. Staging arrays are allocated once and
-        # overwritten in place — rows of envs excluded from a partial add
+        # write targets: the env's cursor, or the scratch slot for envs not
+        # in this (partial) add. Rows of envs excluded from a partial add
         # keep stale bytes, which land harmlessly in the scratch slot
-        if not hasattr(self, "_stage_pos"):
-            width = sum(s[1] - s[0] for s in self._small_slices.values())
-            self._stage_pos = np.empty((self._n_envs,), np.int32)
-            self._stage_smalls = np.zeros((self._n_envs, width), np.float32)
-            self._stage_pixels = {
-                k: np.zeros((self._n_envs, *self._bufs[k].shape[2:]), np.uint8)
-                for k in self._pixel_keys
-            }
         pos, pixels, smalls = self._stage_pos, self._stage_pixels, self._stage_smalls
         pos.fill(self._buffer_size)
         for col, env in enumerate(indices):
             pos[env] = self._pos[env]
-            for k in self._pixel_keys:
-                pixels[k][env] = data[k][0, col]
-            for k in self._small_keys:
-                o0, o1, _ = self._small_slices[k]
-                smalls[env, o0:o1] = np.asarray(data[k][0, col], np.float32).reshape(-1)
+            layout.store({k: v[0, col] for k, v in data.items()}, pixels, smalls, (env,))
 
         ref_device = (
             self._mesh.devices.flat[0] if self._mesh is not None else (self._device or jax.devices()[0])
@@ -739,34 +846,38 @@ class DeviceReplayBuffer:
         """Set ``truncated=1`` on every env's most recent step (checkpoint
         self-consistency — reference ``callback.py:87-142``) and return the
         clobbered values for :meth:`restore_last_truncated`."""
-        if self._bufs is None or "truncated" not in self._bufs:
+        if self._bufs is None or "truncated" not in self._bufs.layout.columns:
             return None
+        o0, o1 = self._bufs.layout.columns["truncated"]
         slots = ((self._pos - 1) % self._buffer_size).astype(np.int32)
-        env_ids = np.arange(self._n_envs, dtype=np.int32)
-        saved = np.asarray(jax.device_get(self._bufs["truncated"][env_ids, slots]))
-        self._bufs = dict(self._bufs)
-        self._bufs["truncated"] = (
-            self._bufs["truncated"].at[env_ids, slots].set(jnp.ones_like(saved))
-        )
+        saved = np.asarray(jax.device_get(self._bufs.smalls[np.arange(self._n_envs), slots, o0:o1]))
+        self._write_truncated(slots, np.ones_like(saved))
         return saved
 
     def restore_last_truncated(self, saved: Optional[np.ndarray]) -> None:
         if saved is None or self._bufs is None:
             return
-        slots = ((self._pos - 1) % self._buffer_size).astype(np.int32)
-        env_ids = np.arange(self._n_envs, dtype=np.int32)
-        self._bufs = dict(self._bufs)
-        self._bufs["truncated"] = self._bufs["truncated"].at[env_ids, slots].set(jnp.asarray(saved))
+        self._write_truncated(((self._pos - 1) % self._buffer_size).astype(np.int32), saved)
+
+    def _write_truncated(self, slots: np.ndarray, values: np.ndarray) -> None:
+        args = jax.device_put((slots, values.astype(np.float32)), self._sharding or self._device)
+        self._bufs = self._set_truncated(self._bufs, *args)
 
     # ------------------------------------------------------------- checkpoint
     def host_arrays(self) -> Dict[str, np.ndarray]:
         """Fetch the ring (without the scratch slot) as ``[E, cap, ...]``
-        numpy arrays — one bulk transfer per key."""
+        numpy arrays — one bulk transfer per stored array."""
         if self._bufs is None:
             return dict(self._pending_arrays or {})
-        return {k: np.asarray(jax.device_get(v))[:, : self._buffer_size] for k, v in self._bufs.items()}
+        pixels, smalls = jax.device_get((self._bufs.pixels, self._bufs.smalls))
+        filled = slice(0, self._buffer_size)
+        return self._bufs.layout.restore({k: v[:, filled] for k, v in pixels.items()}, smalls[:, filled])
 
     def __getstate__(self) -> Dict[str, Any]:
+        arrays = self.host_arrays()
+        layout = RingLayout.of(arrays)
+        # the external format of every checkpoint since the ring exists:
+        # ``[E, cap, *item]`` arrays and the three key tables beside them
         state = {
             "buffer_size": self._buffer_size,
             "n_envs": self._n_envs,
@@ -774,10 +885,10 @@ class DeviceReplayBuffer:
             "rng": self._rng,
             "pos": self._pos,
             "full": self._full,
-            "small_slices": self._small_slices,
-            "small_keys": self._small_keys,
-            "pixel_keys": self._pixel_keys,
-            "arrays": self.host_arrays(),
+            "small_slices": {k: (o0, o1, item) for k, o0, o1, item in layout.smalls},
+            "small_keys": tuple(s[0] for s in layout.smalls),
+            "pixel_keys": tuple(k for k, _ in layout.pixels),
+            "arrays": arrays,
         }
         return state
 
@@ -788,9 +899,6 @@ class DeviceReplayBuffer:
         self._rng = state["rng"]
         self._pos = state["pos"]
         self._full = state["full"]
-        self._small_slices = state["small_slices"]
-        self._small_keys = state["small_keys"]
-        self._pixel_keys = state["pixel_keys"]
         self._device = None  # re-pinned by the restoring process
         # meshes do not pickle: a restored ring comes back single-device and
         # the restoring run's jitted consumers reshard it on first use
@@ -800,7 +908,7 @@ class DeviceReplayBuffer:
         self._sharding = None
         self._bufs = None
         self._write = self._gather = self._amend = None
-        self._gather_transitions = self._gather_transitions_next = None
+        # the stored form is rebuilt from the arrays alone (restore_to_device)
         self._pending_arrays = state["arrays"]
 
     def restore_to_device(self, device: Optional[jax.Device] = None) -> "DeviceReplayBuffer":
@@ -808,14 +916,7 @@ class DeviceReplayBuffer:
         self._device = device
         arrays = getattr(self, "_pending_arrays", None)
         if arrays:
-            cap1 = self._buffer_size + 1
-            bufs = {}
-            for k, v in arrays.items():
-                padded = np.zeros((self._n_envs, cap1, *v.shape[2:]), v.dtype)
-                padded[:, : self._buffer_size] = v
-                bufs[k] = jax.device_put(padded, device)
-            self._bufs = bufs
-            self._build_kernels()
+            self._allocate(RingLayout.of(arrays), arrays)
             self._pending_arrays = None
         return self
 
@@ -834,20 +935,8 @@ class DeviceReplayBuffer:
         }
         out._pos = np.array([sub._pos for sub in subs], np.int64)
         out._full = np.array([sub.full for sub in subs], bool)
-        out._pending_arrays = {
-            k: (v if v.dtype == np.uint8 else v.astype(np.float32)) for k, v in arrays.items()
-        }
         # _pending_arrays carries [E, cap, ...]; reuse the restore path
-        out._small_slices = {}
-        smalls = [k for k in sorted(keys) if arrays[k].dtype != np.uint8]
-        offset = 0
-        for k in smalls:
-            item = tuple(arrays[k].shape[2:])
-            width = int(np.prod(item)) if item else 1
-            out._small_slices[k] = (offset, offset + width, item)
-            offset += width
-        out._small_keys = tuple(smalls)
-        out._pixel_keys = tuple(k for k in sorted(keys) if arrays[k].dtype == np.uint8)
+        out._pending_arrays = arrays
         out.restore_to_device(device)
         return out
 
@@ -867,19 +956,7 @@ class DeviceReplayBuffer:
         )
         out._pos = np.full((host_rb.n_envs,), host_rb._pos, np.int64)
         out._full = np.full((host_rb.n_envs,), host_rb.full, bool)
-        out._pending_arrays = {
-            k: (v if v.dtype == np.uint8 else v.astype(np.float32)) for k, v in arrays.items()
-        }
-        smalls = [k for k in sorted(arrays) if arrays[k].dtype != np.uint8]
-        offset = 0
-        out._small_slices = {}
-        for k in smalls:
-            item = tuple(arrays[k].shape[2:])
-            width = int(np.prod(item)) if item else 1
-            out._small_slices[k] = (offset, offset + width, item)
-            offset += width
-        out._small_keys = tuple(smalls)
-        out._pixel_keys = tuple(k for k in sorted(arrays) if arrays[k].dtype == np.uint8)
+        out._pending_arrays = arrays
         out.restore_to_device(device)
         return out
 
@@ -911,9 +988,7 @@ class DeviceReplayBuffer:
 
     def ring_bytes(self) -> int:
         """Current HBM footprint of the allocated ring."""
-        if self._bufs is None:
-            return 0
-        return sum(int(np.prod(v.shape)) * v.dtype.itemsize for v in self._bufs.values())
+        return sum(v.nbytes for v in jax.tree.leaves(self._bufs))
 
     def to_host_buffer(self, memmap: bool = False, memmap_dir: Any = None) -> Any:
         """Materialize as a stock ``EnvIndependentReplayBuffer`` (host RAM),
@@ -940,19 +1015,56 @@ class DeviceReplayBuffer:
         return host
 
 
+def lower_ring_programs(
+    step: Dict[str, Any],
+    buffer_size: int,
+    n_envs: int,
+    batch_size: int,
+    sequence_length: int,
+    device: Optional[jax.Device] = None,
+) -> Dict[str, Any]:
+    """``ring_write`` and ``ring_gather_sequences`` lowered from shapes alone
+    (``step``: ``[1, n_envs, *item]`` shapes with dtypes, as ``add`` takes
+    them), so that a ring of any size can be compiled and its optimised HLO
+    and ``memory_analysis()`` read with nothing allocated. ``device`` may be
+    a described one (``jax.experimental.topologies``)."""
+    sharding = jax.sharding.SingleDeviceSharding(device) if device is not None else None
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rb = DeviceReplayBuffer(buffer_size, n_envs=n_envs)
+    layout = RingLayout.of(step)
+    rb._build_kernels(layout)
+    pixel_shapes, small_shape = layout.stored_shapes(n_envs, buffer_size + 1)
+    ring = RingArrays({k: sds(v, jnp.uint8) for k, v in pixel_shapes.items()}, sds(small_shape, jnp.float32), layout)
+    stage_shapes, stage_small = layout.stored_shapes(n_envs)
+    staged = ({k: sds(v, jnp.uint8) for k, v in stage_shapes.items()}, sds(stage_small, jnp.float32))
+    return {
+        "ring_write": rb._write.lower(ring, *staged, sds((n_envs,), jnp.int32)),
+        "ring_gather_sequences": rb._gather.lower(
+            ring, sds((batch_size,), jnp.int32), sds((batch_size, sequence_length), jnp.int32)
+        ),
+    }
+
+
+def _stored_step_bytes(spaces: Sequence[Any], copies: int, extra_floats: int) -> int:
+    """Bytes of one slot in the stored form: each ``uint8`` space in whole
+    128-byte rows, everything else as columns of the packed ``float32``
+    array, itself in whole 128-lane rows (``copies`` of every space)."""
+    pixel_bytes = sum(_lane_rows(np.prod(sp.shape)) * _LANES for sp in spaces if np.issubdtype(sp.dtype, np.uint8))
+    floats = sum(int(np.prod(sp.shape)) for sp in spaces if not np.issubdtype(sp.dtype, np.uint8))
+    return copies * pixel_bytes + _lane_rows(copies * floats + extra_floats) * _LANES * 4
+
+
 def estimate_ring_bytes(
     obs_space: Any, actions_dim: Sequence[int], buffer_size: int, n_envs: int
 ) -> int:
     """Upper-bound estimate of the HBM ring footprint for a Dreamer-style
-    step dict (obs keys + actions + 4 scalar flags), used by the ``auto``
-    device-buffer decision before any data exists."""
-    per_step = 0
-    for k in obs_space.spaces:
-        space = obs_space[k]
-        itemsize = 1 if np.issubdtype(space.dtype, np.uint8) else 4
-        per_step += int(np.prod(space.shape)) * itemsize
-    per_step += (int(np.sum(actions_dim)) + 4) * 4
-    return per_step * int(buffer_size) * int(n_envs)
+    step dict (obs keys + actions + 4 scalar flags), padding included, used
+    by the ``auto`` device-buffer decision before any data exists."""
+    spaces = [obs_space[k] for k in obs_space.spaces]
+    return _stored_step_bytes(spaces, 1, int(np.sum(actions_dim)) + 4) * int(buffer_size) * int(n_envs)
 
 
 def estimate_transition_bytes(
@@ -965,16 +1077,10 @@ def estimate_transition_bytes(
 ) -> int:
     """Upper-bound HBM estimate for a SAC-style transition step dict: the
     stored obs keys (doubled when the loop stores explicit next obs), actions
-    and 3 scalar flags."""
-    per_step = 0
-    for k in keys:
-        space = obs_space[k]
-        itemsize = 1 if np.issubdtype(space.dtype, np.uint8) else 4
-        per_step += int(np.prod(space.shape)) * itemsize
-    if store_next_obs:
-        per_step *= 2
-    per_step += (int(np.sum(actions_dim)) + 3) * 4
-    return per_step * int(buffer_size) * int(n_envs)
+    and 3 scalar flags, padding included."""
+    spaces = [obs_space[k] for k in keys]
+    copies = 2 if store_next_obs else 1
+    return _stored_step_bytes(spaces, copies, int(np.sum(actions_dim)) + 3) * int(buffer_size) * int(n_envs)
 
 
 def resolve_device_buffer(

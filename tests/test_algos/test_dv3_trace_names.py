@@ -102,9 +102,8 @@ def lowered():
         "actions": np.zeros((1, envs, act_dim), np.float32),
         **{k: np.zeros((1, envs, 1), np.float32) for k in ("rewards", "terminated", "truncated", "is_first")},
     }
-    rb._allocate(step)
-    smalls = jnp.zeros((envs, sum(hi - lo for lo, hi, _ in rb._small_slices.values())))
-    out["ring_write"] = text(rb._write, rb._bufs, {"rgb": jnp.zeros((envs, size, size, 3), jnp.uint8)}, smalls, jnp.zeros((envs,), jnp.int32))
+    rb.add(step)  # allocates the ring and its staging arrays (the stored form of one step)
+    out["ring_write"] = text(rb._write, rb._bufs, rb._stage_pixels, rb._stage_smalls, rb._stage_pos)
     out["ring_amend"] = text(rb._amend, rb._bufs, jnp.int32(0), jnp.int32(0), jnp.float32(0), jnp.float32(1), jnp.float32(0))
     out["ring_gather_sequences"] = text(rb._gather, rb._bufs, jnp.zeros((B,), jnp.int32), jnp.zeros((B, T), jnp.int32))
     return out

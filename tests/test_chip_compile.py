@@ -1,5 +1,6 @@
-"""The Pallas RSSM kernels, compiled for a TPU v5e that is described, not
-attached (on-chip-measurement guide, section 2, third rehearsal).
+"""The Pallas RSSM kernels and the replay ring's programs, compiled for a TPU
+v5e that is described, not attached (on-chip-measurement guide, section 2,
+third rehearsal).
 
 Interpret mode cannot show what the chip's compiler refuses — VMEM limits,
 block shapes, unaligned slices — and before this file the kernels had only
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chip_smoke
 from benchmarks.pallas_gru_ab import SIZES
 from sheeprl_tpu.ops.pallas_gru import fits_vmem, fused_recurrent_step, sharded_recurrent_step
 
@@ -138,3 +140,16 @@ def test_sharded_step_compiles_on_the_four_chip_mesh(topo, size, model_shards, d
         for program in programs.values():
             with pytest.raises(ValueError, match="too large for the VMEM-resident kernel"):
                 program.lower(*shapes)
+
+
+@pytest.mark.parametrize("ring", list(chip_smoke.RINGS))
+def test_ring_programs_address_the_ring_in_the_layout_it_is_stored_in(topo, ring):
+    """The TPU's side of ``test_device_buffer.py``'s guard, the phase
+    ``chip_smoke.py`` runs on the attached chip: at the benchmark cells' ring
+    sizes and at the walker recipe's own 500,000 frames, ``ring_write`` and
+    ``ring_gather_sequences`` compile for a 16 GB chip, keep the ring in the
+    one layout the runtime stores it in (no instruction of its shape but the
+    in-place row updates) and ask for under 64 MB of temporaries. Stored as
+    ``[..., 64, 64, 3]`` each program copied all 3.07 GB per call, and the
+    recipe's size asked for 17.19 GB."""
+    chip_smoke.phase_ring_programs({ring: chip_smoke.RINGS[ring]}, max_temp_bytes=64 << 20, device=topo.devices[0])

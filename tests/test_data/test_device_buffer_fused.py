@@ -159,7 +159,9 @@ def test_superstep_inputs_validates_like_the_sampling_paths():
         rb.superstep_inputs(sample_next_obs=True)
     rb.add(_step_data(1))
     bufs, pos, full = rb.superstep_inputs(sequence_length=2)
-    assert set(bufs) == set(rb._bufs)
+    # the ring itself, in its stored form: the item shapes ride with it
+    assert bufs is rb._bufs and set(bufs.layout.keys) == set(_step_data(0))
+    assert bufs.smalls.shape == (N_ENVS, CAP + 1, 128) and not bufs.pixels
     np.testing.assert_array_equal(np.asarray(pos), rb._pos.astype(np.int32))
     np.testing.assert_array_equal(np.asarray(full), rb._full)
     # the cursor snapshot must not alias the live host mirrors (add() mutates
