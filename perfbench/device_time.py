@@ -1,16 +1,20 @@
 """The device's time by program and by scope, and the idle time no host span
 accounts for: what ``trace_reduce.load`` drops from the profiler's trace.
 
-The program gives every jitted function of the Dreamer-V3 path a stable name
+The program gives every jitted function of a measured path a stable name
 (the XLA module is ``jit_<name>``) and the train step's parts a
 ``jax.named_scope`` each. On the device plane of the trace, the ``XLA Modules``
 line holds one event per program execution, named ``jit_<name>(<program id>)``,
 and each event of the ``XLA Ops`` line has an ``XEventMetadata`` whose ``tf_op``
 stat is the op's ``op_name`` path, scopes included
-(``jit(dv3_train_step)/jvp(dv3/wm/rssm_scan)/while/body/...``; found on the
-chip, PR 25). ``jax.profiler.ProfileData`` gives events with their own stats
-only, not their metadata's, so the metadata table is read from the file's
-protobuf wire format here (a few thousand entries; the events are skipped).
+(``jit(<train program>)/jvp(<scope>)/while/body/...``; found on the chip, PR
+25). ``jax.profiler.ProfileData`` gives events with their own stats only, not
+their metadata's, so the metadata table is read from the file's protobuf wire
+format here (a few thousand entries; the events are skipped).
+
+Which names those are is the algorithm's to say: ``reduce`` takes the tables
+(``programs``, ``train_program``, ``scopes``), and ``of_run`` finds them, with
+``leaf_spans``, in the cell's ``algorithms/<reference>.py``.
 
 One neutral form, checked against a small recorded trace under the tests
 directory, so the arithmetic does not depend on the profiler's reader::
@@ -36,6 +40,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from perfbench import loader
 from perfbench.trace_reduce import DEVICE_PLANE, OPS_LINE, SYNC, clip, overlap, short_name, union
 
 MODULES_LINE = "XLA Modules"
@@ -43,17 +48,6 @@ MODULES_LINE = "XLA Modules"
 PATH_STAT = "tf_op"
 PROGRAM_STAT = "program_id"
 
-#: the programs the Dreamer-V3 loop dispatches (howto/telemetry.md)
-PROGRAMS = ("ring_write", "ring_amend", "ring_gather_sequences", "dv3_train_step", "dv3_player_step", "dv3_player_reset", "dv3_target_ema")
-TRAIN = "dv3_train_step"
-#: the scopes inside the train step
-SCOPES = ("dv3/wm/encode", "dv3/wm/rssm_scan", "dv3/wm/decode", "dv3/wm/optimizer", "dv3/behaviour/imagine",
-          "dv3/behaviour/actor_loss", "dv3/behaviour/optimizer", "dv3/critic/loss", "dv3/critic/optimizer")  # fmt: skip
-WORLD_MODEL = ("dv3/wm/encode", "dv3/wm/rssm_scan", "dv3/wm/decode")
-BEHAVIOUR = ("dv3/behaviour/imagine", "dv3/behaviour/actor_loss", "dv3/critic/loss")
-OPTIMIZER = ("dv3/wm/optimizer", "dv3/behaviour/optimizer", "dv3/critic/optimizer")
-#: the host's leaf spans, nested in the loop's two window spans
-LEAF_SPANS = ("player/get_actions", "ring/add", "env/step", "loop/store_step", "replay/draw", "train/dispatch", "train/block")
 
 Neutral = Dict[str, Any]
 
@@ -147,7 +141,7 @@ def op_metadata(buf: bytes, plane_prefix: str = DEVICE_PLANE) -> Dict[str, List[
 
 
 def module_program(name: str) -> Tuple[str, str]:
-    """``("dv3_train_step", "1366...")`` from ``jit_dv3_train_step(1366...)``."""
+    """``("train_step", "1366...")`` from ``jit_train_step(1366...)``."""
     head, _, rest = name.partition("(")
     return (head[4:] if head.startswith("jit_") else head), rest.rstrip(")")
 
@@ -212,11 +206,11 @@ def save(neutral: Neutral, path: str, keep: Optional[Tuple[float, float]] = None
 
 
 @functools.lru_cache(maxsize=65536)  # a trace has some thousands of distinct paths over its hundreds of thousands of ops
-def scope_of(path: str) -> Tuple[Optional[str], bool]:
-    """The train step's scope an op_name path lies in, and whether the op is
-    of the backward pass (``transpose(jvp(<scope>))``, or ``<scope>/transpose(jvp())``
-    where the scope is around the ``value_and_grad``)."""
-    for scope in SCOPES:
+def scope_of(path: str, scopes: Tuple[str, ...]) -> Tuple[Optional[str], bool]:
+    """Which of the train step's ``scopes`` an op_name path lies in, and whether
+    the op is of the backward pass (``transpose(jvp(<scope>))``, or
+    ``<scope>/transpose(jvp())`` where the scope is around the ``value_and_grad``)."""
+    for scope in scopes:
         at = path.find(scope)
         if at >= 0 and path[at + len(scope) : at + len(scope) + 1] in ("/", ")", ":", ""):
             return scope, "transpose(" in path
@@ -227,30 +221,33 @@ def _intervals(events: Sequence[Sequence[Any]]) -> np.ndarray:
     return np.asarray([(e[1], e[1] + e[2]) for e in events], np.float64).reshape(-1, 2)
 
 
-def reduce(neutral: Neutral, *, sync_mono_ns: float, window_mono_ns: Tuple[float, float], spans_mono_ns: np.ndarray,
-           env_steps_mono_ns: np.ndarray) -> Dict[str, Any]:  # fmt: skip
+def reduce(neutral: Neutral, *, programs: Sequence[str], train_program: str, scopes: Sequence[str], sync_mono_ns: float,
+           window_mono_ns: Tuple[float, float], spans_mono_ns: np.ndarray, env_steps_mono_ns: np.ndarray) -> Dict[str, Any]:  # fmt: skip
     """Over the traced stretch ``window_mono_ns``: device seconds and
-    executions by program, the train step's self time by scope, and the idle
-    time under no leaf span (``spans_mono_ns``, ``[n, 2]``) nor env 0's
-    ``step()``. All ``*_mono_ns`` are on the host's monotonic clock, as is
-    ``sync_mono_ns``, the time read inside the sync annotation."""
+    executions by program (``programs``: the ones the algorithm names, whose
+    cover is ``named_busy_s``), the self time by scope inside executions of
+    ``train_program``, and the idle time under no leaf span (``spans_mono_ns``,
+    ``[n, 2]``) nor env 0's ``step()``. All ``*_mono_ns`` are on the host's
+    monotonic clock, as is ``sync_mono_ns``, the time read inside the sync
+    annotation."""
+    scopes = tuple(scopes)
     shift = neutral["sync"][0] + neutral["sync"][1] / 2.0 - sync_mono_ns  # monotonic -> trace
     lo, hi = window_mono_ns[0] + shift, window_mono_ns[1] + shift
-    programs: Dict[str, Dict[str, float]] = {}
+    by_program: Dict[str, Dict[str, float]] = {}
     named, train_runs = [], []
     for name, start, dur in neutral["modules"]:
         end = start + dur
         if end <= lo or start >= hi:
             continue
         program = module_program(name)[0]
-        entry = programs.setdefault(program, {"seconds": 0.0, "whole_seconds": 0.0, "executions": 0})
+        entry = by_program.setdefault(program, {"seconds": 0.0, "whole_seconds": 0.0, "executions": 0})
         entry["seconds"] += (min(end, hi) - max(start, lo)) / 1e9
-        if program in PROGRAMS:
+        if program in programs:
             named.append((start, end))
         if start >= lo and end <= hi:  # an execution cut by the stretch's edge is not a sample
             entry["whole_seconds"] += dur / 1e9
             entry["executions"] += 1
-            if program == TRAIN:
+            if program == train_program:
                 train_runs.append((start, end))
     ops = [e for e in neutral["ops"] if e[1] + e[2] > lo and e[1] < hi]
     cover = clip(union(_intervals(ops)), lo, hi)
@@ -260,7 +257,7 @@ def reduce(neutral: Neutral, *, sync_mono_ns: float, window_mono_ns: Tuple[float
     # self time by scope inside whole train-step executions: an op without a
     # scope of its own (a body op whose metadata has no path) takes the scope
     # of the op it is nested in, as a ``while`` spans the ops of its body
-    scopes: Dict[str, Dict[str, float]] = {s: {"forward": 0.0, "backward": 0.0} for s in SCOPES}
+    by_scope: Dict[str, Dict[str, float]] = {s: {"forward": 0.0, "backward": 0.0} for s in scopes}
     unscoped = 0.0
     runs = np.asarray(train_runs, np.float64).reshape(-1, 2)
     stack: List[List[Any]] = []  # [end, self_ns, scope, backward]
@@ -272,7 +269,7 @@ def reduce(neutral: Neutral, *, sync_mono_ns: float, window_mono_ns: Tuple[float
             if scope is None:
                 unscoped += max(own, 0.0) / 1e9
             else:
-                scopes[scope]["backward" if backward else "forward"] += max(own, 0.0) / 1e9
+                by_scope[scope]["backward" if backward else "forward"] += max(own, 0.0) / 1e9
 
     if len(runs):
         for _, start, dur, path in sorted(ops, key=lambda e: (e[1], -e[2])):
@@ -280,7 +277,7 @@ def reduce(neutral: Neutral, *, sync_mono_ns: float, window_mono_ns: Tuple[float
             if i < 0 or start + dur > runs[i, 1]:
                 continue
             close(start)
-            scope, backward = scope_of(path)
+            scope, backward = scope_of(path, scopes)
             if scope is None and stack:
                 scope, backward = stack[-1][2], stack[-1][3]
             if stack:
@@ -296,10 +293,10 @@ def reduce(neutral: Neutral, *, sync_mono_ns: float, window_mono_ns: Tuple[float
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy_s,
-        "programs": programs,
+        "programs": by_program,
         "named_busy_s": overlap(cover, named_cover) / 1e9,
         "train_executions": len(train_runs),
-        "scopes": scopes,
+        "scopes": by_scope,
         "train_unscoped_s": unscoped,
         "idle_s": idle_s,
         "idle_unattributed_s": max(idle_s - accounted, 0.0),
@@ -352,9 +349,13 @@ def of_run(run: Any) -> Optional[Dict[str, Any]]:
     neutral = load(path)
     if neutral is None:
         return None
-    leaves = [(t0, t0 + d * 1e9) for name in LEAF_SPANS for t0, d in spans(run, name)]
+    tables = loader.algorithm(run.cell)
+    leaves = [(t0, t0 + d * 1e9) for name in tables.leaf_spans for t0, d in spans(run, name)]
     run.__dict__["_device_time"] = reduce(
         neutral,
+        programs=tables.programs,
+        train_program=tables.train_program,
+        scopes=tables.scopes,
         sync_mono_ns=(sync["before_ns"] + sync["inside_ns"]) / 2.0,
         window_mono_ns=(float(sync["inside_ns"]), float(run.window["close_ns"])),
         spans_mono_ns=np.asarray(leaves, np.float64).reshape(-1, 2),
@@ -370,6 +371,11 @@ def program_ms(reduced: Optional[Dict[str, Any]], program: str) -> Optional[floa
     if not entry or not entry["executions"]:
         return None
     return 1e3 * entry["whole_seconds"] / entry["executions"]
+
+
+def train_ms(run: Any) -> Optional[float]:
+    """Device milliseconds per execution of the train program of the cell's algorithm."""
+    return program_ms(of_run(run), loader.algorithm(run.cell).train_program)
 
 
 def scope_ms(reduced: Optional[Dict[str, Any]], scopes: Sequence[str]) -> Optional[float]:
@@ -392,7 +398,8 @@ def record(run: Any, path: str, ms: float = 320.0) -> None:
     end = float(run.window["close_ns"])
     cut = (end - ms * 1e6, end)
     save(neutral, path, keep=(cut[0] + shift, cut[1] + shift))
-    leaves = {name: [[t0, d] for t0, d in spans(run, name) if t0 + d * 1e9 >= cut[0] and t0 <= cut[1]] for name in LEAF_SPANS}
+    leaf_spans = loader.algorithm(run.cell).leaf_spans
+    leaves = {name: [[t0, d] for t0, d in spans(run, name) if t0 + d * 1e9 >= cut[0] and t0 <= cut[1]] for name in leaf_spans}
     steps = np.stack([run.entry_ns, run.exit_ns], 1)
     steps = steps[(steps[:, 1] >= cut[0]) & (steps[:, 0] <= cut[1])]
     with open(path.replace(".json.gz", "_host.json"), "w") as f:

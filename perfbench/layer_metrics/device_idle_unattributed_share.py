@@ -1,5 +1,5 @@
-"""Share of the traced stretch's idle time that lies under none of the seven
-leaf spans nor env 0's ``step()``."""
+"""Share of the traced stretch's idle time that lies under none of the
+algorithm's leaf spans (seven for Dreamer-V3) nor env 0's ``step()``."""
 
 from perfbench import device_time
 

@@ -6,7 +6,7 @@ from perfbench import device_time
 
 
 def read(run):
-    ms = device_time.program_ms(device_time.of_run(run), device_time.TRAIN)
+    ms = device_time.train_ms(run)
     if ms is None or run.peak is None:
         return None
     flops = run.cell.config["model_flops_per_grad_step"]

@@ -2,8 +2,8 @@
 
 The harness is driven by data: ``BENCHMARK.json`` names the cells, and each
 name leads to a file of its own under this directory. A later PR adds a
-configuration, a cell or a per-layer metric by adding a file and an entry,
-never by editing one that is there.
+configuration, a cell, a per-layer metric or an algorithm by adding files and
+entries, never by editing one that is there.
 """
 
 from __future__ import annotations
@@ -56,15 +56,18 @@ class Cell:
 
     def overrides(self, run_dir: str, stamps: str, seed: int, trace: bool) -> List[str]:
         """The program's command line: the configuration's overrides, the
-        cell's traffic, then what the harness itself adds (README.md lists
-        these; nothing else is set)."""
+        cell's traffic, then what the harness itself adds, which every recipe
+        composes (README.md lists these; nothing else is set). The env factory
+        is the configuration's ``env.make`` and is named nowhere else."""
         env = self.config["env"]
+        if "wrapper._target_" in self.config.get("env_overrides", {}):
+            raise SystemExit(f"perfbench: {self.config['name']} names its env factory in env_overrides: that is env.make's to say")
         return [
             *self.config["overrides"],
             *self.workload.get("overrides", []),
             "env=dummy",
             f"env.id={self.config['name']}",
-            "env.wrapper._target_=perfbench.env.make",
+            f"env.wrapper._target_={env.get('make', 'perfbench.env.make')}",
             f"+env.wrapper.spec={json.dumps(env, separators=(',', ':'))}",
             f"+env.wrapper.seed={seed}",
             "+env.wrapper.rank=0",
@@ -72,7 +75,6 @@ class Cell:
             *[f"env.{k}={v}" for k, v in self.config.get("env_overrides", {}).items()],
             f"seed={seed}",
             "env.capture_video=False",
-            "buffer.checkpoint=False",
             "checkpoint.every=1000000000",
             "checkpoint.save_last=False",
             "fabric.callbacks=[]",
@@ -86,7 +88,8 @@ class Cell:
 
 def algorithm(cell: Cell) -> Any:
     """``algorithms/<reference>.py``, by the ``reference`` key of the cell's
-    configuration: the bridge into the program and the comparison for ``correct``."""
+    configuration: the bridge into the program, the comparison for ``correct``
+    and the trace tables (README.md lists the names it supplies)."""
     return importlib.import_module(f"perfbench.algorithms.{cell.config['reference']}")
 
 

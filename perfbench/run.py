@@ -366,12 +366,12 @@ def _run_record(path: str) -> Dict[str, Any]:
 
 
 def _check_placement(cell, placement: Dict[str, Any]) -> None:
-    expect = cell.config["expect"]
-    for key in ("buffer_device", "player_device"):
-        if placement.get(key) != expect[key]:
-            raise SystemExit(
-                f"perfbench: {key} resolved to {placement.get(key)!r}; the configuration's file expects {expect[key]!r}"
-            )
+    """Every key of the configuration's ``expect`` (but ``platform``, which the
+    look for a chip has held) against what the algorithm's capture read of the
+    program, such as where its replay and its player resolved to."""
+    for key, want in cell.config["expect"].items():
+        if key != "platform" and placement.get(key) != want:
+            raise SystemExit(f"perfbench: {key} resolved to {placement.get(key)!r}; the configuration's file expects {want!r}")
 
 
 def main(argv: Optional[List[str]] = None) -> None:

@@ -2,7 +2,10 @@
 ``BENCHMARK.json`` leads to its file, every configuration composes and says
 what the program's recipe says, the FLOP function against a hand count, the
 window on synthetic timestamps, the trace reduction, and a configuration, a
-cell and a per-layer metric added as new files only."""
+cell and a per-layer metric added as new files only (an algorithm is added by
+``test_second_algorithm.py``, which then runs this whole file in a checkout
+whose ``BENCHMARK.json`` lists it: a test here that is about Dreamer-V3 names
+its cells, and one over every cell asks the cell's own algorithm)."""
 
 import glob
 import json
@@ -14,7 +17,7 @@ import sys
 import numpy as np
 import pytest
 
-from perfbench import bridge, flops, loader, run, trace_reduce, window
+from perfbench import flops, loader, run, trace_reduce, window
 from perfbench.loader import ROOT
 from tests.test_perfbench import tiny
 
@@ -23,12 +26,17 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in BENCH["workloads"]]
 CONFIG_FILES = sorted(glob.glob(os.path.join(ROOT, "perfbench", "configs", "*.json")))
 WORKLOAD_FILES = sorted(glob.glob(os.path.join(ROOT, "perfbench", "workloads", "*.json")))
+#: the command lines of the cells that PR 27 found, as the harness of PR 26 gave them
+with open(os.path.join(os.path.dirname(__file__), "recorded_command_lines.json")) as _f:
+    RECORDED_COMMANDS = json.load(_f)
 
 
 def _composed(overrides):
     from sheeprl_tpu.config import compose
 
-    return compose("config", overrides)
+    composed = compose("config", overrides).to_dict()
+    composed.pop("run_name", None)  # carries the second it was composed in
+    return composed
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -40,21 +48,29 @@ def test_every_name_leads_to_its_files(name):
     assert set(loader.layer_readers(cell)) == {m["name"] for m in cell.per_layer}
     moved = {m["name"] for m in cell.end_to_end}
     assert all(m["moves"] in moved for m in cell.per_layer)
+    # the limits carry names that the comparison of the cell's algorithm gives
     compared = set(cell.workload["limits"])
-    assert compared and compared <= set(tiny.TINY_LIMITS)
+    assert compared and compared <= set(tiny.rule(cell.config["reference"]).LIMITS)
 
 
 @pytest.mark.parametrize("path", CONFIG_FILES, ids=os.path.basename)
 def test_configuration_composes_and_states_the_recipe(path):
+    """The file is the one its entry names, composes under the harness's command line, says what the
+    program's recipe says (the algorithm's ``check_stated``) and states the algorithm's own FLOP count."""
     with open(path) as f:
         config = json.load(f)
     entry = {c["name"]: c for c in BENCH["configs"]}[config["name"]]
     assert entry["file"] == os.path.relpath(path, ROOT) and entry["reduced"] == config["reduced"]
     assert entry["source"] == config["source"] and len(config["source"]) <= 200
-    cell = [w["name"] for w in BENCH["workloads"] if w["config"] == config["name"]][0]
-    bridge.check_stated(config, _composed(loader.Cell(cell).overrides("/tmp/x", "/tmp/x/stamps", 0, False)))
-    assert config["model_flops_per_grad_step"] == flops.per_gradient_step(config)["total"]
-    assert config["env"]["episode_frames"]["low"] % config["algo"]["action_repeat"] == 0
+    cell = loader.Cell([w["name"] for w in BENCH["workloads"] if w["config"] == config["name"]][0])
+    algorithm = loader.algorithm(cell)
+    composed = _composed(cell.overrides("/tmp/x", "/tmp/x/stamps", 0, False))
+    algorithm.check_stated(config, composed)
+    assert config["model_flops_per_grad_step"] == algorithm.model_flops(config)
+    if "episode_frames" in config["env"]:  # a spec of the benchmark's own env: an episode ends on a whole policy step
+        assert config["env"]["episode_frames"]["low"] % config["algo"]["action_repeat"] == 0
+    # the way out of the window gathers a checkpoint: a recipe that keeps a replay buffer must not put it in
+    assert composed.get("buffer", {}).get("checkpoint", False) is False, "add buffer.checkpoint=False to the file's overrides"
     for key in config["reduced"]:
         assert config[key] != config["published"][key]
 
@@ -67,11 +83,44 @@ def test_workload_names_a_configuration_that_exists(path):
     assert workload["name"] == os.path.basename(path)[: -len(".json")]
 
 
+@pytest.mark.parametrize("name", sorted(RECORDED_COMMANDS["cells"]))
+def test_the_command_line_of_a_cell_is_the_parents(name):
+    """PR 27 moved ``buffer.checkpoint=False`` from the harness's list into the two configurations' own and
+    named the env factory from the file: the cells that were there are started with the same words as before
+    (the one that moved stands earlier in the line) and so with the same composed configuration."""
+    theirs, at = RECORDED_COMMANDS["cells"][name], RECORDED_COMMANDS["arguments"]
+    ours = loader.Cell(name).overrides(at["run_dir"], at["stamps"], at["seed"], at["trace"])
+    assert sorted(ours) == sorted(theirs)
+    assert _composed(ours) == _composed(theirs)
+    traced = loader.Cell(name).overrides(at["run_dir"], at["stamps"], at["seed"], True)
+    assert [a for a, b in zip(ours, traced) if a != b] == ["metric.telemetry.enabled=False"] and len(ours) == len(traced)
+
+
+@pytest.mark.parametrize("exp", ["ppo", "ppo_recurrent", "a2c", "sac", "dreamer_v3"])
+def test_a_recipe_composes_under_the_harness_overrides(exp, tmp_path, monkeypatch):
+    """What the harness adds is what every recipe has: the on-policy recipes keep no buffer to checkpoint."""
+    cell = loader.Cell(CELLS[0])
+    monkeypatch.setitem(cell.config, "overrides", [f"exp={exp}"])
+    composed = _composed(cell.overrides(str(tmp_path), str(tmp_path / "stamps"), 1, False))
+    assert ("checkpoint" in composed["buffer"]) == (exp in ("sac", "dreamer_v3"))
+    assert composed["env"]["wrapper"]["_target_"] == "perfbench.env.make" and composed["algo"]["run_test"] is False
+
+
+def test_the_env_factory_is_named_in_one_place(monkeypatch):
+    cell = loader.Cell(CELLS[0])
+    monkeypatch.setitem(cell.config, "env", {**cell.config["env"], "make": "somewhere.else.make"})
+    overrides = cell.overrides("/tmp/x", "/tmp/x/stamps", 1, False)
+    assert [o for o in overrides if o.startswith("env.wrapper._target_=")] == ["env.wrapper._target_=somewhere.else.make"]
+    monkeypatch.setitem(cell.config, "env_overrides", {"wrapper._target_": "a.third.make"})
+    with pytest.raises(SystemExit, match="env.make"):
+        cell.overrides("/tmp/x", "/tmp/x/stamps", 1, False)
+
+
 def test_stated_check_refuses_a_departure():
     cell = loader.Cell(CELLS[0])
     overrides = [*cell.overrides("/tmp/x", "/tmp/x/stamps", 0, False), "algo.dense_units=64"]
     with pytest.raises(SystemExit, match="algo.dense_units"):
-        bridge.check_stated(cell.config, _composed(overrides))
+        loader.algorithm(cell).check_stated(cell.config, _composed(overrides))
 
 
 def test_peaks_table_has_the_v5e_row_and_no_default():
@@ -95,10 +144,7 @@ def test_flops_of_one_dense_and_one_conv_by_hand():
 
 
 def test_flops_split_adds_up_and_grows_with_width():
-    with open(CONFIG_FILES[0]) as f:
-        small = json.load(f)
-    with open(CONFIG_FILES[1]) as f:
-        large = json.load(f)
+    small, large = (loader.Cell(name).config for name in ("dv3_S_walker.train", "dv3_XL_crafter.train"))
     for cfg in (small, large):
         split = flops.per_gradient_step(cfg)
         assert split["total"] == split["world_model"] + split["imagination"] + split["critic"]

@@ -6,7 +6,7 @@ program's place) fail."""
 import pytest
 
 from perfbench import correct
-from tests.test_perfbench import tiny
+from tests.test_perfbench import tiny_dreamer_v3 as tiny
 
 CONFIGS = ("dv3_S_walker", "dv3_XL_crafter")
 

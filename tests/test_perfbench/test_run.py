@@ -8,7 +8,7 @@ import json
 import pytest
 
 from perfbench import run
-from tests.test_perfbench import tiny
+from tests.test_perfbench import tiny, tiny_dreamer_v3
 
 
 @pytest.fixture(scope="module")
@@ -111,8 +111,6 @@ def test_the_players_control_and_a_wrong_observation_fail(root):
 
     from perfbench import bridge, correct
     from perfbench.references import dreamer_v3 as reference
-    from tests.test_perfbench import tiny
-
     if "dv3_S_walker" not in KEPT:
         run.run_cell("dv3_S_walker.train", 2**31 + 17, 1.0, False, root=root, require_tpu=False, verify=_verify_and_keep)
     cfg, capture, _ = KEPT["dv3_S_walker"]
@@ -120,9 +118,9 @@ def test_the_players_control_and_a_wrong_observation_fail(root):
     wm, actor, _ = jax.device_put(capture.seeded)
     ref = correct.player_side(reference.Model(cfg, "float32"), wm, actor, capture.player)
     sound = correct.player_gaps(correct._stacked(capture.player), ref)
-    assert all(sound[k] <= tiny.TINY_LIMITS[k] for k in ("player_h", "player_z", "player_action")), sound
+    assert all(sound[k] <= tiny_dreamer_v3.LIMITS[k] for k in ("player_h", "player_z", "player_action")), sound
     control = correct.player_gaps(correct.player_side(reference.Model(cfg, "float8"), wm, actor, capture.player), ref)
-    assert control["player_h"] > 10 * tiny.TINY_LIMITS["player_h"], control
+    assert control["player_h"] > 10 * tiny_dreamer_v3.LIMITS["player_h"], control
     shifted = [{**call, "obs": {k: v[::-1] for k, v in call["obs"].items()}} for call in capture.player]
     wrong = correct.player_gaps(correct._stacked(capture.player), correct.player_side(reference.Model(cfg, "float32"), wm, actor, shifted))
-    assert wrong["player_z"] > 10 * tiny.TINY_LIMITS["player_z"], wrong
+    assert wrong["player_z"] > 10 * tiny_dreamer_v3.LIMITS["player_z"], wrong
