@@ -68,6 +68,7 @@ from sheeprl_tpu.ops.rollout_scan import (
 )
 from sheeprl_tpu.ops.superstep import fused_fallback, reset_fused_fallback_warnings
 from sheeprl_tpu.parallel.shard_map import shard_map
+from sheeprl_tpu.resilience import RunResilience
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator
 from sheeprl_tpu.utils.prealloc import RolloutStore
@@ -82,14 +83,17 @@ def build_sequences(
     seq_len: int,
     num_envs: int,
     pad_multiple: int,
+    carry_keys: Tuple[str, ...] = ("prev_hx", "prev_cx"),
 ) -> Dict[str, np.ndarray]:
     """Split the ``[T, E, ...]`` rollout into per-episode chunks of at most
     ``seq_len`` steps (reference :406-444), pad each chunk to ``seq_len`` and
     the chunk count to a multiple of ``pad_multiple``. Only ``train_keys``
-    are shipped as ``[seq_len, N_pad, ...]`` arrays; the chunk-initial LSTM
-    states are emitted once per sequence as ``hx0``/``cx0`` ``[N_pad, H]``
-    (the update reads nothing else from them), plus a ``mask`` of valid
-    steps."""
+    are shipped as ``[seq_len, N_pad, ...]`` arrays; what the sequence core
+    carried into each chunk's first step (``carry_keys``, stored per step as
+    ``prev_<name>``: the LSTM's ``hx``/``cx``, or the decoder core's cache
+    length and row) is emitted once per sequence as ``<name>0`` ``[N_pad,
+    ...]`` (the update reads nothing else from them), plus a ``mask`` of
+    valid steps."""
     T = next(iter(local_data.values())).shape[0]
     chunks: List[Dict[str, np.ndarray]] = []
     starts: List[Tuple[int, int]] = []  # (env, t) of each chunk's first step
@@ -120,14 +124,12 @@ def build_sequences(
     for j, ln in enumerate(lengths):
         mask[:ln, j] = 1.0
     out["mask"] = mask
-    hidden = local_data["prev_hx"].shape[-1]
-    hx0 = np.zeros((n_pad, hidden), np.float32)
-    cx0 = np.zeros((n_pad, hidden), np.float32)
-    for j, (e, t) in enumerate(starts):
-        hx0[j] = local_data["prev_hx"][t, e]
-        cx0[j] = local_data["prev_cx"][t, e]
-    out["hx0"] = hx0
-    out["cx0"] = cx0
+    for key in carry_keys:
+        stored = local_data[key]
+        first = np.zeros((n_pad, *stored.shape[2:]), stored.dtype)
+        for j, (e, t) in enumerate(starts):
+            first[j] = stored[t, e]
+        out[key[len("prev_") :] + "0"] = first
     return out
 
 
@@ -252,6 +254,13 @@ def make_train_fn(fabric, agent, tx, cfg, obs_keys):
     return jax.jit(train_fn, donate_argnums=(0, 1))
 
 
+def _aggregator(cfg: Dict[str, Any]) -> MetricAggregator:
+    aggregator = MetricAggregator(cfg.metric.get("aggregator", {}).get("metrics", {}) or {})
+    for k in AGGREGATOR_KEYS - set(aggregator.metrics):
+        aggregator.add(k, "mean")
+    return aggregator
+
+
 @register_algorithm()
 def main(fabric, cfg: Dict[str, Any]):
     if cfg.checkpoint.resume_from:
@@ -268,6 +277,9 @@ def main(fabric, cfg: Dict[str, Any]):
     fabric.logger = logger
     logger.log_hyperparams(cfg.to_dict() if hasattr(cfg, "to_dict") else dict(cfg))
     print(f"Log dir: {log_dir}")
+
+    # preemption watcher + non-finite sentinel + checkpoint rollback
+    resil = RunResilience(fabric, cfg, log_dir)
 
     initial_clip_coef = float(cfg.algo.clip_coef)
     initial_ent_coef = float(cfg.algo.ent_coef)
@@ -286,6 +298,20 @@ def main(fabric, cfg: Dict[str, Any]):
             "You should specify at least one CNN key or MLP key from the cli: "
             "`algo.cnn_keys.encoder=[rgb]` or `algo.mlp_keys.encoder=[state]`"
         )
+
+    if str((cfg.algo.get("core") or {}).get("name", "lstm")) == "decoder":
+        # the decoder core (a policy over tokens): another carry, another loop body, the same main around it
+        from sheeprl_tpu.algos.ppo_recurrent.token_policy import run_token_policy
+
+        preempted = run_token_policy(
+            fabric, cfg, envs, state if cfg.checkpoint.resume_from else None, log_dir, logger, resil, _aggregator(cfg)
+        )
+        envs.close()
+        logger.finalize()
+        resil.close()
+        if preempted:
+            resil.exit_preempted()
+        return
 
     is_continuous = isinstance(envs.single_action_space, gym.spaces.Box)
     is_multidiscrete = isinstance(envs.single_action_space, gym.spaces.MultiDiscrete)
@@ -349,9 +375,7 @@ def main(fabric, cfg: Dict[str, Any]):
     if fabric.is_global_zero:
         save_configs(cfg, log_dir)
 
-    aggregator = MetricAggregator(cfg.metric.get("aggregator", {}).get("metrics", {}) or {})
-    for k in AGGREGATOR_KEYS - set(aggregator.metrics):
-        aggregator.add(k, "mean")
+    aggregator = _aggregator(cfg)
 
     train_fn = make_train_fn(fabric, agent, tx, cfg, obs_keys)
     gae_fn = jax.jit(partial(gae, gamma=float(cfg.algo.gamma), gae_lambda=float(cfg.algo.gae_lambda)))
@@ -445,6 +469,39 @@ def main(fabric, cfg: Dict[str, Any]):
     cx = np.zeros((num_envs, agent.lstm_hidden_size), np.float32)
     prev_actions = np.zeros((num_envs, n_actions), np.float32)
 
+    def ckpt_state_fn(completed_update: int) -> Dict[str, Any]:
+        # shared by the periodic save and the preemption drain's emergency
+        # save: reads the loop's CURRENT bindings at call time
+        return {
+            "agent": jax.device_get(params),
+            "opt_state": jax.device_get(opt_state),
+            "update": completed_update,
+            "batch_size": int(cfg.algo.per_rank_batch_size) * world_size,
+            "last_log": last_log,
+            "last_checkpoint": last_checkpoint,
+            "rng_key": jax.device_get(key),
+            "player_rng_key": jax.device_get(player_key),
+        }
+
+    def ckpt_path_fn(step: int) -> str:
+        return os.path.join(log_dir, "checkpoint", f"ckpt_{step}_{rank}.ckpt")
+
+    def drain_if_preempted(update: int) -> bool:
+        # the update has NOT run yet: the emergency checkpoint records
+        # update-1 so auto-resume replays from exactly this boundary
+        nonlocal last_checkpoint
+        if not resil.preempt_requested():
+            return False
+        last_checkpoint = policy_step
+        resil.emergency_checkpoint(ckpt_path_fn(policy_step), ckpt_state_fn(update - 1))
+        return True
+
+    # a crash anywhere in the loop gets the preemption treatment too
+    resil.arm_crash_guard(
+        path_fn=lambda: ckpt_path_fn(policy_step),
+        state_fn=lambda: ckpt_state_fn(update - 1),
+    )
+    preempted = False
     steps_per_dispatch = int(cfg.algo.update_epochs) * num_batches
     if superstep_fn is not None:
         # ------------------------------------------------------------------
@@ -474,6 +531,9 @@ def main(fabric, cfg: Dict[str, Any]):
         )
         for update in range(start_update, num_updates + 1):
             telemetry_advance(policy_step)
+            if drain_if_preempted(update):
+                preempted = True
+                break
             if update == start_update + 1:
                 # no bench probe in this loop — warm the recompile watchdog here
                 telemetry_mark_warm()
@@ -558,18 +618,9 @@ def main(fabric, cfg: Dict[str, Any]):
                 update == num_updates and cfg.checkpoint.save_last
             ):
                 last_checkpoint = policy_step
-                ckpt_state = {
-                    "agent": jax.device_get(params),
-                    "opt_state": jax.device_get(opt_state),
-                    "update": update,
-                    "batch_size": int(cfg.algo.per_rank_batch_size) * world_size,
-                    "last_log": last_log,
-                    "last_checkpoint": last_checkpoint,
-                    "rng_key": jax.device_get(key),
-                    "player_rng_key": jax.device_get(player_key),
-                }
-                ckpt_path = os.path.join(log_dir, "checkpoint", f"ckpt_{policy_step}_{rank}.ckpt")
-                fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path, state=ckpt_state)
+                fabric.call(
+                    "on_checkpoint_coupled", ckpt_path=ckpt_path_fn(policy_step), state=ckpt_state_fn(update)
+                )
         # the player sampled nothing during the fused loop; publish the final
         # params once for the eval rollout below
         player.update_params(params)
@@ -579,6 +630,9 @@ def main(fabric, cfg: Dict[str, Any]):
         # write is itself the copy), no end-of-window np.stack
         store = RolloutStore(rollout_steps)
         for update in range(start_update, num_updates + 1):
+            if drain_if_preempted(update):
+                preempted = True
+                break
             buf = store.begin(update)
             with timer("Time/env_interaction_time"):
                 # fused rollout step: key folding, sampling and the real-action
@@ -757,21 +811,12 @@ def main(fabric, cfg: Dict[str, Any]):
                 update == num_updates and cfg.checkpoint.save_last
             ):
                 last_checkpoint = policy_step
-                ckpt_state = {
-                    "agent": jax.device_get(params),
-                    "opt_state": jax.device_get(opt_state),
-                    "update": update,
-                    "batch_size": int(cfg.algo.per_rank_batch_size) * world_size,
-                    "last_log": last_log,
-                    "last_checkpoint": last_checkpoint,
-                    "rng_key": jax.device_get(key),
-                    "player_rng_key": jax.device_get(player_key),
-                }
-                ckpt_path = os.path.join(log_dir, "checkpoint", f"ckpt_{policy_step}_{rank}.ckpt")
-                fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path, state=ckpt_state)
+                fabric.call(
+                    "on_checkpoint_coupled", ckpt_path=ckpt_path_fn(policy_step), state=ckpt_state_fn(update)
+                )
 
     envs.close()
-    if fabric.is_global_zero and cfg.algo.run_test:
+    if fabric.is_global_zero and cfg.algo.run_test and not preempted:
         if obs_widened:
             import warnings
 
@@ -779,3 +824,6 @@ def main(fabric, cfg: Dict[str, Any]):
         else:
             test(player, fabric, cfg, log_dir)
     logger.finalize()
+    resil.close()
+    if preempted:
+        resil.exit_preempted()

@@ -1,0 +1,583 @@
+"""A decoder-only token policy: RMSNorm, rotary embedding, multi-head latent
+attention in its two forms, SwiGLU, a routed expert layer that is told which
+experts it holds, the decoder block, and the multi-token-prediction module.
+
+The equations are DeepSeek-V2/V3's published forms (``glm4_moe_lite`` follows
+them): every size comes from :class:`SeqPolConfig`, nothing is fixed here.
+
+Unlike ``blocks.py`` this module is plain functions over one parameter tree
+(nested dicts whose leaves are named ``kernel``, ``embedding``, ``scale`` and
+``bias``, as flax names them, so ``parallel/fabric.py``'s partition rules
+resolve every leaf): the two forms of the attention read the same weights in
+two arrangements, which a ``linen`` module would have to share by hand.
+
+- :func:`forward_sequence` — the whole-sequence form, for the update and the
+  prefill: ``[B, S]`` tokens under a causal mask, each row optionally attending
+  first to a latent cache as it stood before the row's first position. It
+  expands the cache through ``W_kvb``.
+- :func:`decode_step` — one token per row through the latent cache. ``W_kvb``'s
+  key part is absorbed into the query and its value part into the output, so a
+  row attends over its 576-wide cache entries without expanding them.
+
+The cache holds, per layer and position, ``c_kv`` after its norm and ``k_rope``
+after its rotation (``kv_lora_rank + qk_rope_head_dim`` numbers), in the
+compute dtype.
+
+The expert layer routes over all ``n_routed_experts`` at the published width
+and computes only the ``held`` experts' part for the tokens routed to them,
+plus the shared expert; what absent experts would have added is left out.
+No token is dropped and there is no capacity factor: every routed pair on a
+held expert is computed, under whatever imbalance.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+Array = jax.Array
+Params = Dict[str, Any]
+
+@dataclass(frozen=True)
+class SeqPolConfig:
+    hidden_size: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int  # the router's width: all experts of the layer, held here or not
+    held_experts: Tuple[int, ...]  # which of them this share computes
+    num_experts_per_tok: int
+    n_shared_experts: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    first_k_dense_replace: int
+    num_hidden_layers: int
+    num_nextn_predict_layers: int
+    vocab_rows: int  # rows of the vocabulary held here: ids, logits, sampling and losses are over them
+    context: int  # positions a row's cache holds
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    #: routed pairs up to which the expert layer multiplies every held expert
+    #: with every token under a mask (a decode step); above it pairs are sorted
+    #: by expert and multiplied in groups
+    dense_pairs_max: int = 1024
+    #: rows of one chunk of the update's whole-sequence blocks (:func:`block_by_rows`)
+    row_chunk: int = 8
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+def config_from(node: Any) -> SeqPolConfig:
+    """``algo.core`` of a composed recipe (a mapping) as a :class:`SeqPolConfig`."""
+    get = node.get if hasattr(node, "get") else node.__getitem__
+    fields = {f: get(f) for f in SeqPolConfig.__dataclass_fields__ if get(f) is not None}
+    fields["held_experts"] = tuple(int(e) for e in fields["held_experts"])
+    return SeqPolConfig(**fields)
+
+
+# --------------------------------------------------------------------------- #
+# parameters
+# --------------------------------------------------------------------------- #
+
+
+def _dense(key: Array, fan_in: int, fan_out: int, std: float = 0.02) -> Params:
+    return {"kernel": std * jax.random.normal(key, (fan_in, fan_out), jnp.float32)}
+
+
+def _norm(width: int) -> Params:
+    return {"scale": jnp.ones((width,), jnp.float32)}
+
+
+def _swiglu_params(key: Array, width: int, inner: int) -> Params:
+    k = jax.random.split(key, 3)
+    return {"gate": _dense(k[0], width, inner), "up": _dense(k[1], width, inner), "down": _dense(k[2], inner, width)}
+
+
+def _layer_params(key: Array, cfg: SeqPolConfig, dense: bool) -> Params:
+    k = jax.random.split(key, 10)
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    attn = {
+        "q_a": _dense(k[0], d, cfg.q_lora_rank),
+        "q_norm": _norm(cfg.q_lora_rank),
+        "q_b": _dense(k[1], cfg.q_lora_rank, h * cfg.qk_head_dim),
+        "kv_a": _dense(k[2], d, cfg.cache_width),
+        "kv_norm": _norm(cfg.kv_lora_rank),
+        "kv_b": _dense(k[3], cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "o": _dense(k[4], h * cfg.v_head_dim, d),
+    }
+    layer = {"attn_norm": _norm(d), "attn": attn, "ffn_norm": _norm(d)}
+    if dense:
+        layer["mlp"] = _swiglu_params(k[5], d, cfg.intermediate_size)
+        return layer
+    n_held, inner = len(cfg.held_experts), cfg.moe_intermediate_size
+    layer["moe"] = {
+        # the router keeps its published width; its bias is e_score_correction_bias
+        "router": {"kernel": 0.02 * jax.random.normal(k[6], (d, cfg.n_routed_experts), jnp.float32),
+                   "bias": jnp.zeros((cfg.n_routed_experts,), jnp.float32)},  # fmt: skip
+        # the held experts' weights, stacked on a leading axis in the order of ``held_experts``
+        "experts": {name: {"kernel": 0.02 * jax.random.normal(kk, (n_held, *shape), jnp.float32)}
+                    for name, kk, shape in (("gate", k[7], (d, inner)), ("up", k[8], (d, inner)), ("down", k[9], (inner, d)))},  # fmt: skip
+        "shared": _swiglu_params(k[5], d, inner * cfg.n_shared_experts),
+    }
+    return layer
+
+
+def init_params(key: Array, cfg: SeqPolConfig) -> Params:
+    """Float32 parameters from a key: embedding and head over ``vocab_rows``,
+    ``first_k_dense_replace`` dense layers then expert layers, the value head,
+    and one multi-token-prediction module where the configuration has one."""
+    keys = jax.random.split(key, cfg.num_hidden_layers + 6)
+    d = cfg.hidden_size
+    params: Params = {
+        "embed": {"embedding": 0.02 * jax.random.normal(keys[0], (cfg.vocab_rows, d), jnp.float32)},
+        "layers": {str(i): _layer_params(keys[1 + i], cfg, dense=i < cfg.first_k_dense_replace) for i in range(cfg.num_hidden_layers)},
+        "final_norm": _norm(d),
+        "head": _dense(keys[-1], d, cfg.vocab_rows),
+        "value_head": _dense(keys[-2], d, 1),
+    }
+    if cfg.num_nextn_predict_layers:
+        params["mtp"] = {
+            "enorm": _norm(d),
+            "hnorm": _norm(d),
+            "eh_proj": _dense(keys[-3], 2 * d, d),
+            "block": _layer_params(keys[-4], cfg, dense=False),
+            "final_norm": _norm(d),
+        }
+    return params
+
+
+def _computes_in_float32(path: Tuple[Any, ...]) -> bool:
+    """The router's and the value head's kernels are used in float32 whatever the compute dtype."""
+    names = [getattr(k, "key", None) for k in path]
+    return "router" in names or "value_head" in names
+
+
+def low_precision(params: Params, dtype: Any) -> Params:
+    """The copy of ``params`` the matmuls read: kernels and the embedding in
+    the compute ``dtype``; norm scales, biases, the router and the value head
+    as they are."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x if x.ndim < 2 or _computes_in_float32(path) else x.astype(dtype), params
+    )
+
+
+@jax.custom_vjp
+def _read_copy(master: Array, copy: Array) -> Array:
+    return copy
+
+
+_read_copy.defvjp(lambda master, copy: (copy, None), lambda _, g: (g.astype(jnp.float32), jnp.zeros_like(g)))
+
+
+def reading_copy(params: Params, copy: Params) -> Params:
+    """``params`` with every leaf that ``copy`` holds in another dtype read
+    from ``copy`` (:func:`low_precision` of the same values), its gradient
+    going to the float32 leaf: what casting inside the program would compute,
+    without the program making, and keeping, a cast of every weight."""
+    return jax.tree.map(lambda w, c: w if c.dtype == w.dtype else _read_copy(w, c), params, copy)
+
+
+# --------------------------------------------------------------------------- #
+# the pieces
+# --------------------------------------------------------------------------- #
+
+
+def rms_norm(x: Array, scale: Array, eps: float) -> Array:
+    """In float32 whatever ``x`` is; returns ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    return (xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * scale).astype(x.dtype)
+
+
+def rope(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary embedding of the last axis by ``positions`` (broadcast against
+    ``x``'s leading axes): pairs are ``(i, i + d/2)``, all ``d`` dims rotated."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def _mm(x: Array, kernel: Array) -> Array:
+    return jnp.dot(x, kernel.astype(x.dtype))
+
+
+def swiglu(p: Params, x: Array) -> Array:
+    return _mm(jax.nn.silu(_mm(x, p["gate"]["kernel"])) * _mm(x, p["up"]["kernel"]), p["down"]["kernel"])
+
+
+def _queries(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[Array, Array]:
+    """``q_nope [..., H, nope]`` and rotated ``q_rope [..., H, rope]``."""
+    c_q = rms_norm(_mm(x, p["q_a"]["kernel"]), p["q_norm"]["scale"], cfg.rms_norm_eps)
+    q = _mm(c_q, p["q_b"]["kernel"]).reshape(*x.shape[:-1], cfg.num_attention_heads, cfg.qk_head_dim)
+    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
+    return q_nope, rope(q_rope, positions[..., None], cfg.rope_theta)
+
+
+def latent_kv(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[Array, Array]:
+    """What the cache holds of ``x``: ``c_kv`` after its norm, ``k_rope`` after its rotation."""
+    kv = _mm(x, p["kv_a"]["kernel"])
+    c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], p["kv_norm"]["scale"], cfg.rms_norm_eps)
+    return c_kv, rope(kv[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+
+
+def _kv_b(p: Params, cfg: SeqPolConfig, dtype: Any) -> Tuple[Array, Array]:
+    """``W_kvb`` as its key part ``[c, H, nope]`` and its value part ``[c, H, v]``."""
+    w = p["kv_b"]["kernel"].astype(dtype).reshape(cfg.kv_lora_rank, cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+#: queries of one block of the whole-sequence attention: the scores of a block
+#: are all that is alive at once, and the backward pass computes them again
+QUERY_BLOCK = 128
+
+
+def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
+                 ctx: Optional[Tuple[Array, Array, Array, Array]] = None) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
+    """Whole-sequence latent attention. ``x [B, S, D]``; ``positions [B, S]``
+    (rotary positions, rising along a row); ``valid [B, S]`` marks real slots
+    (padding neither attends nor is attended to). ``ctx = (c_kv [E, C, c],
+    k_rope [E, C, r], row [B], length [B])`` is a cache as it stood before the
+    rows' first slots: row ``b`` attends to the first ``length[b]`` entries of
+    the cache's row ``row[b]`` too. Returns the output ``[B, S, D]`` and the
+    rows' own ``(c_kv, k_rope)``."""
+    B, S, _ = x.shape
+    H = cfg.num_attention_heads
+    q_nope, q_rope = _queries(p, cfg, x, positions)
+    c_kv, k_rope = latent_kv(p, cfg, x, positions)
+    keys_c, keys_r, key_ok = c_kv, k_rope, valid
+    # a key is seen by the queries at or after it: by slot among the row's own, always for the cache
+    key_slot = jnp.broadcast_to(jnp.arange(S), (B, S))
+    if ctx is not None:
+        ctx_c, ctx_r, ctx_len = ctx[0][ctx[2]], ctx[1][ctx[2]], ctx[3]
+        C = ctx_c.shape[1]
+        keys_c = jnp.concatenate([ctx_c.astype(x.dtype), c_kv], axis=1)
+        keys_r = jnp.concatenate([ctx_r.astype(x.dtype), k_rope], axis=1)
+        key_ok = jnp.concatenate([jnp.arange(C)[None, :] < ctx_len[:, None], valid], axis=1)
+        key_slot = jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1)
+    w_k, w_v = _kv_b(p, cfg, x.dtype)
+    k_nope = jnp.einsum("bkc,chd->bkhd", keys_c, w_k)
+    v = jnp.einsum("bkc,chd->bkhd", keys_c, w_v)
+    scale = cfg.qk_head_dim**-0.5
+
+    @jax.checkpoint
+    def block(qn, qr, q_slot):
+        s = jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope, preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bqhr,bkr->bhqk", qr, keys_r, preferred_element_type=jnp.float32)
+        seen = key_ok[:, None, None, :] & (key_slot[:, None, None, :] <= q_slot[None, None, :, None])
+        w = jax.nn.softmax(jnp.where(seen, s * scale, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", w.astype(x.dtype), v)
+
+    n = max(1, S // QUERY_BLOCK) if S % QUERY_BLOCK == 0 else 1
+    if n == 1:
+        out = block(q_nope, q_rope, jnp.arange(S))
+    else:
+        split = lambda a: jnp.moveaxis(a.reshape(B, n, S // n, *a.shape[2:]), 1, 0)  # noqa: E731
+        out = lax.map(lambda t: block(*t), (split(q_nope), split(q_rope), jnp.arange(S).reshape(n, S // n)))
+        out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, cfg.v_head_dim)
+    out = _mm(out.reshape(B, S, H * cfg.v_head_dim), p["o"]["kernel"])
+    return out, (c_kv, k_rope)
+
+
+def mla_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, cache_c: Array, cache_r: Array) -> Tuple[Array, Array, Array]:
+    """One token per row against one layer's latent cache, in the absorbed
+    form. ``x [E, D]``; ``positions [E]`` (the row's cache length: where its
+    entry goes); ``cache_c [E, C, c]``, ``cache_r [E, C, r]``. Returns the
+    output ``[E, D]`` and the two caches with the rows' entries written (in
+    place, where the caller donates them)."""
+    E = x.shape[0]
+    q_nope, q_rope = _queries(p, cfg, x, positions)
+    c_kv, k_rope = latent_kv(p, cfg, x, positions)
+    rows = jnp.arange(E)
+    cache_c = cache_c.at[rows, positions].set(c_kv.astype(cache_c.dtype), mode="drop")
+    cache_r = cache_r.at[rows, positions].set(k_rope.astype(cache_r.dtype), mode="drop")
+    w_k, w_v = _kv_b(p, cfg, x.dtype)
+    q_lat = jnp.einsum("ehd,chd->ehc", q_nope, w_k)  # W_kvb's key part, absorbed into the query
+    s = jnp.einsum("ehc,etc->eht", q_lat, cache_c.astype(x.dtype), preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("ehr,etr->eht", q_rope, cache_r.astype(x.dtype), preferred_element_type=jnp.float32)
+    seen = jnp.arange(cache_c.shape[1])[None, None, :] <= positions[:, None, None]
+    w = jax.nn.softmax(jnp.where(seen, s * cfg.qk_head_dim**-0.5, -1e30), axis=-1)
+    o_lat = jnp.einsum("eht,etc->ehc", w.astype(x.dtype), cache_c.astype(x.dtype))
+    out = jnp.einsum("ehc,chd->ehd", o_lat, w_v)  # and its value part, into the output
+    return _mm(out.reshape(E, -1), p["o"]["kernel"]), cache_c, cache_r
+
+
+# --------------------------------------------------------------------------- #
+# the expert layer
+# --------------------------------------------------------------------------- #
+
+
+def route(p: Params, cfg: SeqPolConfig, x: Array) -> Tuple[Array, Array]:
+    """``(expert ids [T, k], weights [T, k])`` over all ``n_routed_experts``:
+    sigmoid scores in float32, the choice by score plus correction bias, the
+    weights the scores themselves at the chosen experts, normalised and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["router"]["kernel"], precision=lax.Precision.HIGHEST))
+    _, chosen = lax.top_k(scores + p["router"]["bias"], cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return chosen, weights * cfg.routed_scaling_factor
+
+
+def _held_slot(cfg: SeqPolConfig, chosen: Array) -> Array:
+    """Each chosen expert's index among the held ones, or ``-1``."""
+    table = [-1] * cfg.n_routed_experts
+    for slot, expert in enumerate(cfg.held_experts):
+        table[expert] = slot
+    return jnp.asarray(table, jnp.int32)[chosen]
+
+
+def _experts_dense(p: Params, x: Array, slot: Array, weights: Array, n_held: int) -> Array:
+    """Every held expert on every token, kept where the token chose it: for a
+    handful of tokens, where the weights' bytes are the cost either way."""
+    gate = jnp.einsum("td,edf->etf", x, p["gate"]["kernel"].astype(x.dtype))
+    up = jnp.einsum("td,edf->etf", x, p["up"]["kernel"].astype(x.dtype))
+    y = jnp.einsum("etf,efd->etd", jax.nn.silu(gate) * up, p["down"]["kernel"].astype(x.dtype))
+    w = jnp.where(slot[None] == jnp.arange(n_held)[:, None, None], weights[None], 0.0).sum(-1)  # [e, T]
+    return jnp.einsum("etd,et->td", y, w.astype(x.dtype))
+
+
+def _experts_grouped(p: Params, x: Array, slot: Array, weights: Array, n_held: int, rows: Optional[int] = None) -> Array:
+    """The routed pairs on held experts, sorted by expert and multiplied in
+    groups (``lax.ragged_dot``); pairs on absent experts sort last, fall in no
+    group and add nothing. A grouped product costs what its buffer holds, not
+    what its groups hold, so the products run over the first ``rows`` sorted
+    pairs when every held pair lies among them and over all pairs when not:
+    none can be dropped. A row in no group comes out of a grouped product as
+    whatever the memory held, NaN included, forward and backward: every
+    product's result is put to zero there before anything is multiplied with it."""
+    T, k = slot.shape
+    flat = slot.reshape(-1)
+    order = jnp.argsort(jnp.where(flat < 0, n_held, flat), stable=True)
+    sizes = jnp.bincount(jnp.where(flat < 0, n_held, flat), length=n_held + 1)[:n_held].astype(jnp.int32)
+    n_pairs = sizes.sum()
+
+    def over(n: int) -> Array:
+        first = order[:n]
+        token = first // k
+        held = (jnp.arange(n) < n_pairs)[:, None]
+
+        def grouped(lhs: Array, kernel: Array) -> Array:
+            return jnp.where(held, lax.ragged_dot(jnp.where(held, lhs, 0), kernel.astype(x.dtype), sizes), 0)
+
+        xs = x[token]
+        y = grouped(jax.nn.silu(grouped(xs, p["gate"]["kernel"])) * grouped(xs, p["up"]["kernel"]), p["down"]["kernel"])
+        return jnp.zeros_like(x).at[token].add(y * weights.reshape(-1)[first][:, None].astype(x.dtype))
+
+    if rows is None or rows >= T * k:
+        return over(T * k)
+    return lax.cond(n_pairs <= rows, lambda: over(rows), lambda: over(T * k))
+
+
+#: the grouped products run over this many times the pairs that even routing
+#: puts on the held experts when the held pairs fit in that many rows, and over
+#: the whole buffer of pairs when they do not (:func:`_experts_grouped`)
+GROUPED_ROWS_FACTOR = 2.0
+
+
+def grouped_rows(cfg: SeqPolConfig, pairs: int) -> int:
+    """Rows of the sorted buffer that the grouped products cover when the held
+    pairs fit: :data:`GROUPED_ROWS_FACTOR` times the held experts' even share
+    of ``pairs``, in whole eights, and never more than ``pairs``."""
+    even = pairs * len(cfg.held_experts) / cfg.n_routed_experts
+    return min(pairs, -(-int(GROUPED_ROWS_FACTOR * even) // 8) * 8)
+
+
+def moe(p: Params, cfg: SeqPolConfig, x: Array, real: Optional[Array] = None) -> Tuple[Array, Array]:
+    """``x [T, D]`` -> the shared expert plus the held experts' part of the
+    routed sum, and the layer's counts ``[routed pairs, pairs on each held
+    expert...]`` (float32). ``real [T]`` marks the rows that are tokens:
+    padding is routed nowhere."""
+    T, n_held = x.shape[0], len(cfg.held_experts)
+    with jax.named_scope("seqpol/moe/route"):
+        chosen, weights = route(p, cfg, x)
+        slot = _held_slot(cfg, chosen)
+        if real is not None:  # a padded slot routes nowhere: it costs no expert and counts in no load
+            slot = jnp.where(real[:, None], slot, -1)
+        load = (slot[None] == jnp.arange(n_held)[:, None, None]).sum((1, 2))
+        n_real = T if real is None else real.sum()
+        counts = jnp.concatenate([jnp.reshape(n_real * cfg.num_experts_per_tok, (1,)), load]).astype(jnp.float32)
+    with jax.named_scope("seqpol/moe/experts"):
+        if T * cfg.num_experts_per_tok <= cfg.dense_pairs_max:
+            routed = _experts_dense(p["experts"], x, slot, weights, n_held)
+        else:
+            routed = _experts_grouped(p["experts"], x, slot, weights, n_held, grouped_rows(cfg, T * cfg.num_experts_per_tok))
+    with jax.named_scope("seqpol/moe/shared"):
+        shared = swiglu(p["shared"], x)
+    return shared + routed, counts
+
+
+def counters_of(counts: Array) -> Array:
+    """A layer's counts as the three counters ``[routed pairs, pairs on held
+    experts, the busiest held expert's pairs]``."""
+    return jnp.stack([counts[0], counts[1:].sum(), counts[1:].max()])
+
+
+def merge_counters(counters: Array, layer: Array) -> Array:
+    """Pair counts add over layers; the busiest expert's load is a maximum over them."""
+    return jnp.stack([counters[0] + layer[0], counters[1] + layer[1], jnp.maximum(counters[2], layer[2])])
+
+
+# --------------------------------------------------------------------------- #
+# blocks and the two forward forms
+# --------------------------------------------------------------------------- #
+
+
+def _ffn(p: Params, cfg: SeqPolConfig, x: Array, real: Optional[Array]) -> Tuple[Array, Array]:
+    if "mlp" in p:
+        with jax.named_scope("seqpol/mlp"):
+            return swiglu(p["mlp"], x), jnp.zeros((1 + len(cfg.held_experts),), jnp.float32)
+    flat = x.reshape(-1, x.shape[-1])
+    y, counts = moe(p["moe"], cfg, flat, None if real is None else real.reshape(-1))
+    return y.reshape(x.shape), counts
+
+
+def block_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
+                   ctx: Optional[Tuple[Array, Array, Array, Array]]) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
+    """One decoder block on whole rows: the output, the rows' own cache entries and the expert layer's counts."""
+    with jax.named_scope("seqpol/attn"):
+        a, kv = mla_sequence(p["attn"], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, valid, ctx)
+    x = x + a
+    f, counts = _ffn(p, cfg, rms_norm(x, p["ffn_norm"]["scale"], cfg.rms_norm_eps), valid)
+    return x + f, kv, counts
+
+
+def block_by_rows(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
+                  ctx: Optional[Tuple[Array, Array, Array, Array]], remat: bool) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
+    """:func:`block_sequence`, ``cfg.row_chunk`` rows at a time where the
+    update asks for it (``remat``): rows do not see each other, so a chunk's
+    intermediates are all that is alive at once, and the backward pass, which
+    keeps only each chunk's input, makes them again."""
+    B, rc = x.shape[0], cfg.row_chunk
+    if not remat:
+        x, kv, counts = block_sequence(p, cfg, x, positions, valid, ctx)
+        return x, kv, counters_of(counts)
+    cache, of_rows = (None, None) if ctx is None else (ctx[:2], ctx[2:])  # the cache is whole in every chunk; its rows and lengths go with theirs
+
+    def fn(args):
+        x, positions, valid, of_rows = args
+        return block_sequence(p, cfg, x, positions, valid, None if cache is None else (*cache, *of_rows))
+
+    fn = jax.checkpoint(fn)
+    if B <= rc or B % rc:
+        x, kv, counts = fn((x, positions, valid, of_rows))
+        return x, kv, counters_of(counts)
+    split = lambda a: a.reshape(B // rc, rc, *a.shape[1:])  # noqa: E731
+    x, kv, counts = lax.map(fn, jax.tree.map(split, (x, positions, valid, of_rows)))
+    join = lambda a: a.reshape(B, *a.shape[2:])  # noqa: E731
+    return join(x), jax.tree.map(join, kv), counters_of(counts.sum(0))
+
+
+def embed(params: Params, tokens: Array, dtype: Any) -> Array:
+    with jax.named_scope("seqpol/embed"):
+        return params["embed"]["embedding"].astype(dtype)[tokens]
+
+
+def forward_sequence(params: Params, cfg: SeqPolConfig, tokens: Array, positions: Array, valid: Array,
+                     ctx: Optional[Tuple[Array, Array, Array, Array]] = None, *, dtype: Any = jnp.float32,
+                     remat: bool = False) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
+    """The trunk over ``tokens [B, S]``: the hidden state before the final
+    norm ``[B, S, D]``, the rows' own cache entries ``(c_kv [L, B, S, c],
+    k_rope [L, B, S, r])`` and the expert layers' counters summed. ``ctx =
+    (c_kv, k_rope, row [B], length [B])`` is a cache the rows continue from
+    (:func:`mla_sequence`), indexed by layer: ``c_kv[i] [E, C, c]``, ``k_rope[i]
+    [E, C, r]``. ``remat`` keeps only each
+    chunk of a block's input for the backward pass."""
+    x = embed(params, tokens, dtype)
+    kvs, counters = [], jnp.zeros((3,), jnp.float32)
+    for i in range(cfg.num_hidden_layers):
+        layer_ctx = None if ctx is None else (ctx[0][i], ctx[1][i], ctx[2], ctx[3])
+        x, kv, n = block_by_rows(params["layers"][str(i)], cfg, x, positions, valid, layer_ctx, remat)
+        kvs.append(kv)
+        counters = merge_counters(counters, n)
+    return x, (jnp.stack([c for c, _ in kvs]), jnp.stack([r for _, r in kvs])), counters
+
+
+def decode_step(params: Params, cfg: SeqPolConfig, tokens: Array, positions: Array, cache_c: Sequence[Array], cache_r: Sequence[Array],
+                *, dtype: Any = jnp.float32) -> Tuple[Array, Tuple[Array, ...], Tuple[Array, ...], Array]:  # fmt: skip
+    """One token for each of ``E`` rows: ``tokens [E]`` at ``positions [E]``
+    through the cache, one array a layer (``cache_c[i] [E, C, c]``,
+    ``cache_r[i] [E, C, r]``: a layer's entries are then written in place and
+    read where they lie, and no layer is sliced out of a stack). Returns the
+    hidden state before the final norm ``[E, D]``, the caches with the rows'
+    entries written, and the expert layers' counters."""
+    x = embed(params, tokens, dtype)
+    counters = jnp.zeros((3,), jnp.float32)
+    new_c, new_r = [], []
+    for i in range(cfg.num_hidden_layers):
+        p = params["layers"][str(i)]
+        with jax.named_scope("seqpol/attn"):
+            a, c, r = mla_decode(p["attn"], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, cache_c[i], cache_r[i])
+        new_c.append(c)
+        new_r.append(r)
+        x = x + a
+        f, n = _ffn(p, cfg, rms_norm(x, p["ffn_norm"]["scale"], cfg.rms_norm_eps), None)
+        x = x + f
+        counters = merge_counters(counters, counters_of(n))
+    return x, tuple(new_c), tuple(new_r), counters
+
+
+def heads(params: Params, cfg: SeqPolConfig, h: Array) -> Tuple[Array, Array]:
+    """Final norm, then the logits over the held rows (float32) and the value."""
+    with jax.named_scope("seqpol/head"):
+        z = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        logits = _mm(z, params["head"]["kernel"]).astype(jnp.float32)
+        value = jnp.dot(z.astype(jnp.float32), params["value_head"]["kernel"])[..., 0]
+    return logits, value
+
+
+def mtp_hidden(params: Params, cfg: SeqPolConfig, h: Array, next_tokens: Array, positions: Array, valid: Array,
+               *, remat: bool = False) -> Tuple[Array, Array]:  # fmt: skip
+    """The multi-token-prediction module's state ``[B, S, D]`` (before its
+    final norm; the shared head turns it into logits for the token after
+    ``next_tokens``): ``W_eh [RMSNorm(h) ; RMSNorm(Emb(next_tokens))]`` through
+    one expert block, which attends over the row's own slots."""
+    with jax.named_scope("seqpol/mtp"):
+        m = params["mtp"]
+        e = embed(params, next_tokens, h.dtype)
+        joined = jnp.concatenate([rms_norm(h, m["hnorm"]["scale"], cfg.rms_norm_eps), rms_norm(e, m["enorm"]["scale"], cfg.rms_norm_eps)], axis=-1)
+        x, _, counters = block_by_rows(m["block"], cfg, _mm(joined, m["eh_proj"]["kernel"]), positions, valid, None, remat)
+    return x, counters
+
+
+def token_stats(params: Params, cfg: SeqPolConfig, h: Array, norm_scale: Array, targets: Array, block: int = 2048) -> Tuple[Array, Array]:
+    """``(log-probability of targets, entropy)`` of the head's distribution at
+    every row of ``h [N, D]``, in float32, a ``block`` of rows at a time so
+    that the ``[rows, vocabulary]`` logits never exist whole (the backward pass
+    makes each block's again)."""
+
+    @jax.checkpoint
+    def one(hb, tb):
+        z = rms_norm(hb, norm_scale, cfg.rms_norm_eps)
+        logp = jax.nn.log_softmax(_mm(z, params["head"]["kernel"]).astype(jnp.float32), axis=-1)
+        return jnp.take_along_axis(logp, tb[:, None], axis=-1)[:, 0], -(jnp.exp(logp) * logp).sum(-1)
+
+    N = h.shape[0]
+    with jax.named_scope("seqpol/head"):
+        if N <= block or N % block:
+            return one(h, targets)
+        logp, ent = lax.map(lambda t: one(*t), (h.reshape(N // block, block, -1), targets.reshape(N // block, block)))
+    return logp.reshape(N), ent.reshape(N)
+
+
+def held_shares(cfg: SeqPolConfig, size: int) -> List[Tuple[int, ...]]:
+    """The expert ids of an uncut layer in shares of ``size``: what each of
+    ``n_routed_experts / size`` chips would be told it holds."""
+    return [tuple(range(i, i + size)) for i in range(0, cfg.n_routed_experts, size)]

@@ -56,6 +56,7 @@ from sheeprl_tpu.obs.telemetry import (
     telemetry_slab,
     telemetry_slab_lag,
     telemetry_torn_slabs,
+    telemetry_counters,
     telemetry_train_window,
     telemetry_worker_restart,
 )
@@ -119,6 +120,7 @@ __all__ = [
     "telemetry_slab",
     "telemetry_slab_lag",
     "telemetry_torn_slabs",
+    "telemetry_counters",
     "telemetry_train_window",
     "telemetry_worker_restart",
     "trace_event",

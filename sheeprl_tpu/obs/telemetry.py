@@ -1229,6 +1229,15 @@ def telemetry_train_window(dispatches: int, gradient_steps: int) -> None:
         tel.record_train_window(dispatches, gradient_steps)
 
 
+def telemetry_counters(name: str, **fields: Any) -> None:
+    """One ``counters`` event of ``name`` with ``fields`` (numbers a loop
+    counts per update, such as routed pairs or padded positions) and the
+    host's monotonic time; no-op when telemetry is off."""
+    tel = _active_telemetry
+    if tel is not None:
+        tel.emit("counters", name=name, t_mono_ns=time.monotonic_ns(), **fields)
+
+
 def telemetry_env_step(dur_s: float, queue_wait_s: Optional[float] = None) -> None:
     """Record one pooled env step's latency (see
     :meth:`RunTelemetry.record_env_step`); no-op when telemetry is off."""

@@ -92,6 +92,12 @@ class Precision:
 # "replicate" (force P()), or an explicit PartitionSpec. First match wins;
 # unmatched leaves fall back to replicated with a warn-once per path.
 DEFAULT_PARTITION_RULES: Tuple[Tuple[str, Any], ...] = (
+    # stacked expert kernels ``[experts held, in, out]`` (models/seqpol.py):
+    # by the shape rule, over their last two dims like any kernel. The leading
+    # axis is the experts THIS chip holds and is never sharded: no expert mesh
+    # axis is built, and a layer shared by several chips runs as one chip's
+    # share (ROADMAP B9 d)
+    (r"(^|/)experts/(gate|up|down)/kernel$", "auto"),
     # dense/conv kernels and embeddings (+ their mu/nu/EMA twins): shape rule
     (r"(^|/)(kernel|embedding)$", "auto"),
     # LayerNorm affine, biases, the learnable h0: small — keep replicated

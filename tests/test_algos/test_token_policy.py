@@ -1,0 +1,388 @@
+"""The decoder core of recurrent PPO (a policy over tokens) against the plain
+reference, tiny on the CPU in float32: the same ratios as the model it was
+built for (1 dense + 2 expert layers, 8 routed experts of which 4 a token, 1
+shared, every latent and head dim distinct, the multi-token-prediction module
+on). Every tolerance is float32 round-off (readings are 1e-6 or under) with
+room for the order of sums; the same numbers computed with bfloat16 operands
+read 1e-2 and fail each of them, which ``test_bfloat16_fails_the_tolerances``
+holds.
+"""
+
+import os
+import signal
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench.references import token_ppo as reference
+from sheeprl_tpu.algos.ppo_recurrent import token_policy
+from sheeprl_tpu.cli import run
+from sheeprl_tpu.models import seqpol
+
+SIZES = dict(hidden_size=32, num_attention_heads=2, q_lora_rank=12, kv_lora_rank=10, qk_nope_head_dim=6, qk_rope_head_dim=4, v_head_dim=8,
+             intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8, held_experts=[0, 1, 2, 3], num_experts_per_tok=4,
+             n_shared_experts=1, routed_scaling_factor=1.8, norm_topk_prob=True, first_k_dense_replace=1, num_hidden_layers=3,
+             num_nextn_predict_layers=1, vocab_rows=24, context=32, rope_theta=1e6, rms_norm_eps=1e-5)  # fmt: skip
+#: float32 round-off at these widths reads 1e-6; a bfloat16 operand anywhere reads 1e-2
+TOL = 2e-5
+VOCAB, LAYERS, CONTEXT = SIZES["vocab_rows"], SIZES["num_hidden_layers"], SIZES["context"]
+
+
+def core(**changes):
+    sizes = {**SIZES, **changes}
+    return seqpol.SeqPolConfig(**{**sizes, "held_experts": tuple(sizes["held_experts"])})
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return reference.init_weights({"model": SIZES}, 3)
+
+
+def gap(ours, theirs):
+    return float(jnp.linalg.norm(jnp.asarray(ours) - jnp.asarray(theirs)) / (jnp.linalg.norm(jnp.asarray(theirs)) + 1e-30))
+
+
+def whole(weights, tokens, dtype=jnp.float32, cfg=None):
+    cfg = cfg or core()
+    tokens = np.atleast_2d(tokens)
+    positions = np.broadcast_to(np.arange(tokens.shape[1]), tokens.shape)
+
+    @jax.jit
+    def run_whole(w):
+        h, kv, counters = seqpol.forward_sequence(w, cfg, tokens, positions, np.ones(tokens.shape, bool), dtype=dtype)
+        return (*seqpol.heads(w, cfg, h), kv, counters)
+
+    return run_whole(weights)
+
+
+@jax.jit
+def _reference_forward(weights, tokens):
+    return reference.forward(weights, SIZES, tokens)
+
+
+def reference_forward(weights, tokens, size=16):
+    """The reference's full forward on ``tokens``, padded to one length (causal: the padding changes nothing before it)."""
+    padded = np.zeros((size,), np.int32)
+    padded[: len(tokens)] = tokens
+    logits, values = _reference_forward(weights, padded)
+    return logits[: len(tokens)], values[: len(tokens)]
+
+
+def test_the_programs_own_weights_have_the_references_tree(weights):
+    ours = seqpol.init_params(jax.random.PRNGKey(0), core())
+    assert jax.tree.structure(ours) == jax.tree.structure(weights)
+    assert [a.shape for a in jax.tree.leaves(ours)] == [b.shape for b in jax.tree.leaves(weights)]
+
+
+def test_every_leaf_resolves_under_the_partition_rules(weights):
+    """(fabric) kernel, embedding, scale and bias, stacked expert kernels among them: no unmatched-leaf warning."""
+    import warnings
+
+    from sheeprl_tpu.parallel.fabric import Fabric, reset_partition_rule_warnings
+
+    reset_partition_rule_warnings()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        specs = Fabric(devices=1, accelerator="cpu").match_partition_rules(weights)
+    assert len(jax.tree.leaves(specs, is_leaf=lambda s: hasattr(s, "index"))) == len(jax.tree.leaves(weights))
+
+
+# (a) the whole-sequence form against the reference's full forward
+@pytest.mark.parametrize("grouped", [False, True], ids=["experts_dense", "experts_grouped"])
+def test_whole_sequence_equals_the_reference(weights, grouped):
+    tokens = np.random.default_rng(0).integers(0, VOCAB, (2, 12)).astype(np.int32)
+    logits, values, _, counters = whole(weights, tokens, cfg=core(dense_pairs_max=0 if grouped else 10**6))
+    for b in range(2):
+        ref_logits, ref_values = reference_forward(weights, tokens[b])
+        assert gap(logits[b], ref_logits) < TOL and gap(values[b], ref_values) < TOL
+    assert counters[0] == 2 * 12 * 4 * 2 and 0 < counters[1] < counters[0]  # pairs routed in 2 expert layers, and those on held experts
+
+
+# (b) prefill, then decoding through the cache, a row reset in the middle, against the reference's full forward
+def test_prefill_then_decode_across_a_reset_equals_the_reference(weights):
+    rng = np.random.default_rng(1)
+    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
+    player = token_policy.TokenPlayer(agent, weights, num_envs=3, prefill_rows=2)
+    key = jax.random.PRNGKey(0)
+    inputs = [[], [], []]  # every token each row's policy has been fed, episode by episode
+    obs_tokens, n_tokens = np.zeros((3, 6), np.int32), np.zeros((3,), np.int32)
+
+    def reset(row):
+        n = int(rng.integers(1, 7))
+        obs_tokens[row, :n], n_tokens[row] = rng.integers(0, VOCAB, n), n
+        inputs[row] = [int(t) for t in obs_tokens[row, :n]]
+
+    for row in range(3):
+        reset(row)
+    for step in range(9):
+        actions, _, values, positions = player.act(obs_tokens, n_tokens, key, step)
+        logits = np.asarray(player.last_logits)
+        for row in range(3):
+            assert positions[row] == len(inputs[row]) - 1
+            ref_logits, ref_values = reference_forward(weights, inputs[row])
+            assert gap(logits[row], ref_logits[-1]) < TOL, (step, row)
+            assert abs(float(values[row]) - float(ref_values[-1])) < TOL * max(1.0, abs(float(ref_values[-1])))
+        dones = np.zeros((3,), bool)
+        dones[1] = step == 3  # row 1 ends in the middle: its next prompt is prefilled into the slot it leaves
+        player.reset_rows(dones)
+        for row in range(3):
+            if dones[row]:
+                reset(row)
+            else:
+                obs_tokens[row, 0], n_tokens[row] = int(actions[row]), 1
+                inputs[row].append(int(actions[row]))
+    assert player.rows_prefilled >= 1 and player.tokens_decoded == 27
+
+
+# (c) the shares add up: an uncut layer of 8 experts in shares of 2
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(weights):
+    everything = {**SIZES, "held_experts": list(range(8))}
+    uncut = reference.init_weights({"model": everything}, 5)["layers"]["1"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (20, SIZES["hidden_size"]))
+    want = reference.expert_layer(uncut, everything, x)
+    shared = seqpol.swiglu(uncut["shared"], x)
+    routed = jnp.zeros_like(x)
+    for held in seqpol.held_shares(core(), 2):
+        share = {**uncut, "experts": {k: {"kernel": uncut["experts"][k]["kernel"][np.asarray(held)]} for k in ("gate", "up", "down")}}
+        y, counters = seqpol.moe(share, core(held_experts=held), x)
+        routed = routed + (y - shared)  # what this share's own experts gave: the shared expert is counted once, below
+    assert gap(shared + routed, want) < TOL
+    assert gap(seqpol.moe(uncut, core(held_experts=tuple(range(8))), x)[0], want) < TOL
+
+
+# (f) routing under a planted imbalance drops nothing and computes nothing wrong
+@pytest.mark.parametrize("planted", ["all_to_one_held_expert", "none_to_any_held_expert"])
+@pytest.mark.parametrize("grouped", [False, True], ids=["experts_dense", "experts_grouped"])
+def test_routing_under_a_planted_imbalance(weights, planted, grouped):
+    layer = jax.tree.map(lambda a: a, weights["layers"]["1"]["moe"])
+    bias = np.zeros((8,), np.float32)
+    # the correction bias decides the choice alone: expert 2 (held) and three absent ones, or the four absent ones
+    bias[[2, 5, 6, 7] if planted == "all_to_one_held_expert" else [4, 5, 6, 7]] = 10.0
+    layer["router"] = {**layer["router"], "bias": jnp.asarray(bias)}
+    x = jax.random.normal(jax.random.PRNGKey(3), (40, SIZES["hidden_size"]))
+    y, counters = seqpol.moe(layer, core(dense_pairs_max=0 if grouped else 10**6), x)
+    assert gap(y, reference.expert_layer(layer, SIZES, x)) < TOL
+    held_pairs = 40 if planted == "all_to_one_held_expert" else 0
+    assert seqpol.counters_of(counters).tolist() == [160.0, held_pairs, held_pairs]  # every pair on the held expert is counted, none dropped
+
+
+def _ragged_dot_as_on_the_chip():
+    """On the chip ``lax.ragged_dot`` leaves the rows that fall in no group (pairs
+    on absent experts) as the memory held them, forward and backward: NaN here."""
+    from jax import lax
+
+    real = lax.ragged_dot
+
+    @jax.custom_vjp
+    def as_on_the_chip(rows, kernel, sizes):
+        return jnp.where((jnp.arange(rows.shape[0]) < sizes.sum())[:, None], real(rows, kernel, sizes), jnp.nan)
+
+    def fwd(rows, kernel, sizes):
+        return as_on_the_chip(rows, kernel, sizes), (rows, kernel, sizes)
+
+    def bwd(kept, ct):
+        rows, kernel, sizes = kept
+        d_rows, d_kernel = jax.vjp(lambda r, k: real(r, k, sizes), rows, kernel)[1](ct)
+        return jnp.where((jnp.arange(rows.shape[0]) < sizes.sum())[:, None], d_rows, jnp.nan), d_kernel, None
+
+    as_on_the_chip.defvjp(fwd, bwd)
+    return as_on_the_chip
+
+
+# the grouped products run over the first rows of the sorted buffer when the held pairs fit in them (40 of 160
+# here: 0.5 times the even share), over all of it when they do not, and over all of it always at a factor that
+# covers every pair; with NaN in the rows of no group the layer's output and gradient stay what they are
+@pytest.mark.parametrize("factor, planted, held_pairs", [(2.0, False, None), (0.5, True, 40), (0.5, False, None)],
+                         ids=["whole_buffer", "first_rows_hold_every_held_pair", "held_pairs_do_not_fit"])  # fmt: skip
+def test_rows_in_no_group_may_hold_anything(weights, monkeypatch, factor, planted, held_pairs):
+    layer = dict(weights["layers"]["1"]["moe"])
+    if planted:  # the correction bias decides the choice alone: expert 2 (held) and three absent ones
+        layer["router"] = {**layer["router"], "bias": jnp.zeros((8,)).at[jnp.asarray([2, 5, 6, 7])].set(10.0)}
+    cfg = core(dense_pairs_max=0)
+    monkeypatch.setattr(seqpol, "GROUPED_ROWS_FACTOR", factor)
+    assert seqpol.grouped_rows(cfg, 160) == (160 if factor == 2.0 else 40)
+    x = jax.random.normal(jax.random.PRNGKey(5), (40, SIZES["hidden_size"]))
+    counters = seqpol.counters_of(seqpol.moe(layer, cfg, x)[1])
+    assert float(counters[1]) == held_pairs if planted else float(counters[1]) > 40  # which side of the 40 rows the pairs fall
+    loss = lambda p, x, cfg: jnp.sum(jnp.square(seqpol.moe(p, cfg, x)[0]))  # noqa: E731
+    want = jax.grad(loss, argnums=(0, 1))(layer, x, core(dense_pairs_max=10**6))
+    assert gap(seqpol.moe(layer, cfg, x)[0], reference.expert_layer(layer, SIZES, x)) < TOL
+    monkeypatch.setattr(seqpol.lax, "ragged_dot", _ragged_dot_as_on_the_chip())
+    assert gap(seqpol.moe(layer, cfg, x)[0], reference.expert_layer(layer, SIZES, x)) < TOL
+    got = jax.grad(loss, argnums=(0, 1))(layer, x, cfg)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(a).all()) and gap(a, b) < TOL
+
+
+def _batch(weights, rng, agent, continuing):
+    """One minibatch: an episode that begins in the rollout, one that
+    continues from a snapshot (or begins too), and a padding sequence; with the
+    reference's aligned form of the same."""
+    P, L = agent.prompt_max, 8
+    pad = lambda a, n, dtype=np.float32: np.concatenate([np.asarray(a, dtype), np.zeros((n - len(a),), dtype)])  # noqa: E731
+    noise = lambda n: rng.normal(size=n).astype(np.float32)  # noqa: E731
+    prompt_a, acts_a = rng.integers(0, VOCAB, 4), rng.integers(0, VOCAB, 5)
+    prompt_b, acts_b = rng.integers(0, VOCAB, 3), rng.integers(0, VOCAB, 7)
+    inputs_b = np.concatenate([prompt_b, acts_b[:-1]])
+    before = 5 if continuing else 0  # episode b's inputs that lie before the rollout: in the snapshot, not in the sequence
+    snap_c = jnp.zeros((LAYERS, 2, CONTEXT, SIZES["kv_lora_rank"]))
+    snap_r = jnp.zeros((LAYERS, 2, CONTEXT, SIZES["qk_rope_head_dim"]))
+    if continuing:
+        _, _, (c, r), _ = whole(weights, inputs_b[:before])
+        snap_c, snap_r = snap_c.at[:, 1, :before].set(c[:, 0]), snap_r.at[:, 1, :before].set(r[:, 0])
+    seq_a = dict(actions=acts_a, logprobs=-3 + 0.1 * noise(5), advantages=noise(5), returns=noise(5), values=noise(5))
+    n_b = 4 if continuing else 7
+    seq_b = dict(actions=acts_b[-n_b:], logprobs=-3 + 0.1 * noise(n_b), advantages=noise(n_b), returns=noise(n_b), values=noise(n_b))
+    first_b = len(inputs_b) - n_b
+    batch = {
+        "prompt": np.stack([pad(prompt_a, P, np.int32), pad(inputs_b[before : first_b + 1], P, np.int32), np.zeros(P, np.int32)]),
+        "n0": np.asarray([4, first_b + 1 - before, 1], np.int32),
+        "tok_in": np.stack([pad(np.concatenate([prompt_a[-1:], acts_a[:-1]]), L, np.int32), pad(inputs_b[first_b:], L, np.int32), np.zeros(L, np.int32)]),
+        "len0": np.asarray([0, before, 0], np.int32),
+        "env0": np.asarray([0, 1, 0], np.int32),
+        "mask": np.stack([pad(np.ones(5), L), pad(np.ones(n_b), L), np.zeros(L, np.float32)]),
+    }
+    for k in seq_a:
+        dtype = np.int32 if k == "actions" else np.float32
+        batch[k] = np.stack([pad(seq_a[k], L, dtype), pad(seq_b[k], L, dtype), np.zeros(L, dtype)])
+
+    def aligned(tokens, first, seq, size=12):
+        out = {"tokens": pad(tokens, size, np.int32), "steps": np.zeros(size, np.float32)}
+        n = len(seq["actions"])
+        out["steps"][first : first + n] = 1
+        for k, v in seq.items():
+            out[k] = np.zeros(size, np.int32 if k == "actions" else np.float32)
+            out[k][first : first + n] = v
+        return out
+
+    return batch, snap_c, snap_r, [aligned(np.concatenate([prompt_a, acts_a[:-1]]), 3, seq_a), aligned(inputs_b, first_b, seq_b)]
+
+
+CONSTS = dict(clip_coef=0.2, vf_coef=0.2, ent_coef=0.001, lr=3e-4, eps=1e-4, weight_decay=0.01, max_grad_norm=0.5)
+LOSS_NAMES = ("policy_loss", "value_loss", "entropy_loss", "mtp_loss")
+
+
+def _program_loss(weights, agent, batch, snap_c, snap_r, mtp_coef):
+    def loss(p):
+        return token_policy.token_loss(p, agent, batch, snap_c, snap_r, 0.2, 0.001, vf_coef=0.2, mtp_coef=mtp_coef)
+
+    (_, metrics), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(weights)
+    return dict(zip(token_policy.METRICS, np.asarray(metrics))), grads
+
+
+# (d) a sequence that starts from a snapshot equals the same episode evaluated whole
+def test_a_sequence_from_a_snapshot_equals_the_episode_evaluated_whole(weights):
+    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
+    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=True)
+    ours, _ = _program_loss(weights, agent, batch, snap_c, snap_r, 0.1)
+    theirs = reference.losses_only(weights, SIZES, {**CONSTS, "mtp_loss_coef": 0.1}, aligned)
+    for name in LOSS_NAMES:
+        assert abs(ours[name] - theirs[name]) < TOL * max(1.0, abs(theirs[name])), name
+    assert ours["real_positions"] == 3 + 5 + 4 and ours["padded_positions"] == 3 * (6 + 8)
+
+
+# (e) one update equals the reference's: losses, the gradient by leaf, the weights after; MTP term on and off
+@pytest.mark.parametrize("mtp_coef", [0.1, 0.0], ids=["mtp_on", "mtp_off"])
+def test_one_update_equals_the_reference(weights, mtp_coef):
+    import optax
+
+    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
+    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=False)
+    a = {**CONSTS, "mtp_loss_coef": mtp_coef}
+    ours, grads = _program_loss(weights, agent, batch, snap_c, snap_r, mtp_coef)
+    theirs, ref_grads = reference.loss_and_grad(weights, SIZES, a, aligned)
+    for name in LOSS_NAMES:
+        assert abs(ours[name] - theirs[name]) < TOL * max(1.0, abs(theirs[name])), name
+    gaps = jax.tree.map(gap, grads, ref_grads)
+    assert max(jax.tree.leaves(gaps)) < 10 * TOL, gaps  # a leaf's gradient sums over every position: ten round-offs of room
+    moved = jax.tree.map(lambda g: float(jnp.abs(g).max()) > 0, ref_grads["mtp"])
+    assert any(jax.tree.leaves(moved)) == (mtp_coef > 0)  # with its coefficient at 0 the module gets no gradient
+    # the weights after: the main's optimizer (global-norm clip, then AdamW) against the reference's first step
+    from sheeprl_tpu.ops.optim import adam
+
+    tx = adam(lr=a["lr"], eps=a["eps"], weight_decay=a["weight_decay"], max_grad_norm=a["max_grad_norm"])
+    updates, _ = tx.update(grads, tx.init(weights), weights)
+    after = optax.apply_updates(weights, updates)
+    ref_after = reference.adamw_first_step(weights, reference.clip_by_global_norm(ref_grads, a["max_grad_norm"]), a)
+    change = jax.tree.map(lambda x, y, w: gap(x - w, y - w), after, ref_after, weights)
+    assert max(jax.tree.leaves(change)) < 1e-3, change  # g / (|g| + eps) at eps 1e-4 magnifies the gradient's round-off
+
+
+def test_bfloat16_fails_the_tolerances(weights):
+    """What (a), (b) and (e) hold in float32 does not survive bfloat16 operands."""
+    tokens = np.random.default_rng(0).integers(0, VOCAB, (1, 12)).astype(np.int32)
+    logits, values, _, _ = whole(weights, tokens, dtype=jnp.bfloat16)
+    ref_logits, _ = reference_forward(weights, tokens[0])
+    assert gap(logits[0].astype(jnp.float32), ref_logits) > 50 * TOL
+    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.bfloat16)
+    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=False)
+    _, grads = _program_loss(weights, agent, batch, snap_c.astype(jnp.bfloat16), snap_r.astype(jnp.bfloat16), 0.1)
+    _, ref_grads = reference.loss_and_grad(weights, SIZES, {**CONSTS, "mtp_loss_coef": 0.1}, aligned)
+    assert max(jax.tree.leaves(jax.tree.map(gap, grads, ref_grads))) > 50 * TOL
+
+
+def test_build_sequences_emits_the_cores_carry():
+    from sheeprl_tpu.algos.ppo_recurrent.ppo_recurrent import build_sequences
+
+    T, E = 6, 2
+    data = {"x": np.arange(T * E, dtype=np.float32).reshape(T, E, 1), "dones": np.zeros((T, E, 1), np.float32),
+            "prev_len": np.arange(T * E, dtype=np.int32).reshape(T, E, 1) + 100, "prev_env": np.tile(np.arange(E, dtype=np.int32)[None, :, None], (T, 1, 1))}  # fmt: skip
+    data["dones"][2, 0] = 1
+    out = build_sequences(data, ["x"], 8, E, 4, carry_keys=("prev_len", "prev_env"))
+    assert out["mask"].shape == (8, 4, 1) and out["mask"].sum(0)[:, 0].tolist() == [3, 3, 6, 0]
+    assert out["len0"][:, 0].tolist() == [100, 106, 101, 0] and out["env0"][:, 0].tolist() == [0, 0, 1, 0]
+    assert out["len0"].dtype == np.int32
+
+
+# (g) the recipe through cli.run at the tiny size: it learns to copy, and leaves with 77 on SIGTERM
+def tiny_args(tmp_path):
+    return ["exp=ppo_recurrent_glm47_flash", "fabric=cpu", "fabric.precision=fp32", "fabric.devices=1", "env.num_envs=8", "algo.rollout_steps=16",
+            "algo.per_rank_sequence_length=20", "algo.per_rank_batch_size=32", *[f"algo.core.{k}={v}" for k, v in {
+                **{k: v for k, v in SIZES.items() if k not in ("held_experts", "vocab_rows", "context", "rope_theta", "rms_norm_eps")},
+                "held_experts": "[0,1,2,3]", "vocab_rows": 8, "context": 16, "prompt_max": 4, "prefill_rows": 2}.items()],
+            "env.wrapper.prompt_min=1", "env.wrapper.prompt_max=2", "algo.optimizer.lr=3e-3", "metric.log_level=1", "algo.run_test=False",
+            "checkpoint.save_last=False", "checkpoint.every=0", f"log_base_dir={tmp_path}/logs"]  # fmt: skip
+
+
+def _sigterm_at_boundary(monkeypatch, nth):
+    """A real SIGTERM to this process at the ``nth`` poll of the train loop's boundary."""
+    import sheeprl_tpu.resilience.manager as manager
+
+    real, count = manager.RunResilience.preempt_requested, [0]
+
+    def polled(self):
+        count[0] += 1
+        if count[0] == nth:
+            os.kill(os.getpid(), signal.SIGTERM)
+        return real(self)
+
+    monkeypatch.setattr(manager.RunResilience, "preempt_requested", polled)
+
+
+def test_the_recipe_learns_to_copy_and_leaves_with_77_on_sigterm(tmp_path, monkeypatch, capsys):
+    from sheeprl_tpu.resilience import PREEMPTED_EXIT_CODE
+
+    monkeypatch.chdir(tmp_path)
+    _sigterm_at_boundary(monkeypatch, 26)  # after 25 updates of 128 policy steps
+    with pytest.raises(SystemExit) as left:
+        run([*tiny_args(tmp_path), "algo.total_steps=1000000"])
+    assert left.value.code == PREEMPTED_EXIT_CODE
+    out = capsys.readouterr().out
+    rewards = [float(line.rsplit("=", 1)[1]) for line in out.splitlines() if "reward_env_" in line]
+    fifth = len(rewards) // 5
+    assert fifth > 50 and np.mean(rewards[-fifth:]) > np.mean(rewards[:fifth]) + 0.1, (np.mean(rewards[:fifth]), np.mean(rewards[-fifth:]))
+
+
+def test_the_lstm_recipe_leaves_with_77_on_sigterm(tmp_path, monkeypatch):
+    from sheeprl_tpu.resilience import PREEMPTED_EXIT_CODE
+    from tests.test_algos.test_ppo_recurrent import find_checkpoints, rppo_args
+
+    monkeypatch.chdir(tmp_path)
+    _sigterm_at_boundary(monkeypatch, 2)
+    args = [a for a in rppo_args(tmp_path) if a != "dry_run=True"] + ["algo.total_steps=64", "algo.run_test=False"]
+    with pytest.raises(SystemExit) as left:
+        run(args)
+    assert left.value.code == PREEMPTED_EXIT_CODE
+    assert find_checkpoints(tmp_path)  # the emergency checkpoint of the update that had finished
