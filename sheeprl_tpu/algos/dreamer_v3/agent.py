@@ -35,6 +35,7 @@ from sheeprl_tpu.ops.distributions import (
     OneHotCategoricalStraightThrough,
     TanhNormal,
 )
+from sheeprl_tpu.ops.hoisted_scan import hoist_plan, hoisted_scan
 from sheeprl_tpu.ops.math import symlog
 from sheeprl_tpu.ops.pallas_gru import fused_recurrent_step, resolve_backend
 from sheeprl_tpu.parallel.fabric import HostPlayerParams, put_tree
@@ -515,6 +516,25 @@ class WorldModel(nn.Module):
         return z, h
 
 
+def _rssm_step(wm: WorldModel):
+    """One step of :func:`rssm_scan` in :func:`hoisted_scan`'s form."""
+
+    def step(params, carry, emb_t, frozen):
+        h, z, key = carry
+        act_t, first_t = frozen
+        key, sub = jax.random.split(key)
+        h, z, post_logits, prior_logits = wm.apply(params, z, h, act_t, emb_t, first_t, sub, method=WorldModel.dynamic)
+        return (h, z, key), (h, z, post_logits, prior_logits)
+
+    return step
+
+
+def _rssm_init(wm: WorldModel, batch: int, key: Array) -> Tuple[Array, Array, Array]:
+    h = jnp.zeros((batch, wm.recurrent_state_size), jnp.float32)
+    z = jnp.zeros((batch, wm.stoch_state_size), jnp.float32)
+    return h, z, key
+
+
 def rssm_scan(
     wm: WorldModel,
     params: Any,
@@ -525,22 +545,24 @@ def rssm_scan(
 ) -> Tuple[Array, Array, Array, Array]:
     """The RSSM sequence as one ``lax.scan`` (replaces the reference's Python
     loop, dreamer_v3.py:134-145). Returns time-major
-    ``(recurrent_states, posteriors, posterior_logits, prior_logits)``."""
-    T, B = embedded.shape[0], embedded.shape[1]
-    h = jnp.zeros((B, wm.recurrent_state_size), jnp.float32)
-    z = jnp.zeros((B, wm.stoch_state_size), jnp.float32)
+    ``(recurrent_states, posteriors, posterior_logits, prior_logits)``.
 
-    def step(carry, xs):
-        h, z, key = carry
-        emb_t, act_t, first_t = xs
-        key, sub = jax.random.split(key)
-        h, z, post_logits, prior_logits = wm.apply(params, z, h, act_t, emb_t, first_t, sub, method=WorldModel.dynamic)
-        return (h, z, key), (h, z, post_logits, prior_logits)
-
-    (_, _, _), (hs, zs, post_logits, prior_logits) = jax.lax.scan(
-        step, (h, z, key), (embedded, actions, is_first)
-    )
+    Gradients flow to ``params`` and ``embedded``. The backward loop hands out
+    each dense layer's pre-activation gradient per step and every such
+    kernel's gradient is one contraction over ``T x B`` after it
+    (:mod:`sheeprl_tpu.ops.hoisted_scan`); the Pallas recurrent step's two
+    kernels keep their per-step accumulation."""
+    init = _rssm_init(wm, embedded.shape[1], key)
+    _, (hs, zs, post_logits, prior_logits) = hoisted_scan(_rssm_step(wm), params, init, embedded, (actions, is_first))
     return hs, zs, post_logits, prior_logits
+
+
+def rssm_scan_kernels(wm: WorldModel, params: Any, embedded: Array, actions: Array, is_first: Array, key: Array) -> Dict[str, int]:
+    """What :func:`rssm_scan`'s backward does with the step's kernels on these
+    arguments, from their shapes alone: the fields of the ``dv3/rssm_scan``
+    counters event (howto/telemetry.md)."""
+    init = _rssm_init(wm, embedded.shape[1], key)
+    return hoist_plan(_rssm_step(wm), params, init, embedded, (actions, is_first)).counters(params)
 
 
 class Actor(nn.Module):

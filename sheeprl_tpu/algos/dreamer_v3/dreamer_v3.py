@@ -39,6 +39,7 @@ from sheeprl_tpu.algos.dreamer_v3.agent import (
     actor_logprob_entropy,
     build_agent,
     rssm_scan,
+    rssm_scan_kernels,
     sample_actor_actions,
 )
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
@@ -71,6 +72,7 @@ from sheeprl_tpu.ops.distributions import (
 from sheeprl_tpu.obs import (
     log_sps_and_heartbeat,
     telemetry_advance,
+    telemetry_counters,
     telemetry_register_flops,
     telemetry_run_metrics,
     telemetry_train_window,
@@ -185,6 +187,8 @@ def make_train_step(
         def world_loss_fn(p):
             with jax.named_scope("dv3/wm/encode"):
                 embedded = wm.apply(p, batch_obs, method=WorldModel.encode)
+            # as the step is traced, so once for each program built on it: whose gradient leaves the scan's backward loop
+            telemetry_counters("dv3/rssm_scan", **rssm_scan_kernels(wm, p, embedded, batch_actions, is_first, k_scan))
             with jax.named_scope("dv3/wm/rssm_scan"):
                 hs, zs, post_logits, prior_logits = rssm_scan(wm, p, embedded, batch_actions, is_first, k_scan)
             with jax.named_scope("dv3/wm/decode"):

@@ -123,3 +123,20 @@ def test_a_scope_marks_its_backward_ops_too(lowered):
     ops carry ``jvp(<scope>)`` and their backward ops ``transpose(jvp(<scope>))``."""
     text = lowered["dv3_train_step"]
     assert "transpose(jvp(dv3/wm/rssm_scan))" in text and "jvp(dv3/wm/rssm_scan)" in text
+
+
+def test_the_rssm_scans_own_backward_stays_under_its_scope(lowered):
+    """``rssm_scan``'s backward is a ``vjp`` of its own inside a ``custom_vjp``
+    (``ops/hoisted_scan.py``): its loop (``transpose(jvp())``) and the kernels'
+    contractions after the loop (``ni,no->io``) are ops of ``dv3/wm/rssm_scan``
+    too, or ``train_step.rssm_scan_device_ms`` would fall without the step falling."""
+    import re
+
+    paths = set(re.findall(r'loc\("([^"]*)"', lowered["dv3_train_step"]))
+    loop = [p for p in paths if "transpose(jvp())/while" in p]  # the step's one loop under a gradient of its own
+    contractions = [p for p in paths if "ni,no->io" in p]
+    assert any("/while/body/" in p for p in loop) and any(p.endswith("/dot_general") for p in contractions)
+    for path in loop + contractions:
+        assert "/transpose(jvp(dv3/wm/rssm_scan))/" in path, path
+    assert any("/jvp(dv3/wm/rssm_scan)/jvp()/while" in p for p in paths), "the probed forward loop"
+    assert all("dv3/" in p for p in paths if "jvp()" in p), "an inner gradient's op outside every scope"
