@@ -18,8 +18,8 @@ attached accelerator:
    so the measured time should stay FLAT until the compute term crosses the
    bytes term (the roofline knee), then grow linearly. The knee batch is the
    per-device batch at which L/XL stop being bandwidth-bound — the number
-   that justifies `mfu_probe.py --batch-size 64/128` and the multi-chip
-   recipe (8-way DP at per-device batch >= knee).
+   that justifies the multi-chip recipe (8-way DP at per-device batch >=
+   knee).
 
 Timing uses a chained-step estimator (N dispatches chained on-device, outputs
 referenced, one materializing fetch, the tiny-op round trip subtracted). The

@@ -19,8 +19,7 @@ Two regimes per size, selected by ``--layouts dxm`` (data×model):
   ``benchmarks/gru_roofline.py`` measures.
 
 Run on the TPU: ``python benchmarks/pallas_gru_ab.py --sizes L,XL
---layouts 1x4,2x4 --batches 64,128,256,304 --dtype bf16`` (the
-``sharded_pallas_ab`` entry in ``benchmarks/QUEUE.json`` does exactly this).
+--layouts 1x4,2x4 --batches 64,128,256,304 --dtype bf16``.
 Off the TPU the compiled kernel cannot run: pass ``--interpret`` for a CPU
 smoke run, which times the interpreter and nothing else.
 """

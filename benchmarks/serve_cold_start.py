@@ -20,8 +20,7 @@ pollutes the number.
 ``--record`` folds one registry line per *cached* run into RUNS.jsonl
 (kind=serve, algo=synthetic_mlp, env=cold_start, variant=cold_start,
 metric ``cold_start_s`` lower-is-better) so ``tools/regress.py`` gates the
-cold boot alongside the throughput cells. ``bench.py --cold-start`` wraps
-this file the way ``--floor`` wraps ppo_floor.py.
+cold boot alongside the throughput cells.
 
 Usage:
   python benchmarks/serve_cold_start.py [--repeats 3] [--depth 384]

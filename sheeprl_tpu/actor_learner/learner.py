@@ -47,6 +47,7 @@ from sheeprl_tpu.obs import (
     telemetry_actor_restart,
     telemetry_advance,
     telemetry_child_file,
+    telemetry_mark_warm,
     telemetry_register_flops,
     telemetry_run_metrics,
     telemetry_slab,
@@ -61,7 +62,7 @@ from sheeprl_tpu.parallel.submesh import probe_spaces
 from sheeprl_tpu.resilience import RunResilience
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator
-from sheeprl_tpu.utils.utils import SteadyStateProbe, polynomial_decay, save_configs
+from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
 
 
 def build_slab_layout(obs_space, cnn_keys, mlp_keys, actions_width: int, rows: int) -> SlabLayout:
@@ -348,7 +349,6 @@ def run_actor_learner(fabric, cfg: Dict[str, Any], state: Optional[Dict[str, Any
         win_train_s = win_wait_s = 0.0
 
     preempted = False
-    probe = SteadyStateProbe()
     try:
         supervisor.spawn_all()
         while update < num_updates:
@@ -427,7 +427,7 @@ def run_actor_learner(fabric, cfg: Dict[str, Any], state: Optional[Dict[str, Any
 
             telemetry_advance(policy_step)
             if update == start_update:
-                probe.mark(policy_step)
+                telemetry_mark_warm()
             t0 = time.perf_counter()
             key, train_key = jax.random.split(key)
             params, opt_state, metrics = train_fn(
@@ -507,7 +507,6 @@ def run_actor_learner(fabric, cfg: Dict[str, Any], state: Optional[Dict[str, Any
         sync_torn()
         transport.close()
 
-    probe.finish(policy_step, sync=lambda: jax.device_get(jax.tree.leaves(params)[0]))
     maybe_heartbeat(final=True)
     if fabric.is_global_zero and cfg.algo.run_test and not preempted:
         player.update_params(params)

@@ -73,6 +73,7 @@ from sheeprl_tpu.obs import (
     log_sps_and_heartbeat,
     telemetry_advance,
     telemetry_counters,
+    telemetry_mark_warm_after_warmup,
     telemetry_register_flops,
     telemetry_run_metrics,
     telemetry_train_window,
@@ -833,13 +834,6 @@ def main(fabric, cfg: Dict[str, Any]):
     from sheeprl_tpu.parallel.fabric import DispatchFence
 
     fence = DispatchFence(depth=int(cfg.algo.get("dispatch_fence_depth", 4) or 4))
-    # steady-state throughput probe (bench.py): measure from shortly after
-    # the gradient path has compiled to the final update, in one process
-    from sheeprl_tpu.utils.utils import SteadyStateProbe
-
-    probe = SteadyStateProbe()
-    bench_batch = None  # one sampled batch kept for the post-run cost analysis
-    bench_superstep = None  # fused path: (fn, chunk, arg shapes) for the same
     last_grad_steps = 0  # heartbeat window: train_fn invocations since last log
     placement_recorded = False
     for update in range(start_step, num_updates + 1):
@@ -856,7 +850,7 @@ def main(fabric, cfg: Dict[str, Any]):
             )
             preempted = True
             break
-        probe.mark_warm(update, learning_starts, policy_step, work=cumulative_per_rank_gradient_steps)
+        telemetry_mark_warm_after_warmup(update, learning_starts)
         policy_step += num_envs * num_processes
 
         with timer("Time/env_interaction_time"):
@@ -995,14 +989,6 @@ def main(fabric, cfg: Dict[str, Any]):
                             telemetry_register_flops(
                                 superstep, params, aux, counter, ctx, key, scale=1.0 / chunk
                             )
-                        if probe.active and bench_superstep is None:
-                            # ShapeDtypeStructs, NOT live refs — aux is about
-                            # to be donated and deleted by the dispatch
-                            shapes = jax.tree.map(
-                                lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
-                                (params, aux, counter, ctx, key),
-                            )
-                            bench_superstep = (superstep, chunk, shapes)
                         with timer("train/dispatch"):
                             if resil.finite_checks:
                                 # the sentinel rides the same dispatch: a [chunk]
@@ -1083,8 +1069,6 @@ def main(fabric, cfg: Dict[str, Any]):
                                 train_key,
                             )
                         cumulative_per_rank_gradient_steps += 1
-                        if probe.active and bench_batch is None:
-                            bench_batch = batch
                         if cumulative_per_rank_gradient_steps == 1:
                             # shapes only — the batch itself is not pinned
                             telemetry_register_flops(
@@ -1195,34 +1179,6 @@ def main(fabric, cfg: Dict[str, Any]):
     # finished every queued train dispatch before the closing bookkeeping
     fence.drain()
 
-    def _bench_extra():
-        # per-train-step FLOPs for bench.py's MFU: one AOT cost-analysis
-        # compile, paid after the clock stopped
-        from sheeprl_tpu.utils.profiler import compiled_flops
-
-        if bench_superstep is not None:
-            fn, chunk, shapes = bench_superstep
-            flops = compiled_flops(fn, *shapes)
-            return {"flops_per_train_step": flops / chunk} if flops else {}
-        if bench_batch is None:
-            return {}
-
-        flops = compiled_flops(
-            train_fn,
-            wm_params,
-            actor_params,
-            critic_params,
-            target_critic_params,
-            world_opt,
-            actor_opt,
-            critic_opt,
-            moments_state,
-            bench_batch,
-            key,
-        )
-        return {"flops_per_train_step": flops} if flops else {}
-
-    probe.finish(policy_step, work=cumulative_per_rank_gradient_steps, extra=_bench_extra)
     # land any in-flight async param stream so the final evaluation and
     # model registration use the last update's weights
     player.flush_stream_attrs()

@@ -32,13 +32,13 @@ from sheeprl_tpu.algos.sac.loss import entropy_loss, policy_loss
 from sheeprl_tpu.algos.sac.utils import AGGREGATOR_KEYS, prepare_obs, test
 from sheeprl_tpu.data.device_buffer import draw_transition_batch
 from sheeprl_tpu.envs import build_vector_env
-from sheeprl_tpu.obs import telemetry_train_window
+from sheeprl_tpu.obs import telemetry_mark_warm_after_warmup, telemetry_train_window
 from sheeprl_tpu.ops.superstep import fold_sample_key, fused_fallback, reset_fused_fallback_warnings
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import Ratio, SteadyStateProbe, gradient_step_chunks, save_configs, weighted_chunk_metrics
+from sheeprl_tpu.utils.utils import Ratio, gradient_step_chunks, save_configs, weighted_chunk_metrics
 
 
 def _ensemble_apply_dropout(critic, stacked_params, obs, action, key, n_critics):
@@ -381,10 +381,8 @@ def main(fabric, cfg: Dict[str, Any]):
     obs, _ = envs.reset(seed=cfg.seed)
     cumulative_per_rank_gradient_steps = 0
     step_data: Dict[str, np.ndarray] = {}
-    # steady-state throughput probe (SHEEPRL_TPU_BENCH_JSON contract)
-    probe = SteadyStateProbe()
     for update in range(start_step, num_updates + 1):
-        probe.mark_warm(update, learning_starts, policy_step, work=cumulative_per_rank_gradient_steps)
+        telemetry_mark_warm_after_warmup(update, learning_starts)
         policy_step += num_envs * num_processes
 
         with timer("Time/env_interaction_time"):
@@ -585,12 +583,6 @@ def main(fabric, cfg: Dict[str, Any]):
                 replay_buffer=rb if cfg.buffer.checkpoint else None,
             )
 
-    probe.finish(
-        policy_step,
-        # a materializing fetch: the value cannot arrive before the device is done
-        sync=lambda: np.asarray(jax.device_get(agent.log_alpha)),
-        work=cumulative_per_rank_gradient_steps,
-    )
     # land any in-flight async param stream before the final evaluation
     player.flush_stream_attrs()
     envs.close()

@@ -59,6 +59,7 @@ from sheeprl_tpu.envs.variants import (
 from sheeprl_tpu.obs import (
     log_sps_and_heartbeat,
     telemetry_advance,
+    telemetry_mark_warm,
     telemetry_register_flops,
     telemetry_run_metrics,
     telemetry_train_window,
@@ -486,10 +487,6 @@ def main(fabric, cfg: Dict[str, Any]):
     next_obs, _ = envs.reset(seed=cfg.seed)
     next_obs = prepare_obs(next_obs, cnn_keys=cnn_keys, num_envs=num_envs)
 
-    # steady-state throughput probe (bench.py): updates 2..last, skipping the
-    # compile-heavy first update — shared contract in utils.SteadyStateProbe
-    from sheeprl_tpu.utils.utils import SteadyStateProbe
-
     def ckpt_state_fn(completed_update: int) -> Dict[str, Any]:
         # shared by the periodic save, the preemption drain's emergency save
         # and (structurally) the rollback restore — reads the loop's CURRENT
@@ -573,7 +570,6 @@ def main(fabric, cfg: Dict[str, Any]):
         state_fn=lambda: ckpt_state_fn(update - 1),
     )
     preempted = False
-    probe = SteadyStateProbe()
     if superstep_fn is not None:
         # ------------------------------------------------------------------
         # fused on-policy path: rollout + GAE + epochs x minibatches update
@@ -621,7 +617,7 @@ def main(fabric, cfg: Dict[str, Any]):
                 preempted = True
                 break
             if update == start_update + 1:
-                probe.mark(policy_step)
+                telemetry_mark_warm()
             # same fold schedule as the host player: rollout_actions folds
             # policy_step on top of the per-update key inside the superstep
             update_key = jax.random.fold_in(player_key, update)
@@ -732,7 +728,7 @@ def main(fabric, cfg: Dict[str, Any]):
                 preempted = True
                 break
             if update == start_update + 1:
-                probe.mark(policy_step)
+                telemetry_mark_warm()
             buf = store.begin(update)
             with timer("Time/env_interaction_time"):
                 # one jitted dispatch + ONE device->host fetch per env step: key
@@ -865,9 +861,6 @@ def main(fabric, cfg: Dict[str, Any]):
         # before eval and the final checkpointed state
         finalize_pending()
 
-    # the params fetch is a real device sync (everything dispatched before
-    # it has executed once it materializes)
-    probe.finish(policy_step, sync=lambda: jax.device_get(jax.tree.leaves(params)[0]))
     envs.close()
     if fabric.is_global_zero and cfg.algo.run_test and not preempted:
         if obs_widened:

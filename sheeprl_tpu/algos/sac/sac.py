@@ -43,6 +43,7 @@ from sheeprl_tpu.envs import build_vector_env
 from sheeprl_tpu.obs import (
     log_sps_and_heartbeat,
     telemetry_advance,
+    telemetry_mark_warm_after_warmup,
     telemetry_run_metrics,
     telemetry_train_window,
 )
@@ -52,7 +53,7 @@ from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import Ratio, SteadyStateProbe, gradient_step_chunks, save_configs, weighted_chunk_metrics
+from sheeprl_tpu.utils.utils import Ratio, gradient_step_chunks, save_configs, weighted_chunk_metrics
 
 
 def make_train_fn(
@@ -437,8 +438,6 @@ def main(fabric, cfg: Dict[str, Any]):
         replay_buffer_fn=lambda: rb if cfg.buffer.checkpoint else None,
     )
     preempted = False
-    # steady-state throughput probe (SHEEPRL_TPU_BENCH_JSON contract)
-    probe = SteadyStateProbe()
     for update in range(start_step, num_updates + 1):
         telemetry_advance(policy_step)
         if resil.preempt_requested():
@@ -450,7 +449,7 @@ def main(fabric, cfg: Dict[str, Any]):
             )
             preempted = True
             break
-        probe.mark_warm(update, learning_starts, policy_step, work=cumulative_per_rank_gradient_steps)
+        telemetry_mark_warm_after_warmup(update, learning_starts)
         policy_step += num_envs * num_processes
 
         with timer("Time/env_interaction_time"):
@@ -630,12 +629,6 @@ def main(fabric, cfg: Dict[str, Any]):
                 replay_buffer=rb if cfg.buffer.checkpoint else None,
             )
 
-    probe.finish(
-        policy_step,
-        # a materializing fetch: the value cannot arrive before the device is done
-        sync=lambda: np.asarray(jax.device_get(agent.log_alpha)),
-        work=cumulative_per_rank_gradient_steps,
-    )
     # land any in-flight async param stream before the final evaluation
     player.flush_stream_attrs()
     envs.close()

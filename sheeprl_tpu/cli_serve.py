@@ -17,7 +17,7 @@ Sources, one of:
 With ``serve.load.enabled=True`` the scripted load generator drives the
 server and the run report (QPS, p50/p95 vs SLO, shed/retry counts) is
 printed as JSON and emitted as the final ``serve_stats`` telemetry event —
-this is the acceptance path ``bench.py --serve-stats`` reads. Otherwise the
+this is the acceptance path ``python -m tools.report --serve-stats`` reads. Otherwise the
 server runs until SIGTERM/SIGINT, emitting ``serve_stats`` every
 ``serve.stats_interval_s``.
 """

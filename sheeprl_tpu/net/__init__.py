@@ -20,7 +20,7 @@ boundary:
   that bridges a :class:`~sheeprl_tpu.serve.slots.SlotPool` to one agent.
 - :mod:`sheeprl_tpu.net.stats` — per-transport counters (frames, bytes,
   reconnects, checksum rejects, heartbeat gaps) surfaced through the
-  ``net_event`` telemetry stream and ``bench.py --net-stats``.
+  ``net_event`` telemetry stream and ``python -m tools.report --net-stats``.
 """
 
 from sheeprl_tpu.net.agent import ReplicaAgent, agent_child_main

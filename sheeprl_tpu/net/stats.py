@@ -4,7 +4,7 @@ Every transport endpoint registers one :class:`NetStats` under a stable name
 (``tcp.learner``, ``tcp.actor3``, ``remote.replica5``, ``agent``); the
 counters accumulate for the life of the process and are rolled into the run
 registry record at run end (``RunTelemetry.run_summary()['net']``), mirrored
-by ``bench.py --net-stats``. Mutation is plain ``+=`` on int fields — every
+by ``python -m tools.report --net-stats``. Mutation is plain ``+=`` on int fields — every
 writer is a single thread per endpoint, and the read side (telemetry rollup)
 only ever snapshots, so momentary torn reads cost nothing worse than an
 off-by-one in a monitoring counter.

@@ -15,9 +15,9 @@ One instrumentation vocabulary for the whole framework:
   per-log-interval ``heartbeat`` assembly (SPS, duty cycle, MFU, HBM peak,
   recompile count).
 
-The event schema is documented in ``howto/telemetry.md``; ``bench.py``
-consumes the same stream (``telemetry_summary``) so the bench and the run
-report the same numbers. Everything is inert unless
+The event schema is documented in ``howto/telemetry.md``; ``tools/report.py``
+reads the same stream (``telemetry_summary``) so the report and the run
+show the same numbers. Everything is inert unless
 ``metric.telemetry.enabled=True`` — the disabled hot path is one global read.
 """
 
@@ -43,6 +43,7 @@ from sheeprl_tpu.obs.telemetry import (
     telemetry_env_step,
     telemetry_fused_fallback,
     telemetry_mark_warm,
+    telemetry_mark_warm_after_warmup,
     telemetry_masked_slot,
     telemetry_nan_rollback,
     telemetry_net_event,
@@ -107,6 +108,7 @@ __all__ = [
     "telemetry_env_step",
     "telemetry_fused_fallback",
     "telemetry_mark_warm",
+    "telemetry_mark_warm_after_warmup",
     "telemetry_masked_slot",
     "telemetry_nan_rollback",
     "telemetry_net_event",

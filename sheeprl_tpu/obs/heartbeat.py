@@ -4,7 +4,7 @@ Every algorithm's loop used to hand-roll the same ``timer.compute()`` →
 ``Time/sps_*`` → ``timer.reset()`` dance; this helper centralizes it and, when
 run telemetry is active, feeds the same window into
 :meth:`RunTelemetry.heartbeat` so the JSONL stream, TensorBoard scalars and
-``bench.py`` all report identical numbers.
+``tools/report.py`` all report identical numbers.
 
 Callers pass their own window deltas (the env-steps formula differs between
 on-policy and off-policy loops) and reset their ``last_log``/``last_train``

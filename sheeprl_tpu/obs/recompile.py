@@ -12,8 +12,9 @@ jax.monitoring passes no function names, so while the watchdog is active the
 handler parses the "Compiling <name> with global shapes and types" line that
 immediately precedes lowering; the original level is restored on ``stop()``.
 
-After :meth:`mark_warm` (called from the bench steady-state probe, or
-explicitly by loops without one), every further lowering is a *recompile*:
+After :meth:`mark_warm` (each training loop calls it at its own steady-state
+point; the off-policy and Dreamer loops share :meth:`mark_warm_after_warmup`),
+every further lowering is a *recompile*:
 it increments the ``Counters/recompiles`` counter, is tagged
 ``post_warm=true`` in the JSONL stream, and raises a ``RecompileWarning`` —
 silent retracing is the #1 TPU perf killer.
@@ -80,6 +81,7 @@ class CompileWatchdog:
         # recompile — a third category, counted separately
         self.aot_loads: Dict[str, int] = {}
         self.warm = False
+        self._first_update: Optional[int] = None
         # compiles fire on the compiling thread (serve AOT on the server's
         # caller, revalidation on watcher threads), so the allowlist flag
         # must be thread-local: one thread's deliberate window must not
@@ -133,6 +135,27 @@ class CompileWatchdog:
             # one event at the flip, so a reader of the stream can tell "no
             # recompile" from "the warm point was never reached"
             self._emit("warm", compiles=self.compiles)
+
+    #: updates past the first train event before a loop counts as warm —
+    #: enough for every gradient-path compile (incl. the chunked-scan
+    #: variants) to have happened, shared by all off-policy loops
+    WARMUP_UPDATES = 64
+
+    def mark_warm_after_warmup(self, update: int, learning_starts: int) -> None:
+        """The one warm-point rule of the off-policy/Dreamer loops, called
+        every update. Two conditions, both required:
+
+        - ``learning_starts + WARMUP_UPDATES``: past the first train event's
+          compiles (the fresh-run rule);
+        - ``first observed update + WARMUP_UPDATES``: a RESUMED run whose
+          start update is already beyond the fresh-run warm point still does
+          its gradient-path compiles on its first update — going warm there
+          would count every one of them as a recompile.
+        """
+        if self._first_update is None:
+            self._first_update = update
+        if update >= learning_starts + self.WARMUP_UPDATES and update >= self._first_update + self.WARMUP_UPDATES:
+            self.mark_warm()
 
     @contextmanager
     def deliberate(self, reason: str):

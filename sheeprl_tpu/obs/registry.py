@@ -10,8 +10,8 @@ ended (``completed | preempted | crashed | rolled_back`` — plus the
 disaggregated actor–learner outcomes ``actor_exhausted`` / ``learner_crashed``,
 see ``howto/actor_learner.md``). The registry is
 the memory the per-run ``telemetry.jsonl`` lacks: it survives the run
-directory and feeds the regression gates (``tools/regress.py``,
-``bench.py --regress`` → ``SCENARIOS.json``).
+directory and feeds the regression gates (``python -m tools.regress`` →
+``SCENARIOS.json``).
 
 Appends are atomic (``O_APPEND`` + ``flock``) so concurrent runs on one host
 interleave whole lines; the reader is tolerant (unparsable lines are

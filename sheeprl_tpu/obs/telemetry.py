@@ -10,7 +10,7 @@ Event schema (one JSON object per line, documented in howto/telemetry.md):
 every event carries ``event`` (kind), ``t`` (unix seconds), ``step``
 (policy step at emission), ``process_index`` and optionally ``name``; the
 kinds are ``run_start``, ``span``, ``compile``, ``device_poll``,
-``heartbeat``, ``bench_probe``, ``worker_restart``, ``masked_slot`` and
+``heartbeat``, ``worker_restart``, ``masked_slot`` and
 ``run_end``.
 
 The module-level accessor :func:`get_telemetry` returns ``None`` unless a run
@@ -340,7 +340,7 @@ class RunTelemetry:
         ``gradient_steps`` gradient steps.  The per-step path reports
         O(gradient_steps) dispatches, a fused superstep reports
         ceil(gradient_steps / K) — the O(K)→O(1) reduction the dispatch
-        counters exist to make visible (``bench.py --dispatch-stats``)."""
+        counters exist to make visible (``tools.report --dispatch-stats``)."""
         self._window_train_windows += 1
         self._window_train_dispatches += int(dispatches)
         self._window_train_gradient_steps += int(gradient_steps)
@@ -415,7 +415,7 @@ class RunTelemetry:
     def record_fused_fallback(self, reason: str, detail: str = "", **fields: Any) -> None:
         """``algo.fused_gradient_steps`` was requested but this run dispatches
         per-step: one structured ``fused_fallback`` event + run_end counter,
-        so ``bench.py --dispatch-stats`` can say *why* a run shows zero fused
+        so ``tools.report --dispatch-stats`` can say *why* a run shows zero fused
         windows instead of silently reporting O(K) dispatches."""
         self._fused_fallbacks[reason] = self._fused_fallbacks.get(reason, 0) + 1
         self.emit("fused_fallback", reason=reason, detail=detail, **fields)
@@ -550,7 +550,7 @@ class RunTelemetry:
     def _net_section(self) -> Dict[str, Any]:
         """The run_end/run_summary ``net`` section: per-kind sparse event
         counts plus every registered transport endpoint's frame/byte/reconnect
-        counters (``bench.py --net-stats`` reads this path)."""
+        counters (``tools.report --net-stats`` reads this path)."""
         section: Dict[str, Any] = {"events": dict(self._net_events)}
         try:
             from sheeprl_tpu.net.stats import net_stats_snapshot
@@ -1165,6 +1165,12 @@ def telemetry_mark_warm() -> None:
     tel = _active_telemetry
     if tel is not None:
         tel.mark_warm()
+
+
+def telemetry_mark_warm_after_warmup(update: int, learning_starts: int) -> None:
+    tel = _active_telemetry
+    if tel is not None:
+        tel.watchdog.mark_warm_after_warmup(update, learning_starts)
 
 
 @contextmanager

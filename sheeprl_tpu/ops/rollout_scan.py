@@ -1,8 +1,8 @@
 """Fused on-policy collection: the whole rollout+GAE+update as ONE dispatch.
 
 The coupled PPO host loop pays one jitted dispatch plus one device->host fetch
-per env step, then a GAE dispatch, then the fused update — ``benchmarks/
-ppo_floor.py`` measures that bookkeeping at ~3x the jitted-player ceiling.
+per env step, then a GAE dispatch, then the fused update: host bookkeeping
+around a jitted player, step after step.
 This module closes the gap for envs with a jittable twin
 (:mod:`sheeprl_tpu.envs.jittable`): the T-step rollout (agent forward, env
 transition, truncation bootstrap, autoreset, per-step bookkeeping) runs as a

@@ -83,7 +83,7 @@ def fused_fallback(reason: str, detail: str) -> None:
     it dispatches per-step instead.
 
     Emits a structured ``fused_fallback`` telemetry event (always — so
-    ``bench.py --dispatch-stats`` can report *why* a run shows zero fused
+    ``tools.report --dispatch-stats`` can report *why* a run shows zero fused
     windows) and raises a ``UserWarning`` exactly once per ``reason`` per
     run. Known reasons: ``"host_buffer"`` (SAC-family in-scan gather needs
     the device replay ring), ``"model_axis"`` (fused supersteps are pure

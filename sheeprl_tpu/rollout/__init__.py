@@ -22,7 +22,7 @@ build_vector_env`):
 Telemetry: when ``metric.telemetry.enabled=True`` the pool emits
 ``rollout/env_step`` / ``rollout/env_reset`` spans, ``worker_restart`` and
 ``masked_slot`` events, and feeds the heartbeat's env step-latency p50/p95 and
-queue-wait fields (``bench.py --env-stats`` summarizes the stream).
+queue-wait fields (``tools.report --env-stats`` summarizes the stream).
 
 Workers never touch the TPU: the bootstrap pins ``JAX_PLATFORMS=cpu`` and
 strips the distributed-coordinator environment before the child imports jax.

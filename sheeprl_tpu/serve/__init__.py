@@ -10,7 +10,7 @@ with rollback.
 Import layering mirrors ``rollout``: this package root re-exports the
 jax-free surface eagerly; :mod:`~sheeprl_tpu.serve.model` /
 :mod:`~sheeprl_tpu.serve.server` (which import jax) are re-exported lazily
-so ``bench.py``-style parents can read configs and errors without touching
+so parents that stay off jax can read configs and errors without touching
 an accelerator runtime.
 """
 

@@ -23,9 +23,9 @@ This module is that doctrine, factored once:
   threads, the router and swap watchers query concurrently.
 - :func:`register_fault_domain` / :func:`fault_domains` — the domain
   registry. Every domain module declares its fault-kind vocabulary here at
-  import, so drill-coverage tooling (``bench.py --drills`` →
-  ``tools/drills.py``) audits which fault keys the test suite exercises
-  against one authoritative list instead of folklore.
+  import, so drill-coverage tooling (``python -m tools.drills``) audits
+  which fault keys the test suite exercises against one authoritative list
+  instead of folklore.
 
 The domain modules stay the public surface (their specs, kinds and config
 shapes are unchanged); they are thin adapters over this engine.

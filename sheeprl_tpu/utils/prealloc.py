@@ -3,7 +3,7 @@
 The reference collection loop appends per-step numpy arrays to Python lists
 and ``np.stack``s them at the end of the window — for small classic-control
 obs the stack (one more full copy plus T*keys list traversals) is a visible
-slice of the ``benchmarks/ppo_floor.py`` bookkeeping gap.  ``RolloutStore``
+slice of the host loop's bookkeeping.  ``RolloutStore``
 replaces it with arrays of shape ``[T, ...]`` allocated once on the first
 window and written in place (``buf[k][t] = v`` — the write IS the copy, so
 callers that used to ``.copy()`` values before appending can stop).

@@ -88,8 +88,8 @@ def compiled_flops(jitted_fn: Any, *args: Any) -> Optional[float]:
     """FLOPs of ONE invocation of ``jitted_fn`` at the shapes of ``args``,
     read from XLA's cost analysis of an AOT compile built from
     ``ShapeDtypeStruct``s — no data moves, but one extra compile is paid, so
-    callers run this outside any measured window. The number feeds the MFU
-    computation (``bench.py``): flops x steps / seconds / chip peak."""
+    callers run this outside any measured window. The number feeds the
+    heartbeat's MFU: flops x steps / seconds / chip peak."""
     import jax
 
     def as_shape(x: Any) -> Any:

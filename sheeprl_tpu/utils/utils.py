@@ -194,114 +194,6 @@ def print_config(
         print(yaml.safe_dump(cfg.to_dict() if isinstance(cfg, dotdict) else dict(cfg), sort_keys=False))
 
 
-class SteadyStateProbe:
-    """The ``SHEEPRL_TPU_BENCH_JSON`` steady-state throughput contract, in
-    one place (consumed by ``bench.py``; producers are the training loops).
-
-    A loop constructs one probe, calls :meth:`mark` once it considers itself
-    warm (compiles done — each loop picks its own rule), and :meth:`finish`
-    after its final update with a zero-arg ``sync`` callable that genuinely
-    waits for the device (a materializing fetch: the value cannot arrive
-    before the device is done)."""
-
-    def __init__(self) -> None:
-        import os
-
-        self.path = os.environ.get("SHEEPRL_TPU_BENCH_JSON")
-        self._t0: float | None = None
-        self._step0 = 0
-        self._first_update: int | None = None
-
-    @property
-    def active(self) -> bool:
-        return self.path is not None
-
-    #: updates past the first train event before the window opens — enough
-    #: for every gradient-path compile (incl. the chunked-scan variants) to
-    #: have happened, shared by all off-policy loops
-    WARMUP_UPDATES = 64
-
-    def mark_warm(self, update: int, learning_starts: int, step: int, work: int = 0) -> None:
-        """Open the window once ``update`` reaches the shared warm point —
-        the one probe convention of the off-policy/Dreamer loops, kept here
-        so it cannot drift. Two conditions, both required:
-
-        - ``learning_starts + WARMUP_UPDATES``: past the first train event's
-          compiles (the fresh-run rule);
-        - ``first observed update + WARMUP_UPDATES``: a RESUMED run whose
-          start update is already beyond the fresh-run warm point still does
-          its gradient-path compiles on its first update — opening there
-          would put minutes of compile time inside the measured window.
-        """
-        if self._first_update is None:
-            self._first_update = update
-        if update >= learning_starts + self.WARMUP_UPDATES and update >= self._first_update + self.WARMUP_UPDATES:
-            self.mark(step, work=work)
-
-    def mark(self, step: int, work: int = 0) -> None:
-        """``work`` is the loop's cumulative gradient-step counter at the
-        mark, so the window's training work can be reported alongside its
-        env steps (the MFU numerator needs gradient steps, not env steps)."""
-        # every loop's steady-state point doubles as the recompile watchdog's
-        # warm point — anything traced past here is a genuine recompile
-        from sheeprl_tpu.obs.telemetry import telemetry_mark_warm
-
-        telemetry_mark_warm()
-        if self.path is None or self._t0 is not None:
-            return
-        import time
-
-        self._t0, self._step0, self._work0 = time.perf_counter(), step, work
-
-    def finish(self, step: int, sync=None, work: int = 0, extra=None) -> None:
-        """``extra``: optional dict (or zero-arg callable returning one)
-        merged into the record AFTER the clock stops — expensive bookkeeping
-        like an AOT cost-analysis compile goes here without polluting the
-        measured window."""
-        if self.path is None:
-            return
-        import json
-        import time
-
-        import jax
-
-        if self._t0 is None:
-            # The run ended before the warmup gate opened the window. That is
-            # NOT an outage — the workload was simply shorter than
-            # learning_starts/WARMUP_UPDATES — so say exactly that, both to
-            # bench.py (which raises a targeted error instead of the outage
-            # path) and to the telemetry stream.
-            detail = (
-                f"run ended at step {step} before the steady-state window opened "
-                f"(first update {self._first_update}, warmup {self.WARMUP_UPDATES} updates); "
-                "raise total_steps or lower learning_starts for this bench"
-            )
-            from sheeprl_tpu.obs.telemetry import get_telemetry
-
-            tel = get_telemetry()
-            if tel is not None:
-                tel.emit("bench_probe", error="window_never_opened", detail=detail)
-            if jax.process_index() == 0:
-                with open(self.path, "w") as f:
-                    json.dump({"error": "window_never_opened", "detail": detail}, f)
-            return
-
-        if sync is not None:
-            sync()
-        seconds = time.perf_counter() - self._t0
-        if jax.process_index() != 0:  # one writer on multi-process runs
-            return
-        rec = {"steps": step - self._step0, "seconds": seconds}
-        if work:
-            rec["train_steps"] = work - getattr(self, "_work0", 0)
-        if callable(extra):
-            extra = extra()
-        if extra:
-            rec.update(extra)
-        with open(self.path, "w") as f:
-            json.dump(rec, f)
-
-
 def gradient_step_chunks(n_steps: int, algo_cfg: Mapping[str, Any]) -> list:
     """Split a variable gradient-step count into jit-shape-stable pieces.
 

@@ -118,13 +118,14 @@ def test_actor_crash_mid_write_drill(tmp_path, monkeypatch):
     for key in ("age_ms", "collect_ms", "ring_wait_ms", "train_ms"):
         assert "p50" in slabs[key] and "p95" in slabs[key]
 
-    # bench.py --trace prints the same decomposition from the jax-free parent
+    # tools.report --trace prints the same decomposition without importing jax
     import subprocess
     import sys
 
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--trace", *files],
+        [sys.executable, "-m", "tools.report", "--trace", *files],
+        cwd=repo,
         capture_output=True,
         text=True,
         timeout=120,

@@ -6,7 +6,6 @@ the documented warn-fallbacks and the Dreamer host-buffer pregather path."""
 
 import json
 import os
-import sys
 
 import pytest
 
@@ -14,6 +13,7 @@ from sheeprl_tpu.cli import run
 from tests.test_algos.test_a2c_droq import droq_args
 from tests.test_algos.test_dreamer_v3 import dv3_args, find_checkpoints
 from tests.test_algos.test_sac import sac_args
+from tools import report
 
 TELEMETRY = ["metric.telemetry.enabled=True", "metric.telemetry.poll_interval=0.0"]
 
@@ -26,16 +26,6 @@ def _run_end(tmp_path):
     events = [json.loads(line) for line in open(jsonls[0]) if line.strip()]
     (end,) = [e for e in events if e["event"] == "run_end"]
     return end, jsonls[0]
-
-
-def _bench():
-    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    sys.path.insert(0, repo_root)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    return bench
 
 
 def test_dreamer_v3_fused_device_buffer_single_dispatch_per_window(tmp_path, monkeypatch):
@@ -70,7 +60,7 @@ def test_dreamer_v3_fused_device_buffer_single_dispatch_per_window(tmp_path, mon
     # first call always yields 1; later windows carry replay_ratio * steps)
     assert end["train_gradient_steps"] > end["train_windows"]
 
-    ds = _bench().dispatch_stats(path)
+    ds = report.dispatch_stats(path)
     assert ds["dispatches_per_window"] == 1.0
     assert ds["train_gradient_steps"] == end["train_gradient_steps"]
 
@@ -118,7 +108,7 @@ def test_dreamer_v3_fused_multi_device_single_dispatch_per_window(tmp_path, monk
     assert end["train_gradient_steps"] > end["train_windows"]
     assert not end.get("fused_fallbacks")
 
-    ds = _bench().dispatch_stats(path)
+    ds = report.dispatch_stats(path)
     assert ds["dispatches_per_window"] == 1.0
     assert "fused_fallbacks" not in ds
 
@@ -158,7 +148,7 @@ def test_sac_fused_device_buffer_single_dispatch_per_window(tmp_path, monkeypatc
 def test_sac_fused_host_buffer_falls_back_with_warning(tmp_path, monkeypatch):
     """SAC's host-buffer path already scans each chunk in one jit, so
     fused_gradient_steps without buffer.device warns (once) and is ignored —
-    and the reason lands in run_end / ``bench.py --dispatch-stats`` so a
+    and the reason lands in run_end / ``tools.report --dispatch-stats`` so a
     per-step run is diagnosable after the fact."""
     monkeypatch.chdir(tmp_path)
     with pytest.warns(UserWarning, match="device replay buffer"):
@@ -170,7 +160,7 @@ def test_sac_fused_host_buffer_falls_back_with_warning(tmp_path, monkeypatch):
     assert find_checkpoints(tmp_path)
     end, path = _run_end(tmp_path)
     assert end["fused_fallbacks"] == {"host_buffer": 1}
-    assert _bench().dispatch_stats(path)["fused_fallbacks"] == {"host_buffer": 1}
+    assert report.dispatch_stats(path)["fused_fallbacks"] == {"host_buffer": 1}
 
 
 def test_droq_fused_device_buffer_dispatch_budget(tmp_path, monkeypatch):

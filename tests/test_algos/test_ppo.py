@@ -159,7 +159,7 @@ def test_ppo_unknown_algo_error(tmp_path):
 def test_ppo_telemetry_smoke(tmp_path, monkeypatch):
     """One tiny CPU update with metric.telemetry.enabled=True: the run must
     leave a telemetry.jsonl whose span names match the timer metric keys and
-    that carries compile/device_poll/heartbeat events, and bench.py must be
+    that carries compile/device_poll/heartbeat events, and tools/report.py must be
     able to compute SPS from it without log scraping (ISSUE acceptance)."""
     import json
     import sys
@@ -185,14 +185,10 @@ def test_ppo_telemetry_smoke(tmp_path, monkeypatch):
     span_names = {e["name"] for e in events if e["event"] == "span"}
     assert {"Time/env_interaction_time", "Time/train_time"} <= span_names
 
-    # bench.py digests the stream without touching the run's logs
-    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    sys.path.insert(0, repo_root)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    summary = bench.telemetry_summary(jsonls[0])
+    # tools/report.py digests the stream without touching the run's logs
+    from tools import report
+
+    summary = report.telemetry_summary(jsonls[0])
     assert summary["sps_env"] > 0
     assert summary["sps_train"] > 0
     assert summary["compiles"] >= 1

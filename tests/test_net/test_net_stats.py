@@ -1,10 +1,9 @@
-"""`bench.py --net-stats` plumbing: the report reads the run_end ``net``
+"""`python -m tools.report --net-stats` plumbing: the report reads the run_end ``net``
 section (per-endpoint transport counters + per-kind event totals), the
 sparse ``net_event`` log, and the ``net_handshake`` clock-skew observations
 — and falls back to summing the event stream when the run is still going
 (no run_end yet)."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -12,17 +11,11 @@ import sys
 
 import pytest
 
+from tools import report
+
 pytestmark = pytest.mark.net
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH = os.path.join(REPO_ROOT, "bench.py")
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location("_bench_net_stats", BENCH)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _write(path, events):
@@ -59,10 +52,9 @@ _RUN_END = {
 
 
 def test_report_prefers_run_end_counters(tmp_path):
-    bench = _load_bench()
     path = str(tmp_path / "telemetry.jsonl")
     _write(path, _EVENTS + [_RUN_END])
-    out = bench.net_stats_report(path)
+    out = report.net_stats_report(path)
     assert out["events"] == {"reconnect": 2, "disconnect": 1}
     assert set(out["transports"]) == {"tcp.learner", "tcp.actor0"}
     assert out["transports"]["tcp.learner"]["checksum_rejects"] == 1
@@ -79,29 +71,27 @@ def test_report_prefers_run_end_counters(tmp_path):
 
 
 def test_report_falls_back_to_stream_without_run_end(tmp_path):
-    bench = _load_bench()
     path = str(tmp_path / "telemetry.jsonl")
     _write(path, _EVENTS)
-    out = bench.net_stats_report(path)
+    out = report.net_stats_report(path)
     assert out["events"] == {"disconnect": 1, "reconnect": 1}
     assert "transports" not in out  # counters only live in run_end
     assert out["handshakes"]["count"] == 2
 
 
 def test_report_notes_streams_with_no_net_plane(tmp_path):
-    bench = _load_bench()
     path = str(tmp_path / "telemetry.jsonl")
     _write(path, [{"event": "heartbeat", "t": 1.0}])
-    out = bench.net_stats_report(path)
+    out = report.net_stats_report(path)
     assert "note" in out and "multihost" in out["note"]
 
 
 def test_net_stats_cli(tmp_path):
-    """`bench.py --net-stats PATH` prints the JSON report (jax-free parent)."""
+    """`python -m tools.report --net-stats PATH` prints the JSON report."""
     path = str(tmp_path / "telemetry.jsonl")
     _write(path, _EVENTS + [_RUN_END])
     proc = subprocess.run(
-        [sys.executable, BENCH, "--net-stats", path],
+        [sys.executable, "-m", "tools.report", "--net-stats", path],
         capture_output=True,
         text=True,
         timeout=60,

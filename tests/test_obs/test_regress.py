@@ -1,6 +1,5 @@
-"""Regression gates (tools/regress.py + bench.py --regress): verdicts on
-synthetic history, the SCENARIOS.json grid, exit codes, BENCH_*.json
-folding, and the CLI surfaces."""
+"""Regression gates (tools/regress.py): verdicts on synthetic history, the
+SCENARIOS.json grid, exit codes, and the CLI surfaces."""
 
 import importlib.util
 import json
@@ -117,25 +116,6 @@ def test_run_gate_writes_scenarios_and_exit_code(regress, tmp_path):
         assert json.load(f)["summary"]["regress"] == 0
 
 
-def test_bench_json_folding(regress, tmp_path):
-    for n, (value, outage) in enumerate([(50.0, False), (51.0, False), (49.0, True), (20.0, False)]):
-        parsed = {
-            "metric": "dreamer_v3_env_steps_per_sec_per_chip",
-            "value": value,
-            "secondary": {"metric": "ppo_cartpole_env_steps_per_sec", "value": value * 10},
-        }
-        if outage:
-            parsed["outage"] = True
-        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-            json.dump({"n": n, "rc": 0, "parsed": parsed}, f)
-    records = regress.bench_records(str(tmp_path / "BENCH_r*.json"))
-    # 3 rounds kept (outage skipped), each contributing primary + secondary
-    assert len(records) == 6
-    doc = regress.evaluate(records)
-    assert doc["cells"]["bench:dreamer_v3:bench:?x?p?"]["verdict"] == "regress"  # 50,51 -> 20
-    assert doc["cells"]["bench:ppo:bench:?x?p?"]["verdict"] == "regress"
-
-
 def test_self_test_cli(tmp_path):
     proc = subprocess.run(
         [sys.executable, REGRESS_PY, "--self-test"],
@@ -149,8 +129,8 @@ def test_self_test_cli(tmp_path):
 
 
 def test_bench_regress_cli(tmp_path):
-    """bench.py --regress drives the gate from the jax-free parent: grid on
-    disk, nonzero exit on a synthetically regressed record."""
+    """``python -m tools.regress`` drives the gate: grid on disk, nonzero
+    exit on a synthetically regressed record."""
     runs = tmp_path / "RUNS.jsonl"
     out = tmp_path / "SCENARIOS.json"
     with open(runs, "w") as f:
@@ -158,16 +138,14 @@ def test_bench_regress_cli(tmp_path):
             f.write(json.dumps(_rec(t, sps_env=sps)) + "\n")
     cmd = [
         sys.executable,
-        os.path.join(REPO_ROOT, "bench.py"),
-        "--regress",
+        "-m",
+        "tools.regress",
         "--runs",
         str(runs),
-        "--scenarios-out",
+        "--out",
         str(out),
-        "--bench-glob",
-        "",
     ]
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120, cwd=str(tmp_path))
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120, cwd=REPO_ROOT)
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert "REGRESS" in proc.stdout
     with open(out) as f:
@@ -176,5 +154,5 @@ def test_bench_regress_cli(tmp_path):
     # make the newest healthy again: exit 0
     with open(runs, "a") as f:
         f.write(json.dumps(_rec(9, sps_env=101.0)) + "\n")
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120, cwd=str(tmp_path))
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120, cwd=REPO_ROOT)
     assert proc.returncode == 0, proc.stdout + proc.stderr

@@ -1,4 +1,4 @@
-"""bench.py --serve-stats / tools/regress.py folds for the online bridge."""
+"""tools/report.py --serve-stats / tools/regress.py folds for the online bridge."""
 
 import importlib.util
 import os
@@ -19,7 +19,7 @@ def _load(name, rel):
 
 @pytest.fixture(scope="module")
 def bench():
-    return _load("_bench_online_fold", "bench.py")
+    return _load("_report_online_fold", "tools/report.py")
 
 
 def test_serve_stats_folds_bridge_events_and_run_end_online(bench):

@@ -11,6 +11,7 @@ import sys
 from sheeprl_tpu.cli import run
 from sheeprl_tpu.resilience import PREEMPTED_EXIT_CODE, committed_checkpoints, read_manifest
 from sheeprl_tpu.utils.checkpoint import load_checkpoint
+from tools import report
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -56,15 +57,6 @@ def _ckpt_dirs(tmp_path):
     for root, dirs, _ in os.walk(tmp_path):
         out += [os.path.join(root, d) for d in dirs if d == "checkpoint"]
     return out
-
-
-def _bench():
-    sys.path.insert(0, REPO_ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    return bench
 
 
 def test_sigterm_drill_and_auto_resume(tmp_path, monkeypatch):
@@ -132,8 +124,8 @@ raise SystemExit(0)
     assert len(resumed) == 1
     assert resumed[0]["path"] == emergency.path and resumed[0]["ckpt_step"] == 64
 
-    # bench --resilience-stats digests the drill without log scraping
-    stats = _bench().resilience_stats(jsonl)
+    # tools.report --resilience-stats digests the drill without log scraping
+    stats = report.resilience_stats(jsonl)
     assert stats["totals"]["preemptions"] == 1
     assert 64 in stats["emergency_steps"]
     assert stats["auto_resume"][0]["ckpt_step"] == 64
@@ -181,7 +173,7 @@ def test_nan_drill_one_rollback_run_completes(tmp_path, monkeypatch):
     ]
     assert finals and load_checkpoint(finals[0].path)["update"] == 4
 
-    stats = _bench().resilience_stats(jsonl)
+    stats = report.resilience_stats(jsonl)
     assert stats["totals"]["nan_rollbacks"] == 1
     assert stats["nan_rollbacks"][0]["update"] == 3
 
@@ -211,7 +203,7 @@ def test_async_save_blocks_snapshot_only(tmp_path, monkeypatch):
     # every periodic boundary either committed or was accounted as skipped
     assert len(commits) + len(skips) == 4
 
-    stats = _bench().resilience_stats(jsonl)
+    stats = report.resilience_stats(jsonl)
     assert stats["snapshot"]["count"] == len(snapshots)
     assert stats["write"]["async_count"] >= 1
     assert stats["totals"]["ckpt_commits"] == len(commits)

@@ -1,12 +1,12 @@
 """The ISSUE acceptance path end to end: a real algorithm main trained over
 ``env.backend=pool`` with an injected worker crash completes normally, and
-``bench.py --env-stats`` surfaces the restart from the run's telemetry."""
+``tools.report --env-stats`` surfaces the restart from the run's telemetry."""
 
 import json
 import os
 
-import bench
 from sheeprl_tpu.cli import run
+from tools import report
 
 
 def _args(tmp_path):
@@ -47,7 +47,7 @@ def test_ppo_over_pool_with_crash_completes_and_reports(tmp_path, monkeypatch):
     for root, _, files in os.walk(tmp_path):
         jsonls += [os.path.join(root, f) for f in files if f == "telemetry.jsonl"]
     assert len(jsonls) == 1, jsonls
-    stats = bench.env_stats_summary(jsonls[0])
+    stats = report.env_stats_summary(jsonls[0])
 
     # the run finished (run() returning IS the exact-step-count proof: the
     # rollout loop iterates a fixed schedule and a lost step would deadlock

@@ -1,15 +1,15 @@
 """Rollout telemetry plumbing end to end, without worker processes:
 RunTelemetry's env-step reservoir / restart / mask counters → the JSONL
-stream → bench.py's ``--env-stats`` reader."""
+stream → the ``tools.report --env-stats`` reader."""
 
 import json
 
 import numpy as np
 import pytest
 
-import bench
 from sheeprl_tpu.obs import configure_telemetry, shutdown_telemetry, span
 from sheeprl_tpu.rollout import EnvPool, PoolConfig
+from tools import report
 
 
 @pytest.fixture()
@@ -68,7 +68,7 @@ def test_restart_and_mask_events_and_run_end_totals(telemetry):
 
     path = telemetry.writer.path
     shutdown_telemetry()
-    events = bench.read_telemetry(path)
+    events = report.read_telemetry(path)
     (end,) = [e for e in events if e["event"] == "run_end"]
     assert end["worker_restarts"] == 2
     assert end["masked_slots"] == 2
@@ -84,7 +84,7 @@ def test_bench_env_stats_summary(telemetry):
     path = telemetry.writer.path
     shutdown_telemetry()
 
-    stats = bench.env_stats_summary(path)
+    stats = report.env_stats_summary(path)
     assert stats["env_step"]["count"] == 3
     assert stats["env_step"]["p50_ms"] == pytest.approx(12.0, rel=0.01)
     assert stats["env_step"]["max_ms"] == pytest.approx(300.0, rel=0.01)
@@ -102,7 +102,7 @@ def test_bench_env_stats_empty_stream(tmp_path):
     path = str(tmp_path / "telemetry.jsonl")
     with open(path, "w") as f:
         f.write(json.dumps({"event": "heartbeat", "t": 0.0}) + "\n")
-    stats = bench.env_stats_summary(path)
+    stats = report.env_stats_summary(path)
     assert "env_step" not in stats
     assert stats["totals"] == {"worker_restarts": 0, "masked_slots": 0}
 
@@ -110,12 +110,12 @@ def test_bench_env_stats_empty_stream(tmp_path):
 def test_bench_percentile_matches_numpy():
     vals = sorted([0.3, 1.0, 2.5, 9.0, 4.2, 0.01])
     for q in (50, 95, 99):
-        assert bench._percentile(vals, q) == pytest.approx(float(np.percentile(vals, q)))
+        assert report._percentile(vals, q) == pytest.approx(float(np.percentile(vals, q)))
 
 
 def test_pool_step_emits_spans_and_latency(telemetry, tmp_path):
     """One real pool under live telemetry: step/reset spans land in the
-    stream and bench --env-stats can read the run."""
+    stream and tools.report --env-stats can read the run."""
     from sheeprl_tpu.envs.toy import PixelCatcher
 
     def thunk():
@@ -135,6 +135,6 @@ def test_pool_step_emits_spans_and_latency(telemetry, tmp_path):
     for e in step_spans:
         assert e["attrs"]["queue_wait_s"] >= 0.0
         assert e["dur"] >= e["attrs"]["busy_s"]
-    stats = bench.env_stats_summary(events)
+    stats = report.env_stats_summary(events)
     assert stats["env_step"]["count"] == 3
     assert stats["totals"] == {"worker_restarts": 0, "masked_slots": 0}

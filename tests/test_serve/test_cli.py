@@ -1,31 +1,19 @@
 """CLI acceptance (`python -m sheeprl_tpu serve` == cli_serve.serving): load
 a committed checkpoint by manifest, AOT-warm the ladder, run the scripted
-load generator, and have `bench.py --serve-stats` digest the telemetry — plus
-the torn-checkpoint refusal and bench's targeted degradation."""
+load generator, and have `tools.report --serve-stats` digest the telemetry — plus
+the torn-checkpoint refusal and the reader's targeted degradation."""
 
 import json
-import os
-import sys
 
 import pytest
 import yaml
 
 from sheeprl_tpu.serve.errors import SwapRejected
+from tools.report import serve_stats
 
 from .conftest import commit_linear
 
 pytestmark = pytest.mark.serve
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
-def _bench():
-    sys.path.insert(0, REPO_ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    return bench
 
 
 def _serve_run(tmp_path, step=100):
@@ -52,7 +40,7 @@ def _parse_serve_stats(stdout: str) -> dict:
 def test_cli_acceptance_load_run_meets_slo_and_bench_reads_it(tmp_path, capsys, monkeypatch):
     """The ISSUE acceptance path: serve a committed checkpoint, AOT-warm,
     drive the load generator, sustain QPS with p95 <= SLO on CPU, and read
-    the same numbers back through bench.py --serve-stats."""
+    the same numbers back through tools.report --serve-stats."""
     from sheeprl_tpu.cli_serve import serving
 
     run_dir, ckpt_path, _ = _serve_run(tmp_path)
@@ -79,9 +67,9 @@ def test_cli_acceptance_load_run_meets_slo_and_bench_reads_it(tmp_path, capsys, 
     # every rung of the default ladder was AOT-warmed before traffic
     assert sorted(int(k) for k in snap["warmup_s"]) == [1, 2, 4, 8]
 
-    # bench reads the run's own telemetry stream — no log scraping
+    # the reader takes the run's own telemetry stream — no log scraping
     jsonl = str(run_dir / "telemetry.jsonl")
-    stats = _bench().serve_stats(jsonl)
+    stats = serve_stats(jsonl)
     assert "error" not in stats
     assert stats["totals"]["completed"] == snap["completed"]
     assert stats["load_report"]["ok"] == report["ok"]
@@ -126,15 +114,14 @@ def test_cli_requires_a_source():
 
 
 def test_bench_serve_stats_degrades_with_targeted_errors(tmp_path):
-    bench = _bench()
-    missing = bench.serve_stats(str(tmp_path / "nope.jsonl"))
+    missing = serve_stats(str(tmp_path / "nope.jsonl"))
     assert "cannot read telemetry stream" in missing["error"]
     # a training-run stream without serve activity: targeted message, no dump
     stream = tmp_path / "telemetry.jsonl"
     with open(stream, "w") as f:
         f.write(json.dumps({"event": "run_start"}) + "\n")
         f.write(json.dumps({"event": "run_end", "preemptions": 0}) + "\n")
-    empty = bench.serve_stats(str(stream))
+    empty = serve_stats(str(stream))
     assert "no serve telemetry" in empty["error"]
 
 

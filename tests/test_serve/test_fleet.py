@@ -85,7 +85,7 @@ def test_kill_replica_mid_burst_zero_dropped(make_fleet, tmp_path):
     the dead replica, and the survivors keep serving. Runs under the trace
     plane: the merged timeline must show one complete causal chain per
     request, the kill's stranded batch attributed re-routed, and the
-    queue-wait/assembly/compute decomposition via ``bench.py --trace``."""
+    queue-wait/assembly/compute decomposition via ``tools.report --trace``."""
     from sheeprl_tpu.obs.trace import configure_trace, shutdown_trace
 
     trace_path = str(tmp_path / "trace.serve.jsonl")
@@ -178,9 +178,10 @@ def test_kill_replica_mid_burst_zero_dropped(make_fleet, tmp_path):
     # the kill itself lands on the untraced (process-scoped) timeline
     assert any(e["kind"] == "replica_killed" for e in merged["untraced"])
 
-    # bench.py --trace prints the request latency decomposition
+    # tools.report --trace prints the request latency decomposition
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--trace", trace_path],
+        [sys.executable, "-m", "tools.report", "--trace", trace_path],
+        cwd=REPO,
         capture_output=True,
         text=True,
         timeout=120,

@@ -2,8 +2,8 @@
 
 The budget-tiered sweep runner must (a) enumerate a grid with >= 20 learn
 cells all riding the fused path, (b) score reward trends with the
-learning_checks.sh method, (c) defer chip-tier cells into benchmarks/
-QUEUE.json without duplicating standing entries, and (d) fold executed
+learning_checks.sh method, (c) give chip-tier cells a ``deferred_chip``
+verdict that carries the command to run on a TPU, and (d) fold executed
 verdicts into SCENARIOS.json without clobbering the static sections (the
 half tools/regress.py PRESERVED_KEYS carries through its rewrites).
 
@@ -42,8 +42,7 @@ def test_chip_deferrals_do_not_collide_with_smoke_keys():
     assert chip, "chip tier must defer at least the pixel-Dreamer cells"
     for cell in chip:
         assert cell["key"] not in executed_keys, "chip key would overwrite an executed verdict"
-        assert cell["queue_entry"]["requires"] == "tpu"
-        assert cell["queue_entry"]["argv"], cell["key"]
+        assert cell["command"][0] == "python" and len(cell["command"]) > 1, cell["key"]
 
 
 def test_reward_trend_first_vs_last_fifth():
@@ -62,23 +61,6 @@ def test_reward_trend_first_vs_last_fifth():
     # fewer than 10 episodes -> no verdict, not a crash
     assert sweep.reward_trend(lines[0]) is None
     assert sweep.reward_trend("") is None
-
-
-def test_defer_chip_cells_dedups_and_keeps_standing_entries(tmp_path):
-    queue = os.path.join(tmp_path, "QUEUE.json")
-    standing = {"id": "xl_mfu_2d", "requires": "tpu", "argv": ["benchmarks/xl.py"]}
-    with open(queue, "w") as f:
-        json.dump({"schema": 1, "entries": [standing]}, f)
-    chip = sweep.chip_deferrals()
-    added = sweep.defer_chip_cells(chip, queue)
-    assert set(added) == {c["queue_entry"]["id"] for c in chip}
-    # a second sweep adds nothing and rewrites nothing
-    assert sweep.defer_chip_cells(chip, queue) == []
-    with open(queue) as f:
-        doc = json.load(f)
-    ids = [e["id"] for e in doc["entries"]]
-    assert ids[0] == "xl_mfu_2d", "standing entries stay first and untouched"
-    assert len(ids) == len(set(ids)) == 1 + len(chip)
 
 
 def test_fold_executed_merges_and_preserves_static_sections(tmp_path):
@@ -108,7 +90,7 @@ def test_fold_executed_merges_and_preserves_static_sections(tmp_path):
     assert doc["executed_cells"]["sweep:ppo:CartPole-v1"]["wall_s"] == 30.0
     assert doc["executed_cells"]["sweep:a2c:CartPole-v1"]["verdict"] == "learn_fail"
     assert doc["executed_cells"][chip[0]["key"]]["verdict"] == "deferred_chip"
-    assert doc["executed_cells"][chip[0]["key"]]["queue_id"] == chip[0]["queue_entry"]["id"]
+    assert doc["executed_cells"][chip[0]["key"]]["command"] == chip[0]["command"]
     # the static sections next door are untouched
     assert doc["cells"] == {"train:ppo:CartPole-v1:cpux1p1": {"status": "pass"}}
     assert doc["config_cells"] == {"ppo/gym": {"status": "ok"}}
@@ -140,5 +122,5 @@ def test_stats_rolls_up_executed_cells(tmp_path):
     assert out["by_verdict"] == {"learn_pass": 1, "smoke_pass": 1}
     (row,) = [r for r in out["rows"] if r["tier"] == "learn"]
     assert row["sps_env"] == 33000.0 and row["rew_last_fifth"] == 200.0
-    # unreadable path reports instead of raising (bench.py --sweep-stats UX)
+    # unreadable path reports instead of raising (the --stats UX)
     assert "error" in sweep.stats(os.path.join(tmp_path, "missing.json"))

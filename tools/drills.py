@@ -1,4 +1,4 @@
-"""Chaos-drill registry (``bench.py --drills``).
+"""Chaos-drill registry (``python -m tools.drills``).
 
 Every fault kind registered with the unified fault machinery
 (:func:`sheeprl_tpu.utils.faults.fault_domains`) is cross-referenced
@@ -216,9 +216,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             print(f"  [{drill['verdict']:>7}] {drill['nodeid']} marks={marks} faults={kinds}")
         for domain, kinds in sorted(registry["uncovered"].items()):
             print(f"  UNDRILLED {domain}: {', '.join(kinds)}")
-    # undrilled kinds are a registry finding, not a failure: exit 0 so the
-    # bench wrapper decides what to gate on
-    return 0
+    return 1 if registry["uncovered"] else 0
 
 
 if __name__ == "__main__":

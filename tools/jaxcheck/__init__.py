@@ -36,7 +36,7 @@ from .core import (  # noqa: F401  (re-exported API)
 )
 from .rules import FAMILIES, RULES, family_of, run_rules  # noqa: F401
 
-DEFAULT_TARGETS = ("sheeprl_tpu", "tools", "benchmarks", "examples", "bench.py")
+DEFAULT_TARGETS = ("sheeprl_tpu", "tools", "benchmarks", "examples")
 EXCLUDE_DIR_NAMES = {"__pycache__", ".git", "configs", "tests"}
 DEFAULT_BASELINE = os.path.join("tools", "jaxcheck_baseline.json")
 
@@ -106,7 +106,7 @@ def counts_by_rule(findings: Sequence[Finding]) -> Dict[str, int]:
 
 def counts_by_family(findings: Sequence[Finding]) -> Dict[str, int]:
     """Findings bucketed by rule family (tracing/concurrency/sharding) —
-    the per-family breakdown bench.py --static folds into SCENARIOS.json."""
+    the per-family breakdown the gate folds into SCENARIOS.json."""
     out: Dict[str, int] = {family: 0 for family in FAMILIES}
     for f in findings:
         out[family_of(f.rule)] = out.get(family_of(f.rule), 0) + 1

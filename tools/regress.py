@@ -38,12 +38,8 @@ net_reconnects      lower   run_end ``net.transports`` sums (slack 1)
 net_heartbeat_gaps  lower   run_end ``net.transports`` sums (slack 1)
 ==================  ======  =====================================
 
-``--bench`` additionally folds the repo's ``BENCH_r*.json`` driver records
-into synthetic ``bench:*`` cells so the historical chip numbers participate
-even though they predate the registry.
-
-Deliberately dependency-free (stdlib only): ``bench.py --regress`` loads
-this file in the jax-free parent process, and CI can run it on any box.
+Deliberately dependency-free (stdlib only): it imports no jax, so it runs
+beside a process that holds the chip, and CI can run it on any box.
 
 ``--self-test`` runs the verdict logic against a synthetic history
 (pass / regress / insufficient) and exits nonzero on any mismatch — the
@@ -54,7 +50,6 @@ from __future__ import annotations
 
 import argparse
 import fnmatch
-import glob as globlib
 import json
 import os
 import sys
@@ -107,13 +102,8 @@ METRICS: Dict[str, Tuple[bool, float]] = {
 # NEWEST completed record of every matching cell REGARDLESS of history depth:
 # an absolute bar must not hide behind a regressed baseline or an
 # insufficient-history verdict the way the relative band can. All floored
-# metrics are higher-is-better. The ISSUE-14 bar: the 2-D (data, model)
-# fused Dreamer-V3 superstep must sustain >=30% MFU on chip
-# (benchmarks/mfu_probe.py --mesh ... --record). CPU virtual-mesh cells —
-# recorded for continuity until the chip queue drains — sit outside the
-# tpu* glob on purpose.
+# metrics are higher-is-better.
 METRIC_FLOORS: Tuple[Tuple[str, str, float], ...] = (
-    ("train:dreamer_v3:*:tpu*:mfu", "mfu", 0.30),
     # The ISSUE-19 bar: the batched domain-randomization sweep
     # (benchmarks/scenario_sweep.py --record) must sustain >=100k AGGREGATE
     # env-steps/s across its scenario instances — on every backend, CPU
@@ -155,41 +145,6 @@ def read_records(path: str) -> List[Dict[str, Any]]:
     return out
 
 
-def bench_records(pattern: str) -> List[Dict[str, Any]]:
-    """Fold the driver-captured ``BENCH_r*.json`` files into synthetic
-    registry records (kind ``bench``), skipping outage rounds whose numbers
-    are cached replays of older windows."""
-    out: List[Dict[str, Any]] = []
-    for path in sorted(globlib.glob(pattern)):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        parsed = doc.get("parsed") if isinstance(doc, dict) else None
-        if not isinstance(parsed, dict) or parsed.get("outage"):
-            continue
-        t = float(doc.get("n", 0) or 0)  # round index orders the history
-        sections = [parsed] + ([parsed["secondary"]] if isinstance(parsed.get("secondary"), dict) else [])
-        for sec in sections:
-            name, value = sec.get("metric"), sec.get("value")
-            if not name or value is None:
-                continue
-            algo = str(name).split("_env_steps", 1)[0].split("_cartpole", 1)[0]
-            out.append(
-                {
-                    "schema": SCHEMA_VERSION,
-                    "t": t,
-                    "kind": "bench",
-                    "algo": algo,
-                    "env": "bench",
-                    "outcome": "completed",
-                    "sps_env": float(value),
-                }
-            )
-    return out
-
-
 # ---------------------------------------------------------------- cells ----
 
 
@@ -199,7 +154,7 @@ def cell_key(rec: Dict[str, Any]) -> str:
     procs = rec.get("process_count")
     topo = f"{backend}x{devices or '?'}p{procs or '?'}"
     key = f"{rec.get('kind', 'train')}:{rec.get('algo') or '?'}:{rec.get('env') or '?'}:{topo}"
-    # loop variants (fused_rollout, overlap_collection, floor stages) have
+    # loop variants (fused_rollout, overlap_collection) have
     # their own throughput regime — gate them against their own history
     variant = rec.get("variant")
     if variant:
@@ -489,18 +444,6 @@ def self_test() -> int:
         return r
 
     records += [p2_rec(1), p2_rec(2), p2_rec(3)]
-    # ISSUE-14 MFU floor: TPU mfu cells carry an absolute >=0.30 bar that
-    # fires even on a first record; CPU virtual-mesh cells are never floored
-    records += [
-        rec(1, "dreamer_v3", None, env="mfu_probe", backend="tpu", variant="mfu", mfu=0.36),
-        rec(2, "dreamer_v3", None, env="mfu_probe", backend="tpu", variant="mfu", mfu=0.35),
-        rec(3, "dreamer_v3", None, env="mfu_probe", backend="tpu", variant="mfu", mfu=0.37),
-        rec(1, "dreamer_v3", None, env="mfu_probe_xl", backend="tpu", variant="mfu", mfu=0.12),
-        rec(1, "dreamer_v3", None, env="mfu_probe", variant="mfu", mfu=0.0),
-        rec(2, "dreamer_v3", None, env="mfu_probe", variant="mfu", mfu=0.0),
-        rec(3, "dreamer_v3", None, env="mfu_probe", variant="mfu", mfu=0.0),
-    ]
-
     # ISSUE-19 scenario-sweep floor: the batched domain-randomization cell
     # carries an absolute 100k aggregate-sps bar on EVERY backend (the bar
     # was set on a single-core CPU host), firing even on a first record
@@ -563,15 +506,6 @@ def self_test() -> int:
         or "qps@p95" not in (fleet_cell.get("metrics") or {})
     ):
         failures.append(f"fleet serve cell: want 3-run pass cell gating qps@p95, got {fleet_cell}")
-    tpu_ok = doc["cells"].get("train:dreamer_v3:mfu_probe:tpux1p1:mfu")
-    if tpu_ok is None or tpu_ok["verdict"] != "pass" or tpu_ok["metrics"]["mfu"].get("floor") != 0.30:
-        failures.append(f"mfu floor: want passing TPU cell carrying floor=0.3, got {tpu_ok}")
-    tpu_low = doc["cells"].get("train:dreamer_v3:mfu_probe_xl:tpux1p1:mfu")
-    if tpu_low is None or tpu_low["verdict"] != "regress":
-        failures.append(f"mfu floor: a 12% TPU probe must regress even with no history, got {tpu_low}")
-    cpu_mfu = doc["cells"].get("train:dreamer_v3:mfu_probe:cpux1p1:mfu")
-    if cpu_mfu is None or cpu_mfu["verdict"] != "pass" or "floor" in cpu_mfu["metrics"]["mfu"]:
-        failures.append(f"mfu floor: CPU virtual-mesh cell must not be floored, got {cpu_mfu}")
     sweep_ok = doc["cells"].get("train:ppo:scenario_sweep:cpux1p1:fused_scenarios")
     if (
         sweep_ok is None
@@ -609,7 +543,6 @@ def self_test() -> int:
         r
         for r in records
         if r["algo"] != "sac"
-        and r.get("env") != "mfu_probe_xl"
         and r.get("env") != "linear_feedback_flat"
         and not (r.get("env") == "scenario_sweep" and r.get("backend") == "fake")
     ]
@@ -663,18 +596,14 @@ def run_gate(
     runs_path: str,
     out_path: Optional[str] = None,
     *,
-    bench_pattern: Optional[str] = None,
     tol: float = DEFAULT_TOL,
     window: int = DEFAULT_WINDOW,
     min_history: int = DEFAULT_MIN_HISTORY,
     quiet: bool = False,
 ) -> int:
     """Load → evaluate → write grid → render. Returns the process exit code
-    (``1`` on any regressed cell). The shared entry for the CLI here and
-    ``bench.py --regress``."""
+    (``1`` on any regressed cell)."""
     records = read_records(runs_path)
-    if bench_pattern:
-        records += bench_records(bench_pattern)
     doc = evaluate(records, tol=tol, window=window, min_history=min_history)
     if out_path:
         write_scenarios(doc, out_path)
@@ -687,7 +616,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--runs", default="RUNS.jsonl", help="run-registry JSONL (default: ./RUNS.jsonl)")
     parser.add_argument("--out", default="SCENARIOS.json", help="verdict-grid output (default: ./SCENARIOS.json)")
-    parser.add_argument("--bench", metavar="GLOB", help="also fold driver bench records, e.g. 'BENCH_r*.json'")
     parser.add_argument("--tol", type=float, default=DEFAULT_TOL, help="relative tolerance band (default 0.2)")
     parser.add_argument("--window", type=int, default=DEFAULT_WINDOW, help="baseline history window (default 5)")
     parser.add_argument(
@@ -701,7 +629,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     return run_gate(
         args.runs,
         args.out,
-        bench_pattern=args.bench,
         tol=args.tol,
         window=args.window,
         min_history=args.min_history,

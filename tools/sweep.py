@@ -21,15 +21,13 @@ drained through budget tiers:
     scenarios are first-class cells.
 ``chip``
     Cells whose recipes need a real accelerator (pixel Dreamer learning,
-    XL scenario-matrix sweeps) are NOT run here: they are written into
-    ``benchmarks/QUEUE.json`` as workloads that require the ``tpu`` backend;
-    ``bench.py --queue drain`` runs them there and skips them anywhere else.
+    XL scenario-matrix sweeps) are NOT run here: each keeps the verdict
+    ``deferred_chip`` and carries the command to run on a TPU.
 
 Executed verdicts land in SCENARIOS.json as ``executed_cells`` /
 ``executed_summary`` — next to (never replacing) the static ``config_cells``
 — and ``tools/regress.py`` carries both sections through its rewrites
-(PRESERVED_KEYS). ``bench.py --sweep`` drives this module; ``bench.py
---sweep-stats`` summarizes the executed section.
+(PRESERVED_KEYS). ``--stats`` summarizes the executed section.
 
 Sweep knobs (the ``sweep.*`` surface):
 
@@ -37,7 +35,6 @@ Sweep knobs (the ``sweep.*`` surface):
 ``--max-tier T``     stop the ladder at ``smoke`` or ``learn``
 ``--budget-s S``     wall-clock budget; cells past it report ``skipped_budget``
 ``--scenarios-out``  the verdict-grid file to fold ``executed_cells`` into
-``--queue``          the chip-deferral queue file (benchmarks/QUEUE.json)
 ``--keep-logs DIR``  retain per-cell run dirs (default: tmpdir, deleted)
 ``--list``           print the grid (key, tier, bars) without running
 
@@ -45,7 +42,7 @@ Usage::
 
     python tools/sweep.py --list
     python tools/sweep.py --only 'sweep:ppo:*'
-    python bench.py --sweep
+    python tools/sweep.py --stats
 """
 
 from __future__ import annotations
@@ -64,7 +61,6 @@ from typing import Any, Dict, List, Optional
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_SCENARIOS = os.path.join(REPO_ROOT, "SCENARIOS.json")
-DEFAULT_QUEUE = os.path.join(REPO_ROOT, "benchmarks", "QUEUE.json")
 
 # ------------------------------------------------------------------ grid ----
 
@@ -263,67 +259,50 @@ def build_grid() -> List[Dict[str, Any]]:
 
 def chip_deferrals() -> List[Dict[str, Any]]:
     """Chip-tier cells: full-resolution pixel Dreamer learning checks and the
-    XL scenario-matrix sweep. Never run here — merged into benchmarks/
-    QUEUE.json as standing workloads for `bench.py --queue drain`."""
+    XL scenario-matrix sweep. Never run here — each carries the command
+    (from the repo root, on a TPU) that would produce its verdict."""
 
     def dv3_pixel(env_cfg: str, scenario: str) -> Dict[str, Any]:
         # `:tpu` keeps the deferral distinct from the CPU smoke cell over the
-        # same scenario
+        # same scenario. A Dreamer-V3 learning check over the jittable env
+        # (the pixel_catcher recipe of benchmarks/learning_checks.sh pointed
+        # at the dependency-free pixel family); verdict = first-fifth vs
+        # last-fifth of the Rank-0 reward lines
         return {
             "key": f"sweep:dreamer_v3:{scenario}:tpu",
             "tier": "chip",
-            "queue_entry": {
-                "id": f"sweep_dv3_{env_cfg}",
-                "requires": "tpu",
-                "timeout_s": 5400,
-                "argv": [
-                    "-m", "sheeprl_tpu", f"exp=dreamer_v3", f"env={env_cfg}",
-                    "env.num_envs=4", "env.capture_video=False",
-                    "buffer.memmap=False", "buffer.size=60000",
-                    "algo.total_steps=30720", "algo.learning_starts=1024",
-                    "algo.replay_ratio=0.5", "algo.dense_units=128", "algo.mlp_layers=1",
-                    "algo.world_model.discrete_size=16", "algo.world_model.stochastic_size=16",
-                    "algo.world_model.encoder.cnn_channels_multiplier=8",
-                    "algo.world_model.recurrent_model.recurrent_state_size=128",
-                    "algo.world_model.transition_model.hidden_size=128",
-                    "algo.world_model.representation_model.hidden_size=128",
-                    "algo.cnn_keys.encoder=[rgb]", "algo.mlp_keys.encoder=[]",
-                    "algo.run_test=False", "checkpoint.every=10000000",
-                    "checkpoint.save_last=False", "metric.log_level=1",
-                    "metric.log_every=4000",
-                ],
-                "note": (
-                    "ISSUE 19 sweep chip tier: Dreamer-V3 learning check over the "
-                    f"jittable {env_cfg} (the pixel_catcher recipe from "
-                    "benchmarks/learning_checks.sh pointed at the dependency-free "
-                    "pixel family); verdict = first-fifth vs last-fifth of the "
-                    "Rank-0 reward lines"
-                ),
-            },
+            "command": [
+                "python", "-m", "sheeprl_tpu", "exp=dreamer_v3", f"env={env_cfg}",
+                "env.num_envs=4", "env.capture_video=False",
+                "buffer.memmap=False", "buffer.size=60000",
+                "algo.total_steps=30720", "algo.learning_starts=1024",
+                "algo.replay_ratio=0.5", "algo.dense_units=128", "algo.mlp_layers=1",
+                "algo.world_model.discrete_size=16", "algo.world_model.stochastic_size=16",
+                "algo.world_model.encoder.cnn_channels_multiplier=8",
+                "algo.world_model.recurrent_model.recurrent_state_size=128",
+                "algo.world_model.transition_model.hidden_size=128",
+                "algo.world_model.representation_model.hidden_size=128",
+                "algo.cnn_keys.encoder=[rgb]", "algo.mlp_keys.encoder=[]",
+                "algo.run_test=False", "checkpoint.every=10000000",
+                "checkpoint.save_last=False", "metric.log_level=1",
+                "metric.log_every=4000",
+            ],
         }
 
     return [
         dv3_pixel("pixel_pointmass", "PixelPointmass-v0"),
         dv3_pixel("pixel_pendulum", "PixelPendulum-v0"),
+        # the batched domain-randomization superstep at 65536 scenario
+        # instances; --record appends train:ppo:scenario_sweep:tpu* cells
+        # gated by the 100k sps_env floor in tools/regress.py
         {
             "key": "sweep:ppo:scenario_sweep_xl:tpu",
             "tier": "chip",
-            "queue_entry": {
-                "id": "sweep_scenario_xl",
-                "requires": "tpu",
-                "timeout_s": 1800,
-                "argv": [
-                    "benchmarks/scenario_sweep.py", "--envs", "65536",
-                    "--rollout-steps", "64", "--updates", "10",
-                    "--repeats", "3", "--record",
-                ],
-                "note": (
-                    "ISSUE 19 sweep chip tier: the batched domain-randomization "
-                    "superstep at 65536 scenario instances; --record appends "
-                    "train:ppo:scenario_sweep:tpu* cells gated by the 100k "
-                    "sps_env floor in tools/regress.py"
-                ),
-            },
+            "command": [
+                "python", "benchmarks/scenario_sweep.py", "--envs", "65536",
+                "--rollout-steps", "64", "--updates", "10",
+                "--repeats", "3", "--record",
+            ],
         },
     ]
 
@@ -418,31 +397,6 @@ def run_cell(cell: Dict[str, Any], work_dir: str) -> Dict[str, Any]:
     return result
 
 
-def defer_chip_cells(cells: List[Dict[str, Any]], queue_path: str) -> List[str]:
-    """Merge chip-tier queue entries into benchmarks/QUEUE.json (dedup by id,
-    standing entries are never rewritten). Returns newly added ids."""
-    try:
-        with open(queue_path) as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        doc = {"schema": 1, "entries": []}
-    entries = doc.setdefault("entries", [])
-    have = {e.get("id") for e in entries if isinstance(e, dict)}
-    added = []
-    for cell in cells:
-        entry = cell["queue_entry"]
-        if entry["id"] not in have:
-            entries.append(entry)
-            added.append(entry["id"])
-    if added:
-        tmp = queue_path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=1)
-            f.write("\n")
-        os.replace(tmp, queue_path)
-    return added
-
-
 # ------------------------------------------------------------------ output ----
 
 
@@ -467,7 +421,7 @@ def fold_executed(
         cells[cell["key"]] = {
             "tier": "chip",
             "verdict": "deferred_chip",
-            "queue_id": cell["queue_entry"]["id"],
+            "command": cell["command"],
         }
     doc["executed_cells"] = dict(sorted(cells.items()))
     counts: Dict[str, int] = {}
@@ -487,7 +441,7 @@ def fold_executed(
 
 
 def stats(scenarios_path: str) -> Dict[str, Any]:
-    """`bench.py --sweep-stats`: tier reached, verdict and sps per executed
+    """``--stats``: tier reached, verdict and sps per executed
     cell, plus the rollup — read-only over SCENARIOS.json."""
     try:
         with open(scenarios_path) as f:
@@ -498,7 +452,7 @@ def stats(scenarios_path: str) -> Dict[str, Any]:
     rows = []
     for key, c in sorted(cells.items()):
         row = {"cell": key, "tier": c.get("tier"), "verdict": c.get("verdict")}
-        for k in ("sps_env", "rew_first_fifth", "rew_last_fifth", "episodes", "wall_s", "queue_id"):
+        for k in ("sps_env", "rew_first_fifth", "rew_last_fifth", "episodes", "wall_s", "command"):
             if c.get(k) is not None:
                 row[k] = c[k]
         rows.append(row)
@@ -519,7 +473,6 @@ def stats(scenarios_path: str) -> Dict[str, Any]:
 def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--scenarios-out", default=DEFAULT_SCENARIOS, help="verdict-grid file")
-    parser.add_argument("--queue", default=DEFAULT_QUEUE, help="chip-deferral queue file")
     parser.add_argument("--only", metavar="GLOB", help="run only matching cell keys")
     parser.add_argument(
         "--max-tier", choices=("smoke", "learn"), default="learn",
@@ -579,14 +532,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         if res["verdict"].endswith("_fail") and res.get("log_tail"):
             print("  " + "\n  ".join(res["log_tail"].splitlines()[-6:]), flush=True)
 
-    added = defer_chip_cells(chip, args.queue)
     summary = fold_executed(results, chip, args.scenarios_out)
     if not args.keep_logs:
         shutil.rmtree(work_dir, ignore_errors=True)
     print(
         f"# {summary['cells']} executed cells -> {args.scenarios_out} "
-        f"{json.dumps(summary['verdicts'])}; chip deferrals "
-        f"{'added ' + ','.join(added) if added else 'already queued'} -> {args.queue}",
+        f"{json.dumps(summary['verdicts'])}",
         flush=True,
     )
     return 1 if failed else 0

@@ -8,7 +8,7 @@ write standalone flush-per-event ``trace.actor<i>.jsonl`` files. The run's
 full file set is recorded in its RUNS.jsonl record (``telemetry_files``), so
 no globbing is needed to find them.
 
-This module is the read side, pure stdlib (the jax-free ``bench.py`` parent
+This module is the read side, pure stdlib (``tools/report.py --trace``
 loads it by file path):
 
 - **clock alignment** — each stream's handshake carries ``clock_offset =
