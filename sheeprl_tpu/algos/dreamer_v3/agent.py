@@ -858,7 +858,12 @@ class PlayerDV3(HostPlayerParams):
         key: Array,
         greedy: bool = False,
         mask: Optional[Dict[str, Array]] = None,
+        fetch: bool = True,
     ) -> Array:
+        """One observe+act step. ``fetch=False`` is for a caller that queues
+        the forward ahead of the turn that needs it: the step is dispatched,
+        the action's copy to the host started, and the device array returned
+        for the caller to read (``np.asarray``) when that turn comes."""
         self.poll_stream_attrs()
         # keys minted on another backend would clash with host-pinned params
         # (committed-device mismatch) — re-place; identity when aligned
@@ -875,6 +880,9 @@ class PlayerDV3(HostPlayerParams):
             )
         # recurrent state stays on device; only the action crosses PCIe
         self.actions, self.h, self.z = action, h, z
+        if not fetch:
+            action.copy_to_host_async()
+            return action
         return np.asarray(jax.device_get(action))
 
 

@@ -23,7 +23,7 @@ The redesign (SURVEY.md §7 hard parts, all addressed here):
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import gymnasium as gym
 import jax
@@ -484,6 +484,17 @@ def make_fused_train_fn(
     )
 
 
+class QueuedForward(NamedTuple):
+    """The next turn's player forward, dispatched behind this turn's train
+    steps; what a redo after a rollback needs is kept beside the action."""
+
+    action: Any  # on the device, its copy to the host started
+    player_key: Any  # the player's key once that turn has split it
+    action_key: Any
+    state_before: Tuple[Any, Any, Any]  # the player's h / z / actions the forward started from
+    obs: Dict[str, np.ndarray]
+
+
 @jax.jit
 def dv3_target_ema(cp, tcp, tau):
     """EMA update for the target critic (reference dreamer_v3.py:670-675);
@@ -800,6 +811,7 @@ def main(fabric, cfg: Dict[str, Any]):
         # ever holds observations, which a NaN train step cannot poison
         nonlocal wm_params, actor_params, critic_params, target_critic_params
         nonlocal world_opt, actor_opt, critic_opt, moments_state, key
+        nonlocal queued_forward, queued_window
         restored = resil.rollback(update=at_update)
         wm_params = resil.place_like(restored["world_model"], wm_params)
         actor_params = resil.place_like(restored["actor"], actor_params)
@@ -818,6 +830,38 @@ def main(fabric, cfg: Dict[str, Any]):
         key = resil.resalt_key(key)
         pending_metrics.clear()  # the poisoned window must not reach the logger
         player.update_params(wm_params, actor_params)
+        queued_window = None  # made from the poisoned critic and the key that diverged
+        if queued_forward is not None:
+            # the next turn's forward ran on the poisoned weights: made again
+            # on the restored ones, from the state and with the key it had
+            q = queued_forward
+            player.h, player.z, player.actions = q.state_before
+            queued_forward = q._replace(action=dispatch_forward(q.obs, q.action_key))
+            prequeue["forwards_redone"] += 1
+
+    def dispatch_forward(prepared_obs: Dict[str, np.ndarray], action_key: Any) -> Any:
+        mask = {k: v for k, v in prepared_obs.items() if k.startswith("mask")}
+        return player.get_actions(prepared_obs, action_key, mask=mask or None, fetch=False)
+
+    def queue_forward(prepared_obs: Dict[str, np.ndarray], ahead: bool = True) -> None:
+        # ``player_key`` itself moves on when the turn takes the action: a
+        # checkpoint written before then holds the key of before this split
+        nonlocal queued_forward
+        next_player_key, action_key = jax.random.split(player_key)
+        state_before = (player.h, player.z, player.actions)
+        action = dispatch_forward(prepared_obs, action_key)
+        queued_forward = QueuedForward(action, next_player_key, action_key, state_before, prepared_obs)
+        prequeue["forwards_queued"] += ahead
+
+    def target_and_keys() -> Tuple[Any, Any, Any, int]:
+        # what a gradient step of the per-step path takes beside its batch: the
+        # target critic, refreshed when the step count says so, and the split key
+        target, ema_dispatches = target_critic_params, 0
+        if cumulative_per_rank_gradient_steps % cfg.algo.critic.per_rank_target_network_update_freq == 0:
+            tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else float(cfg.algo.critic.tau)
+            target, ema_dispatches = dv3_target_ema(critic_params, target, tau), 1
+        next_key, train_key = jax.random.split(key)
+        return target, next_key, train_key, ema_dispatches
 
     # a crash anywhere in the loop gets the preemption treatment too: the
     # lambdas read the loop's CURRENT policy_step/update at crash time
@@ -829,8 +873,23 @@ def main(fabric, cfg: Dict[str, Any]):
     preempted = False
     cumulative_per_rank_gradient_steps = 0
     pending_metrics: list = []  # device-resident metric vectors, fetched at log time
-    # the loop never blocks on the accelerator; the fence keeps it at most a
-    # few train blocks ahead so the dispatch/transfer queues stay bounded
+    # The host waits for the accelerator in three places a turn: the action's
+    # fetch, ``train/block`` (only while timers are on) and the finite check's
+    # fetch. Before the last two it queues what the next turn needs and it can
+    # already issue, so that runs directly behind the train steps:
+    queued_forward: Optional[QueuedForward] = None  # the next turn's player forward
+    # the next window's first target critic and key split: (target, key, train_key, EMA dispatches)
+    queued_window: Optional[Tuple[Any, Any, Any, int]] = None
+    prequeue = {"turns": 0, "forwards_queued": 0, "forwards_landed": 0, "forwards_redone": 0}
+
+    def report_prequeue() -> None:
+        # counts since the last report; in steady state queued = turns, redone = 0
+        if prequeue["turns"]:
+            telemetry_counters("dv3/prequeue", **prequeue)
+            prequeue.update(dict.fromkeys(prequeue, 0))
+
+    # with the waits off the fence keeps the host at most a few train blocks
+    # ahead, so the dispatch/transfer queues stay bounded
     from sheeprl_tpu.parallel.fabric import DispatchFence
 
     fence = DispatchFence(depth=int(cfg.algo.get("dispatch_fence_depth", 4) or 4))
@@ -865,12 +924,16 @@ def main(fabric, cfg: Dict[str, Any]):
                         axis=-1,
                     )
             else:
-                player_key, action_key = jax.random.split(player_key)
-                prepared = prepare_obs(obs, cnn_keys=cnn_keys, num_envs=num_envs)
-                mask = {k: v for k, v in prepared.items() if k.startswith("mask")}
+                if queued_forward is None:
+                    # the first turn on the policy, or the first after a resume
+                    queue_forward(prepare_obs(obs, cnn_keys=cnn_keys, num_envs=num_envs), ahead=False)
+                pending, player_key = queued_forward.action, queued_forward.player_key
+                queued_forward = None
+                prequeue["turns"] += 1
+                prequeue["forwards_landed"] += bool(pending.is_ready())
                 with timer("player/get_actions"):
-                    # player dispatch and the action's device_get: the turn's one sync
-                    actions = player.get_actions(prepared, action_key, mask=mask or None)
+                    # the fetch of a forward that the last turn queued behind its train steps
+                    actions = np.asarray(pending)
                 if is_continuous:
                     real_actions = actions
                 else:
@@ -961,15 +1024,21 @@ def main(fabric, cfg: Dict[str, Any]):
                 step_data["is_first"][:, dones_idxes] = 1.0
                 player.init_states(dones_idxes)
 
+        # the turn after this one acts on the policy, if there is one: all its
+        # forward needs is on the host (``prepared_next``) or queued (the weights)
+        acts_next = update < num_updates and (
+            update >= learning_starts or cfg.checkpoint.resume_from is not None
+        )
+
         # ---------------- training ---------------- #
-        if update >= learning_starts:
-            per_rank_gradient_steps = ratio(policy_step / num_processes)
-            if per_rank_gradient_steps > 0 and fused_k > 0:
-                # fused path: the whole window is ceil(G / K) superstep
-                # dispatches — gather + EMA + train scanned inside XLA
-                window_dispatches = 0
-                window_finite: list = []  # [chunk] bool vectors, one per dispatch
-                with timer("Time/train_time"):
+        per_rank_gradient_steps = ratio(policy_step / num_processes) if update >= learning_starts else 0
+        if per_rank_gradient_steps > 0:
+            window_finite: list = []  # fused path: [chunk] bool vectors, one per dispatch
+            with timer("Time/train_time"):
+                if fused_k > 0:
+                    # fused path: the whole window is ceil(G / K) superstep
+                    # dispatches — gather + EMA + train scanned inside XLA
+                    window_dispatches = 0
                     n_left = per_rank_gradient_steps
                     while n_left > 0:
                         chunk = min(fused_k, n_left)
@@ -1007,45 +1076,24 @@ def main(fabric, cfg: Dict[str, Any]):
                             # [chunk, len(METRIC_ORDER)] on device, one fetch
                             # per log interval for the whole window
                             pending_metrics.append(metrics)
-                    if not timer.disabled:
-                        with timer("train/block"):
-                            jax.block_until_ready(wm_params)
-                    train_step += num_processes
-                telemetry_train_window(window_dispatches, per_rank_gradient_steps)
-                player.update_params(wm_params, actor_params)
-                fence.push(metrics)
-                # one tiny fetch per window: the [chunk] finite vectors the
-                # superstep computed in-dispatch, reduced on the host
-                if not resil.window_ok(
-                    all(bool(np.all(np.asarray(jax.device_get(f)))) for f in window_finite),
-                    update,
-                ):
-                    nan_rollback(update)
-                    continue
-            elif per_rank_gradient_steps > 0:
-                # each process samples its share of the global batch
-                # batch i+1's host->HBM transfer overlaps gradient step i
-                batches = sampled_batches(
-                    rb,
-                    per_rank_batch_size * fabric.local_data_parallel_size,
-                    sequence_length,
-                    per_rank_gradient_steps,
-                    cnn_keys,
-                    fabric,
-                    prefetch=int(cfg.buffer.get("prefetch", 0) or 0),
-                )
-                window_ema_dispatches = 0
-                with timer("Time/train_time"):
-                    for i, batch in enumerate(batches):
-                        if (
-                            cumulative_per_rank_gradient_steps
-                            % cfg.algo.critic.per_rank_target_network_update_freq
-                            == 0
-                        ):
-                            tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else float(cfg.algo.critic.tau)
-                            target_critic_params = dv3_target_ema(critic_params, target_critic_params, tau)
-                            window_ema_dispatches += 1
-                        key, train_key = jax.random.split(key)
+                else:
+                    # each process samples its share of the global batch
+                    # batch i+1's host->HBM transfer overlaps gradient step i
+                    batches = sampled_batches(
+                        rb,
+                        per_rank_batch_size * fabric.local_data_parallel_size,
+                        sequence_length,
+                        per_rank_gradient_steps,
+                        cnn_keys,
+                        fabric,
+                        prefetch=int(cfg.buffer.get("prefetch", 0) or 0),
+                    )
+                    window_ema_dispatches = 0
+                    for batch in batches:
+                        # the window's first come from the end of the last window
+                        target_critic_params, key, train_key, ema_dispatches = queued_window or target_and_keys()
+                        queued_window = None
+                        window_ema_dispatches += ema_dispatches
                         with timer("train/dispatch"):
                             (
                                 wm_params,
@@ -1084,37 +1132,53 @@ def main(fabric, cfg: Dict[str, Any]):
                                 batch,
                                 train_key,
                             )
-                    if not timer.disabled:
-                        # only when timing: wait so Time/train_time measures
-                        # the chip, not the async dispatch. What the wait is
-                        # for is whatever the device still has queued: the
-                        # turn's ring write and the gathers as well as the
-                        # train steps (``train/block`` is the host's wait)
-                        with timer("train/block"):
-                            jax.block_until_ready(wm_params)
-                    train_step += num_processes
-                # per-step dispatch shape: one train call per gradient step,
-                # plus the on-device gather per batch and the EMA refreshes
-                telemetry_train_window(
-                    per_rank_gradient_steps * (2 if use_device_rb else 1) + window_ema_dispatches,
-                    per_rank_gradient_steps,
-                )
+                    # per-step dispatch shape: one train call per gradient step,
+                    # plus the on-device gather per batch and the EMA refreshes
+                    window_dispatches = (
+                        per_rank_gradient_steps * (2 if use_device_rb else 1) + window_ema_dispatches
+                    )
+                    if cfg.metric.log_level > 0:
+                        # keep the metric vector ON DEVICE: fetching here would
+                        # serialize the async train dispatch against the host
+                        # loop (one device round trip per train block); the queue
+                        # drains at log time instead
+                        pending_metrics.append(metrics)
+                # behind the train steps and before the host waits for them:
+                # the next turn's forward, then the next window's first target
+                # refresh and key split, which depend on nothing newer either
                 player.update_params(wm_params, actor_params)
+                if acts_next:
+                    queue_forward(prepared_next)
+                if fused_k == 0:
+                    queued_window = target_and_keys()
                 fence.push(metrics)
-                if cfg.metric.log_level > 0:
-                    # keep the metric vector ON DEVICE: fetching here would
-                    # serialize the async train dispatch against the host
-                    # loop (one device round trip per train block); the queue
-                    # drains at log time instead
-                    pending_metrics.append(metrics)
-                if resil.finite_checks and not resil.check_finite(
-                    # the window's LAST metric vector: NaNs in params propagate
-                    # to every later loss, so one fetch per window suffices
-                    np.asarray(jax.device_get(metrics)),
-                    update,
-                ):
-                    nan_rollback(update)
-                    continue
+                telemetry_train_window(window_dispatches, per_rank_gradient_steps)
+                if not timer.disabled:
+                    # only when timing: wait so Time/train_time measures
+                    # the chip, not the async dispatch. What the wait is
+                    # for is whatever the device still has queued: the
+                    # turn's ring write and the gathers as well as the
+                    # train steps (``train/block`` is the host's wait)
+                    with timer("train/block"):
+                        jax.block_until_ready(wm_params)
+                train_step += num_processes
+            if fused_k > 0:
+                # one tiny fetch per window: the [chunk] finite vectors the
+                # superstep computed in-dispatch, reduced on the host
+                window_ok = resil.window_ok(
+                    all(bool(np.all(np.asarray(jax.device_get(f)))) for f in window_finite), update
+                )
+            else:
+                # the window's LAST metric vector: NaNs in params propagate
+                # to every later loss, so one fetch per window suffices
+                window_ok = not resil.finite_checks or resil.check_finite(
+                    np.asarray(jax.device_get(metrics)), update
+                )
+            if not window_ok:
+                nan_rollback(update)
+                continue
+        elif acts_next:
+            queue_forward(prepared_next)
 
         if cumulative_per_rank_gradient_steps > 0 and not placement_recorded:
             # once, after the first train window: where the state that the
@@ -1162,6 +1226,7 @@ def main(fabric, cfg: Dict[str, Any]):
             last_log = policy_step
             last_train = train_step
             last_grad_steps = cumulative_per_rank_gradient_steps
+            report_prequeue()
 
         # ---------------- checkpoint ---------------- #
         if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
@@ -1178,6 +1243,7 @@ def main(fabric, cfg: Dict[str, Any]):
     # drain materializes the newest fence marker too: the device has
     # finished every queued train dispatch before the closing bookkeeping
     fence.drain()
+    report_prequeue()
 
     # land any in-flight async param stream so the final evaluation and
     # model registration use the last update's weights
