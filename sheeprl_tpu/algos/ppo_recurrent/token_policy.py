@@ -1,17 +1,19 @@
 """The decoder core of recurrent PPO: a policy over tokens.
 
 ``ppo_recurrent``'s sequence machinery with another thing carried from step to
-step. Where the LSTM core carries ``(hx, cx)`` per env, this core carries a
-per-layer latent cache and its length per env (``models/seqpol.py``): one env
+step. Where the LSTM core carries ``(hx, cx)`` per env, this core carries, for
+each layer, the state its operator declares (``models/seqpol.py``
+``state_shapes``: a cache of positions for an attention layer, the last few
+gated inputs for a convolution layer) and one length per env: one env
 step is one token, the reset observation carries the prompt, and the update
 runs teacher-forced over each sequence of the rollout. A sequence that
-continues an episode begun before the rollout attends to the cache **as it
+continues an episode begun before the rollout starts from that state **as it
 stood at the rollout's start** (the snapshot: what ``prev_hx/prev_cx`` are to
 the LSTM), as constants without gradient.
 
 What a sequence core supplies, for either kind:
 
-- the initial carry for ``E`` rows (an empty cache, :class:`TokenPlayer`; zeros for the LSTM);
+- the initial carry for ``E`` rows (an empty state, :class:`TokenPlayer`; zeros for the LSTM);
 - one rollout step (:meth:`TokenPlayer.act`; ``RecurrentPPOPlayer.rollout_actions``);
 - the evaluation of padded sequences given each sequence's starting carry
   (:func:`token_loss`; ``evaluate_actions``), whose losses the masks of
@@ -19,7 +21,7 @@ What a sequence core supplies, for either kind:
 
 Three named programs: ``seqpol_prefill`` (the prompts of the rows that reset,
 ``prefill_rows`` at a time, so its cost follows the resets and not
-``num_envs``), ``seqpol_decode`` (one token for all rows through the cache:
+``num_envs``), ``seqpol_decode`` (one token for all rows through the state:
 sampling, log-probability, value) and ``seqpol_train_step`` (one gradient step
 on one minibatch of ``per_rank_batch_size`` sequences; an update dispatches as
 many as its rollout's sequences fill, ``update_epochs`` times). Every shape is
@@ -89,40 +91,40 @@ def build_token_agent(fabric: Any, cfg: Dict[str, Any], obs_space: Any, action_s
 def make_player_programs(agent: TokenPolicy) -> Dict[str, Any]:
     """The player's jitted programs, each under its own name: ``cast`` (the
     parameters in the compute dtype), ``prefill``, ``decode`` and ``snapshot``.
-    The two that write the cache take it donated."""
+    The two that write the state take it donated."""
     core, dtype = agent.core, agent.dtype
 
     def seqpol_player_params(p):
         return seqpol.low_precision(p, dtype)
 
-    def seqpol_prefill(p, cache_c, cache_r, rows, tokens, n_prefix):
+    def seqpol_prefill(p, state, rows, tokens, n_prefix):
         slots = jnp.arange(tokens.shape[1])[None, :]
-        _, (c, r), counters = seqpol.forward_sequence(p, core, tokens, jnp.broadcast_to(slots, tokens.shape), slots < n_prefix[:, None], dtype=dtype)
-        width = tokens.shape[1]
-        # a row index past the last env marks an unused slot of this call: its write is dropped
-        cache_c = tuple(layer.at[rows, :width].set(c[i].astype(layer.dtype), mode="drop") for i, layer in enumerate(cache_c))
-        cache_r = tuple(layer.at[rows, :width].set(r[i].astype(layer.dtype), mode="drop") for i, layer in enumerate(cache_r))
-        return cache_c, cache_r, counters
+        _, own, counters = seqpol.forward_sequence(p, core, tokens, jnp.broadcast_to(slots, tokens.shape), slots < n_prefix[:, None], dtype=dtype)
+        # each array of a layer's state takes the rows' own entries from its first position on: a cache the prompt's
+        # slots, a convolution state all it holds. A row index past the last env marks an unused slot of this call:
+        # its write is dropped
+        state = jax.tree.map(lambda held, new: held.at[rows, : new.shape[1]].set(new.astype(held.dtype), mode="drop"), state, own)
+        return state, counters
 
-    def seqpol_decode(p, cache_c, cache_r, tokens, positions, key, counter):
-        h, cache_c, cache_r, counters = seqpol.decode_step(p, core, tokens, positions, cache_c, cache_r, dtype=dtype)
+    def seqpol_decode(p, state, tokens, positions, key, counter):
+        h, state, counters = seqpol.decode_step(p, core, tokens, positions, state, dtype=dtype)
         logits, values = seqpol.heads(p, core, h)
         with jax.named_scope("seqpol/head"):
             actions = jax.random.categorical(jax.random.fold_in(key, counter), logits, axis=-1)
             logprobs = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1), actions[:, None], axis=-1)[:, 0]
-        return actions.astype(jnp.int32), logprobs, values, logits, cache_c, cache_r, counters
+        return actions.astype(jnp.int32), logprobs, values, logits, state, counters
 
-    def seqpol_snapshot(cache_c, cache_r):
-        return jax.tree.map(jnp.copy, (cache_c, cache_r))
+    def seqpol_snapshot(state):
+        return jax.tree.map(jnp.copy, state)
 
-    return {"cast": jax.jit(seqpol_player_params), "prefill": jax.jit(seqpol_prefill, donate_argnums=(1, 2)),
-            "decode": jax.jit(seqpol_decode, donate_argnums=(1, 2)), "snapshot": jax.jit(seqpol_snapshot)}  # fmt: skip
+    return {"cast": jax.jit(seqpol_player_params), "prefill": jax.jit(seqpol_prefill, donate_argnums=(1,)),
+            "decode": jax.jit(seqpol_decode, donate_argnums=(1,)), "snapshot": jax.jit(seqpol_snapshot)}  # fmt: skip
 
 
 class TokenPlayer:
-    """The rollout's side: the parameters in the compute dtype, the latent
-    cache on the device (``cache_c`` and ``cache_r``: one ``[E, context,
-    width]`` array a layer) and each row's length on the host."""
+    """The rollout's side: the parameters in the compute dtype, the state on
+    the device (``state[i]``: the arrays layer ``i``'s operator declares, each
+    ``[E, ...]``) and each row's length on the host."""
 
     def __init__(self, agent: TokenPolicy, params: Any, num_envs: int, prefill_rows: int) -> None:
         core, dtype = agent.core, agent.dtype
@@ -133,39 +135,45 @@ class TokenPlayer:
         # placed like the parameters: the first snapshot then has the sharding of every later one, and the
         # train step's second call finds its first's program
         placed = jax.tree.leaves(self.params)[0].sharding
-
-        def empty(width: int) -> Tuple[Array, ...]:
-            return tuple(jax.device_put(jnp.zeros((self.num_envs, core.context, width), dtype), placed) for _ in range(core.num_hidden_layers))
-
-        self.cache_c, self.cache_r = empty(core.kv_lora_rank), empty(core.qk_rope_head_dim)
+        self.state = tuple(tuple(jax.device_put(jnp.zeros(shape, dtype), placed) for shape in layer)
+                           for layer in seqpol.state_shapes(core, self.num_envs))  # fmt: skip
         self.lengths = np.zeros((self.num_envs,), np.int32)
         self.last_logits: Optional[Array] = None
-        #: counters since the start: rows prefilled, tokens decoded, cache entries the decodes attended to
+        #: counters since the start: rows prefilled, tokens decoded, cache entries the decodes attended to, rows reset
         self.rows_prefilled = 0
         self.tokens_decoded = 0
         self.cache_positions = 0
+        self.rows_reset = 0
+
+    @property
+    def cache_c(self) -> Tuple[Array, ...]:
+        """The first array of every layer's state (``perfbench``'s recording player asks where it lives)."""
+        return tuple(layer[0] for layer in self.state)
 
     def reset_rows(self, dones: np.ndarray) -> None:
-        """A row whose episode ended starts its next from an empty cache."""
-        self.lengths[np.asarray(dones, bool).reshape(-1)] = 0
+        """A row whose episode ended starts its next from nothing: no entry of
+        either kind of state lies at or after its first position."""
+        dones = np.asarray(dones, bool).reshape(-1)
+        self.lengths[dones] = 0
+        self.rows_reset += int(dones.sum())
 
-    def snapshot(self) -> Tuple[Tuple[Array, ...], Tuple[Array, ...]]:
-        """A copy of the cache as it stands: what a rollout's continuing sequences attend to in the update."""
-        return self._snapshot(self.cache_c, self.cache_r)
+    def snapshot(self) -> Tuple[Tuple[Array, ...], ...]:
+        """A copy of the state as it stands: what a rollout's continuing sequences start from in the update."""
+        return self._snapshot(self.state)
 
     def peek_values(self, obs: Dict[str, np.ndarray]) -> np.ndarray:
         """The value of the observation the rollout stopped at, ``[E, 1]``: the
-        rows that continue take one decode on a throw-away copy of the cache
+        rows that continue take one decode on a throw-away copy of the state
         (their next rollout step writes the same entry for real); a row that
         has just been reset is masked by its ``done``."""
         tokens, n_tokens = np.asarray(obs["tokens"], np.int32), np.asarray(obs["n_tokens"], np.int32).reshape(self.num_envs)
         current = tokens[np.arange(self.num_envs), n_tokens - 1].astype(np.int32)
-        out = self._decode(self.params, *self.snapshot(), current, self.lengths.copy(), jax.random.PRNGKey(0), np.uint32(0))
+        out = self._decode(self.params, self.snapshot(), current, self.lengths.copy(), jax.random.PRNGKey(0), np.uint32(0))
         return np.asarray(out[2], np.float32)[:, None]
 
     def prefill(self, tokens: np.ndarray, n_tokens: np.ndarray) -> None:
         """The rows whose observation holds more than one token (a prompt) get
-        all but its last written into their cache, ``prefill_rows`` a call."""
+        all but its last written into their state, ``prefill_rows`` a call."""
         rows = np.nonzero(n_tokens > 1)[0]
         for at in range(0, len(rows), self.prefill_rows):
             chunk = rows[at : at + self.prefill_rows]
@@ -175,7 +183,7 @@ class TokenPlayer:
             toks[: len(chunk)] = tokens[chunk]
             n_prefix = np.zeros((self.prefill_rows,), np.int32)
             n_prefix[: len(chunk)] = n_tokens[chunk] - 1
-            self.cache_c, self.cache_r, _ = self._prefill(self.params, self.cache_c, self.cache_r, idx, toks, n_prefix)
+            self.state, _ = self._prefill(self.params, self.state, idx, toks, n_prefix)
             self.lengths[chunk] = n_tokens[chunk] - 1
             self.rows_prefilled += len(chunk)
 
@@ -188,8 +196,8 @@ class TokenPlayer:
         with timer("player/decode"):
             current = tokens[np.arange(self.num_envs), n_tokens - 1].astype(np.int32)
             positions = self.lengths.copy()
-            actions, logprobs, values, self.last_logits, self.cache_c, self.cache_r, _ = self._decode(
-                self.params, self.cache_c, self.cache_r, current, positions, key, np.uint32(counter)
+            actions, logprobs, values, self.last_logits, self.state, _ = self._decode(
+                self.params, self.state, current, positions, key, np.uint32(counter)
             )
             self.lengths += 1
             self.tokens_decoded += self.num_envs
@@ -218,7 +226,7 @@ def sequence_layout(batch: Dict[str, Array], prompt_max: int) -> Dict[str, Array
     return {"tokens": tokens, "positions": jnp.maximum(positions, 0), "valid": valid}
 
 
-def token_loss(params: Any, agent: TokenPolicy, batch: Dict[str, Array], snap_c: Array, snap_r: Array, clip_coef, ent_coef, *,
+def token_loss(params: Any, agent: TokenPolicy, batch: Dict[str, Array], snap: Any, clip_coef, ent_coef, *,
                vf_coef: float, mtp_coef: float, clip_vloss: bool = False, normalize_adv: bool = False, reduction: str = "mean",
                remat: bool = True, params_lo: Optional[Any] = None):  # fmt: skip
     """The PPO loss of one minibatch of sequences, its terms and the expert
@@ -226,15 +234,15 @@ def token_loss(params: Any, agent: TokenPolicy, batch: Dict[str, Array], snap_c:
     first step's observation), ``tok_in [B, L]`` (each step's input token),
     ``actions``, ``logprobs``, ``values``, ``returns``, ``advantages``, ``mask``
     ``[B, L]``, and each sequence's starting carry ``len0 [B]``, ``env0 [B]``:
-    the length of, and the row in, the snapshot ``snap_c / snap_r`` (one ``[E, C, .]``
-    array a layer). ``params_lo`` is the player's copy of the same parameters in the
+    the length of, and the row in, the snapshot ``snap`` (the player's state
+    as it stood at the rollout's start, each layer's arrays). ``params_lo`` is the player's copy of the same parameters in the
     compute dtype: the matmuls read it, the gradient goes to ``params``."""
     core = agent.core
     if params_lo is not None:
         params = seqpol.reading_copy(params, params_lo)
     Q = agent.prompt_max
     lay = sequence_layout(batch, Q)
-    ctx = (jax.lax.stop_gradient(snap_c), jax.lax.stop_gradient(snap_r), batch["env0"], batch["len0"])
+    ctx = (jax.lax.stop_gradient(snap), batch["env0"], batch["len0"])
     h, _, counters = seqpol.forward_sequence(params, core, lay["tokens"], lay["positions"], lay["valid"], ctx, dtype=agent.dtype, remat=remat)
     h = h[:, Q:]  # the steps' slots
     B, L, D = h.shape
@@ -271,7 +279,7 @@ def token_loss(params: Any, agent: TokenPolicy, batch: Dict[str, Array], snap_c:
 
 
 def make_token_train_fn(fabric: Any, agent: TokenPolicy, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
-    """``seqpol_train_step(params, opt_state, params_lo, batch, snap_c, snap_r,
+    """``seqpol_train_step(params, opt_state, params_lo, batch, snap,
     clip_coef, ent_coef) -> (params, opt_state, params_lo, metrics)``: one
     gradient step on one minibatch of sequences (:data:`METRICS` names the
     metrics). ``params_lo`` is the player's copy in the compute dtype, read by
@@ -280,9 +288,9 @@ def make_token_train_fn(fabric: Any, agent: TokenPolicy, tx: optax.GradientTrans
     consts = dict(vf_coef=float(cfg.algo.vf_coef), mtp_coef=float(cfg.algo.core.mtp_loss_coef), clip_vloss=bool(cfg.algo.clip_vloss),
                   normalize_adv=bool(cfg.algo.normalize_advantages), reduction=str(cfg.algo.loss_reduction))  # fmt: skip
 
-    def seqpol_train_step(params, opt_state, params_lo, batch, snap_c, snap_r, clip_coef, ent_coef):
+    def seqpol_train_step(params, opt_state, params_lo, batch, snap, clip_coef, ent_coef):
         def loss_fn(p):
-            return token_loss(p, agent, batch, snap_c, snap_r, clip_coef, ent_coef, params_lo=params_lo, **consts)
+            return token_loss(p, agent, batch, snap, clip_coef, ent_coef, params_lo=params_lo, **consts)
 
         (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         with jax.named_scope("seqpol/optimizer"):
@@ -382,8 +390,8 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
             preempted = True
             break
         buf = store.begin(update)
-        snap_c, snap_r = player.snapshot()
-        prefilled, decoded, attended = player.rows_prefilled, player.tokens_decoded, player.cache_positions
+        snap = player.snapshot()
+        prefilled, decoded, attended, reset = player.rows_prefilled, player.tokens_decoded, player.cache_positions, player.rows_reset
         with timer("Time/env_interaction_time"):
             for t in range(rollout_steps):
                 policy_step += num_envs
@@ -424,7 +432,7 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
                     for idx in shuffle.permutation(n_seq).reshape(n_seq // batch_size, batch_size):
                         batch = {k: v[idx] for k, v in seqs.items()}
                         params, opt_state, player.params, metrics = train_fn(
-                            params, opt_state, player.params, batch, snap_c, snap_r, clip_coef, ent_coef
+                            params, opt_state, player.params, batch, snap, clip_coef, ent_coef
                         )
                         pending.append(metrics)
             with timer("train/block"):
@@ -432,7 +440,7 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
                 metrics = np.stack(jax.device_get(pending))
         _report(metrics, aggregator if cfg.metric.log_level > 0 else None, core=agent.core,
                 rows_prefilled=player.rows_prefilled - prefilled, tokens_decoded=player.tokens_decoded - decoded,
-                cache_positions=player.cache_positions - attended)  # fmt: skip
+                cache_positions=player.cache_positions - attended, rows_reset=player.rows_reset - reset)  # fmt: skip
 
         if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
             logger.log_metrics(aggregator.compute(), policy_step)
@@ -449,7 +457,7 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
 
 
 def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, rows_prefilled: int, tokens_decoded: int,
-            cache_positions: int) -> None:  # fmt: skip
+            cache_positions: int, rows_reset: int) -> None:  # fmt: skip
     """One update's losses into the aggregator and its counters into ``telemetry.jsonl``."""
     mean = dict(zip(METRICS, metrics.mean(0)))
     total = dict(zip(METRICS, metrics.sum(0)))
@@ -471,5 +479,8 @@ def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, 
         padded_positions=float(total["padded_positions"]),
         rows_prefilled=int(rows_prefilled),
         tokens_decoded=int(tokens_decoded),
+        # the rows' lengths summed over the decodes: what each layer that keeps a cache of positions attended to
         cache_positions=int(cache_positions),
+        # convolution states put back to nothing: a row's reset clears one in every convolution layer
+        conv_state_resets=int(rows_reset) * sum(core.operator(i) == seqpol.CONV for i in range(core.num_hidden_layers)),
     )

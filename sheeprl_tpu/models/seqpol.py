@@ -1,9 +1,13 @@
-"""A decoder-only token policy: RMSNorm, rotary embedding, multi-head latent
-attention in its two forms, SwiGLU, a routed expert layer that is told which
-experts it holds, the decoder block, and the multi-token-prediction module.
+"""A decoder-only token policy: RMSNorm, rotary embedding, a layer operator
+chosen layer by layer (multi-head latent attention, grouped-query attention or
+a gated short convolution, each in a whole-sequence and a one-token form),
+SwiGLU, a routed expert layer that is told which experts it holds, the decoder
+block, and the multi-token-prediction module.
 
-The equations are DeepSeek-V2/V3's published forms (``glm4_moe_lite`` follows
-them): every size comes from :class:`SeqPolConfig`, nothing is fixed here.
+The equations are the published forms: DeepSeek-V2/V3's latent attention and
+expert layer (``glm4_moe_lite`` follows them), and ``lfm2_moe``'s gated short
+convolution beside grouped-query attention with normed queries and keys. Every
+size comes from :class:`SeqPolConfig`, nothing is fixed here.
 
 Unlike ``blocks.py`` this module is plain functions over one parameter tree
 (nested dicts whose leaves are named ``kernel``, ``embedding``, ``scale`` and
@@ -12,16 +16,21 @@ resolve every leaf): the two forms of the attention read the same weights in
 two arrangements, which a ``linen`` module would have to share by hand.
 
 - :func:`forward_sequence` — the whole-sequence form, for the update and the
-  prefill: ``[B, S]`` tokens under a causal mask, each row optionally attending
-  first to a latent cache as it stood before the row's first position. It
-  expands the cache through ``W_kvb``.
-- :func:`decode_step` — one token per row through the latent cache. ``W_kvb``'s
-  key part is absorbed into the query and its value part into the output, so a
-  row attends over its 576-wide cache entries without expanding them.
+  prefill: ``[B, S]`` tokens under a causal mask, each row optionally continuing
+  from the state a player carried as it stood before the row's first position.
+- :func:`decode_step` — one token per row through that state, written in place.
 
-The cache holds, per layer and position, ``c_kv`` after its norm and ``k_rope``
-after its rotation (``kv_lora_rank + qk_rope_head_dim`` numbers), in the
-compute dtype.
+**A layer's state is what its operator declares** (:data:`OPERATORS`,
+:func:`state_shapes`): a tuple of arrays with the rows on the leading axis, in
+the compute dtype. Latent attention: ``c_kv`` after its norm and ``k_rope``
+after its rotation, ``[E, context, kv_lora_rank]`` and ``[E, context,
+qk_rope_head_dim]`` (the whole-sequence form expands them through ``W_kvb``;
+the one-token form absorbs ``W_kvb``'s key part into the query and its value
+part into the output, so a row attends over its 576-wide entries as they lie).
+Grouped-query attention: keys after norm and rotation, and values, ``[E,
+context, key-value heads x head_dim]`` each. The gated short convolution: the
+last ``conv_L_cache`` gated inputs, ``[E, conv_L_cache, D]``; the entries that
+lie before a row's first position read as zero, which is the reset.
 
 The expert layer routes over all ``n_routed_experts`` at the published width
 and computes only the ``held`` experts' part for the tokens routed to them,
@@ -42,15 +51,14 @@ from jax import lax
 Array = jax.Array
 Params = Dict[str, Any]
 
+#: a layer's operator, as ``layer_types`` names it (latent attention where a configuration names none)
+LATENT, ATTENTION, CONV = "latent_attention", "full_attention", "conv"
+
+
 @dataclass(frozen=True)
 class SeqPolConfig:
     hidden_size: int
     num_attention_heads: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
     intermediate_size: int
     moe_intermediate_size: int
     n_routed_experts: int  # the router's width: all experts of the layer, held here or not
@@ -64,6 +72,23 @@ class SeqPolConfig:
     num_nextn_predict_layers: int
     vocab_rows: int  # rows of the vocabulary held here: ids, logits, sampling and losses are over them
     context: int  # positions a row's cache holds
+    # latent attention's sizes: of a configuration that has such layers
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    #: each layer's operator (:data:`LATENT`, :data:`ATTENTION`, :data:`CONV`); latent attention everywhere if not given
+    layer_types: Optional[Tuple[str, ...]] = None
+    # grouped-query attention's sizes
+    num_key_value_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    #: gated inputs the short convolution spans, the current one among them
+    conv_L_cache: int = 3
+    #: the head reads the embedding's rows and the tree has no ``head``
+    tie_word_embeddings: bool = False
+    #: added to the sum the chosen experts' scores are divided by
+    router_eps: float = 1e-20
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-5
     #: routed pairs up to which the expert layer multiplies every held expert
@@ -77,9 +102,8 @@ class SeqPolConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
-    @property
-    def cache_width(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_head_dim
+    def operator(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else LATENT
 
 
 def config_from(node: Any) -> SeqPolConfig:
@@ -87,6 +111,8 @@ def config_from(node: Any) -> SeqPolConfig:
     get = node.get if hasattr(node, "get") else node.__getitem__
     fields = {f: get(f) for f in SeqPolConfig.__dataclass_fields__ if get(f) is not None}
     fields["held_experts"] = tuple(int(e) for e in fields["held_experts"])
+    if "layer_types" in fields:
+        fields["layer_types"] = tuple(str(t) for t in fields["layer_types"])
     return SeqPolConfig(**fields)
 
 
@@ -108,19 +134,38 @@ def _swiglu_params(key: Array, width: int, inner: int) -> Params:
     return {"gate": _dense(k[0], width, inner), "up": _dense(k[1], width, inner), "down": _dense(k[2], inner, width)}
 
 
-def _layer_params(key: Array, cfg: SeqPolConfig, dense: bool) -> Params:
-    k = jax.random.split(key, 10)
+def _latent_params(k: Array, cfg: SeqPolConfig) -> Params:
+    """An operator's parameters from the first of the layer's keys ``k``."""
     d, h = cfg.hidden_size, cfg.num_attention_heads
-    attn = {
+    return {
         "q_a": _dense(k[0], d, cfg.q_lora_rank),
         "q_norm": _norm(cfg.q_lora_rank),
         "q_b": _dense(k[1], cfg.q_lora_rank, h * cfg.qk_head_dim),
-        "kv_a": _dense(k[2], d, cfg.cache_width),
+        "kv_a": _dense(k[2], d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
         "kv_norm": _norm(cfg.kv_lora_rank),
         "kv_b": _dense(k[3], cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
         "o": _dense(k[4], h * cfg.v_head_dim, d),
     }
-    layer = {"attn_norm": _norm(d), "attn": attn, "ffn_norm": _norm(d)}
+
+
+def _gqa_params(k: Array, cfg: SeqPolConfig) -> Params:
+    d, h, g, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    return {"q": _dense(k[0], d, h * hd), "q_norm": _norm(hd), "k": _dense(k[1], d, g * hd), "k_norm": _norm(hd),
+            "v": _dense(k[2], d, g * hd), "o": _dense(k[3], h * hd, d)}  # fmt: skip
+
+
+def _conv_params(k: Array, cfg: SeqPolConfig) -> Params:
+    d = cfg.hidden_size
+    # the depthwise kernel as ``[taps, channels]``: tap ``j`` weighs the gated input ``conv_L_cache - 1 - j`` positions back
+    return {"in_proj": _dense(k[0], d, 3 * d), "conv": _dense(k[1], cfg.conv_L_cache, d), "out_proj": _dense(k[2], d, d)}
+
+
+def _layer_params(key: Array, cfg: SeqPolConfig, dense: bool, kind: str = LATENT) -> Params:
+    k = jax.random.split(key, 10)
+    d = cfg.hidden_size
+    op = OPERATORS[kind]
+    # ``attn_norm`` is the norm in front of the layer's operator, whichever it is
+    layer = {"attn_norm": _norm(d), op.key: op.params(k, cfg), "ffn_norm": _norm(d)}
     if dense:
         layer["mlp"] = _swiglu_params(k[5], d, cfg.intermediate_size)
         return layer
@@ -132,24 +177,28 @@ def _layer_params(key: Array, cfg: SeqPolConfig, dense: bool) -> Params:
         # the held experts' weights, stacked on a leading axis in the order of ``held_experts``
         "experts": {name: {"kernel": 0.02 * jax.random.normal(kk, (n_held, *shape), jnp.float32)}
                     for name, kk, shape in (("gate", k[7], (d, inner)), ("up", k[8], (d, inner)), ("down", k[9], (inner, d)))},  # fmt: skip
-        "shared": _swiglu_params(k[5], d, inner * cfg.n_shared_experts),
     }
+    if cfg.n_shared_experts:
+        layer["moe"]["shared"] = _swiglu_params(k[5], d, inner * cfg.n_shared_experts)
     return layer
 
 
 def init_params(key: Array, cfg: SeqPolConfig) -> Params:
-    """Float32 parameters from a key: embedding and head over ``vocab_rows``,
-    ``first_k_dense_replace`` dense layers then expert layers, the value head,
-    and one multi-token-prediction module where the configuration has one."""
+    """Float32 parameters from a key: embedding and head over ``vocab_rows``
+    (no head where it is tied to the embedding), ``first_k_dense_replace``
+    dense layers then expert layers, each with its operator's parameters, the
+    value head, and one multi-token-prediction module where the configuration
+    has one."""
     keys = jax.random.split(key, cfg.num_hidden_layers + 6)
     d = cfg.hidden_size
     params: Params = {
         "embed": {"embedding": 0.02 * jax.random.normal(keys[0], (cfg.vocab_rows, d), jnp.float32)},
-        "layers": {str(i): _layer_params(keys[1 + i], cfg, dense=i < cfg.first_k_dense_replace) for i in range(cfg.num_hidden_layers)},
+        "layers": {str(i): _layer_params(keys[1 + i], cfg, i < cfg.first_k_dense_replace, cfg.operator(i)) for i in range(cfg.num_hidden_layers)},
         "final_norm": _norm(d),
-        "head": _dense(keys[-1], d, cfg.vocab_rows),
         "value_head": _dense(keys[-2], d, 1),
     }
+    if not cfg.tie_word_embeddings:
+        params["head"] = _dense(keys[-1], d, cfg.vocab_rows)
     if cfg.num_nextn_predict_layers:
         params["mtp"] = {
             "enorm": _norm(d),
@@ -248,13 +297,46 @@ def _kv_b(p: Params, cfg: SeqPolConfig, dtype: Any) -> Tuple[Array, Array]:
 #: are all that is alive at once, and the backward pass computes them again
 QUERY_BLOCK = 128
 
+#: what a row continues from, for one layer: ``(state, row [B], length [B])``. ``state`` is the layer's state as a
+#: player carried it (:func:`state_shapes`), row ``b`` continues row ``row[b]`` of it, which held ``length[b]`` positions
+Context = Tuple[Tuple[Array, ...], Array, Array]
+
+
+def _by_query_blocks(block: Any, queries: Tuple[Array, ...], B: int, S: int) -> Array:
+    """``block(*queries of a block, their slots)`` over blocks of
+    :data:`QUERY_BLOCK` queries, under ``jax.checkpoint``; ``[B, S, ...]``."""
+    block = jax.checkpoint(block)
+    n = max(1, S // QUERY_BLOCK) if S % QUERY_BLOCK == 0 else 1
+    if n == 1:
+        return block(*queries, jnp.arange(S))
+    split = lambda a: jnp.moveaxis(a.reshape(B, n, S // n, *a.shape[2:]), 1, 0)  # noqa: E731
+    out = lax.map(lambda t: block(*t), (*(split(q) for q in queries), jnp.arange(S).reshape(n, S // n)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, *out.shape[3:])
+
+
+def _with_context(ctx: Optional[Context], own: Tuple[Array, ...], valid: Array) -> Tuple[Tuple[Array, ...], Array, Array]:
+    """The keys a row's queries may see: its own entries ``own`` (``[B, S,
+    .]`` each) behind the first ``length`` entries of the cache row it
+    continues. Returns the joined entries, which of them exist, and each
+    one's slot: a key is seen by the queries at or after it, by slot among the
+    row's own, always for the cache (slot ``-1``)."""
+    B, S = valid.shape
+    key_slot = jnp.broadcast_to(jnp.arange(S), (B, S))
+    if ctx is None:
+        return own, valid, key_slot
+    state, row, length = ctx
+    C = state[0].shape[1]
+    keys = tuple(jnp.concatenate([c[row].astype(o.dtype), o], axis=1) for c, o in zip(state, own))
+    key_ok = jnp.concatenate([jnp.arange(C)[None, :] < length[:, None], valid], axis=1)
+    return keys, key_ok, jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1)
+
 
 def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
-                 ctx: Optional[Tuple[Array, Array, Array, Array]] = None) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
+                 ctx: Optional[Context] = None) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
     """Whole-sequence latent attention. ``x [B, S, D]``; ``positions [B, S]``
     (rotary positions, rising along a row); ``valid [B, S]`` marks real slots
-    (padding neither attends nor is attended to). ``ctx = (c_kv [E, C, c],
-    k_rope [E, C, r], row [B], length [B])`` is a cache as it stood before the
+    (padding neither attends nor is attended to). ``ctx = ((c_kv [E, C, c],
+    k_rope [E, C, r]), row [B], length [B])`` is a cache as it stood before the
     rows' first slots: row ``b`` attends to the first ``length[b]`` entries of
     the cache's row ``row[b]`` too. Returns the output ``[B, S, D]`` and the
     rows' own ``(c_kv, k_rope)``."""
@@ -262,22 +344,12 @@ def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid
     H = cfg.num_attention_heads
     q_nope, q_rope = _queries(p, cfg, x, positions)
     c_kv, k_rope = latent_kv(p, cfg, x, positions)
-    keys_c, keys_r, key_ok = c_kv, k_rope, valid
-    # a key is seen by the queries at or after it: by slot among the row's own, always for the cache
-    key_slot = jnp.broadcast_to(jnp.arange(S), (B, S))
-    if ctx is not None:
-        ctx_c, ctx_r, ctx_len = ctx[0][ctx[2]], ctx[1][ctx[2]], ctx[3]
-        C = ctx_c.shape[1]
-        keys_c = jnp.concatenate([ctx_c.astype(x.dtype), c_kv], axis=1)
-        keys_r = jnp.concatenate([ctx_r.astype(x.dtype), k_rope], axis=1)
-        key_ok = jnp.concatenate([jnp.arange(C)[None, :] < ctx_len[:, None], valid], axis=1)
-        key_slot = jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1)
+    (keys_c, keys_r), key_ok, key_slot = _with_context(ctx, (c_kv, k_rope), valid)
     w_k, w_v = _kv_b(p, cfg, x.dtype)
     k_nope = jnp.einsum("bkc,chd->bkhd", keys_c, w_k)
     v = jnp.einsum("bkc,chd->bkhd", keys_c, w_v)
     scale = cfg.qk_head_dim**-0.5
 
-    @jax.checkpoint
     def block(qn, qr, q_slot):
         s = jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope, preferred_element_type=jnp.float32)
         s = s + jnp.einsum("bqhr,bkr->bhqk", qr, keys_r, preferred_element_type=jnp.float32)
@@ -285,23 +357,18 @@ def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid
         w = jax.nn.softmax(jnp.where(seen, s * scale, -1e30), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", w.astype(x.dtype), v)
 
-    n = max(1, S // QUERY_BLOCK) if S % QUERY_BLOCK == 0 else 1
-    if n == 1:
-        out = block(q_nope, q_rope, jnp.arange(S))
-    else:
-        split = lambda a: jnp.moveaxis(a.reshape(B, n, S // n, *a.shape[2:]), 1, 0)  # noqa: E731
-        out = lax.map(lambda t: block(*t), (split(q_nope), split(q_rope), jnp.arange(S).reshape(n, S // n)))
-        out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, cfg.v_head_dim)
+    out = _by_query_blocks(block, (q_nope, q_rope), B, S)
     out = _mm(out.reshape(B, S, H * cfg.v_head_dim), p["o"]["kernel"])
     return out, (c_kv, k_rope)
 
 
-def mla_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, cache_c: Array, cache_r: Array) -> Tuple[Array, Array, Array]:
+def mla_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array, Array]) -> Tuple[Array, Tuple[Array, Array]]:
     """One token per row against one layer's latent cache, in the absorbed
     form. ``x [E, D]``; ``positions [E]`` (the row's cache length: where its
-    entry goes); ``cache_c [E, C, c]``, ``cache_r [E, C, r]``. Returns the
+    entry goes); ``state = (cache_c [E, C, c], cache_r [E, C, r])``. Returns the
     output ``[E, D]`` and the two caches with the rows' entries written (in
     place, where the caller donates them)."""
+    cache_c, cache_r = state
     E = x.shape[0]
     q_nope, q_rope = _queries(p, cfg, x, positions)
     c_kv, k_rope = latent_kv(p, cfg, x, positions)
@@ -316,7 +383,163 @@ def mla_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, cache_c
     w = jax.nn.softmax(jnp.where(seen, s * cfg.qk_head_dim**-0.5, -1e30), axis=-1)
     o_lat = jnp.einsum("eht,etc->ehc", w.astype(x.dtype), cache_c.astype(x.dtype))
     out = jnp.einsum("ehc,chd->ehd", o_lat, w_v)  # and its value part, into the output
-    return _mm(out.reshape(E, -1), p["o"]["kernel"]), cache_c, cache_r
+    return _mm(out.reshape(E, -1), p["o"]["kernel"]), (cache_c, cache_r)
+
+
+def _gqa_qkv(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[Array, Array, Array]:
+    """Queries ``[..., H, d]``, keys ``[..., G, d]`` (both RMS-normed over the
+    head's dims, then rotated on all of them) and values ``[..., G, d]``."""
+    H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    lead = x.shape[:-1]
+    q = rms_norm(_mm(x, p["q"]["kernel"]).reshape(*lead, H, hd), p["q_norm"]["scale"], cfg.rms_norm_eps)
+    k = rms_norm(_mm(x, p["k"]["kernel"]).reshape(*lead, G, hd), p["k_norm"]["scale"], cfg.rms_norm_eps)
+    v = _mm(x, p["v"]["kernel"]).reshape(*lead, G, hd)
+    return rope(q, positions[..., None], cfg.rope_theta), rope(k, positions[..., None], cfg.rope_theta), v
+
+
+def gqa_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
+                 ctx: Optional[Context] = None) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
+    """Whole-sequence grouped-query attention, with :func:`mla_sequence`'s
+    arguments: query head ``i`` reads key-value head ``i // (H / G)``. ``ctx``'s
+    state is ``(keys [E, C, G x d], values [E, C, G x d])``. Returns the output
+    and the rows' own keys (after norm and rotation) and values, ``[B, S, G x d]``."""
+    B, S, _ = x.shape
+    H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q, k, v = _gqa_qkv(p, cfg, x, positions)
+    own = (k.reshape(B, S, G * hd), v.reshape(B, S, G * hd))
+    (keys, values), key_ok, key_slot = _with_context(ctx, own, valid)
+    keys, values = keys.reshape(B, -1, G, hd), values.reshape(B, -1, G, hd)
+
+    def block(qb, q_slot):
+        qb = qb.reshape(B, -1, G, H // G, hd)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, keys, preferred_element_type=jnp.float32)
+        seen = key_ok[:, None, None, None, :] & (key_slot[:, None, None, None, :] <= q_slot[None, None, None, :, None])
+        w = jax.nn.softmax(jnp.where(seen, s * hd**-0.5, -1e30), axis=-1)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(x.dtype), values).reshape(B, -1, H * hd)
+
+    return _mm(_by_query_blocks(block, (q,), B, S), p["o"]["kernel"]), own
+
+
+def gqa_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array, Array]) -> Tuple[Array, Tuple[Array, Array]]:
+    """One token per row against one layer's key-value cache, ``state = (keys
+    [E, C, G x d], values [E, C, G x d])``, with :func:`mla_decode`'s arguments.
+    Every query head is laid out over the cache's whole width, zero outside its
+    own key-value head's dims: the scores and the output are then products with
+    the cache entries as they lie (what the zeros add is exactly nothing), and
+    no head is sliced or transposed out of the cache."""
+    cache_k, cache_v = state
+    E = x.shape[0]
+    H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q, k, v = _gqa_qkv(p, cfg, x, positions)
+    rows = jnp.arange(E)
+    cache_k = cache_k.at[rows, positions].set(k.reshape(E, G * hd).astype(cache_k.dtype), mode="drop")
+    cache_v = cache_v.at[rows, positions].set(v.reshape(E, G * hd).astype(cache_v.dtype), mode="drop")
+    own = (jnp.arange(H)[:, None] // (H // G) == jnp.arange(G)[None, :])[None, :, :, None]  # [1, H, G, 1]
+    q_wide = jnp.where(own, q[:, :, None, :], 0).reshape(E, H, G * hd)
+    s = jnp.einsum("ehc,etc->eht", q_wide, cache_k.astype(x.dtype), preferred_element_type=jnp.float32)
+    seen = jnp.arange(cache_k.shape[1])[None, None, :] <= positions[:, None, None]
+    w = jax.nn.softmax(jnp.where(seen, s * hd**-0.5, -1e30), axis=-1)
+    o_wide = jnp.einsum("eht,etc->ehc", w.astype(x.dtype), cache_v.astype(x.dtype))
+    out = jnp.where(own, o_wide.reshape(E, H, G, hd), 0).sum(2)
+    return _mm(out.reshape(E, H * hd), p["o"]["kernel"]), (cache_k, cache_v)
+
+
+def conv_in_episode(positions: Array, taps: int) -> Array:
+    """``[rows, taps]``: which entries of a convolution state, taken at
+    ``positions [rows]``, are of the rows' own episode: at or after its first
+    position (entry ``j`` is the gated input ``taps - 1 - j`` positions back).
+    The others read as zero: that is the reset of this kind of state."""
+    return positions[:, None] - (taps - 1 - jnp.arange(taps))[None, :] >= 0
+
+
+def _conv_mix(p: Params, window: Sequence[Array]) -> Array:
+    """The depthwise causal convolution: ``sum_j w[j] * window[j]``, ``window[j]`` the gated input ``taps - 1 - j`` back."""
+    w = p["conv"]["kernel"]
+    return sum(w[j].astype(z.dtype) * z for j, z in enumerate(window))
+
+
+def conv_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
+                  ctx: Optional[Context] = None) -> Tuple[Array, Tuple[Array]]:  # fmt: skip
+    """The gated short convolution over whole rows: ``[B, C, X] = W_in x``,
+    ``z = B * X``, ``out = W_out (C * conv(z))`` with ``conv`` depthwise and
+    causal over ``conv_L_cache`` gated inputs. ``valid`` marks one run of real
+    slots a row; before it ``z`` is zero, or, for a row that continues
+    (``ctx = ((state [E, L, D],), row [B], length [B])``), what ``state`` held
+    of the ``length`` positions before. Returns the output and ``(the last L
+    gated inputs up to each row's last real slot [B, L, D],)``: the state a
+    prefill leaves behind."""
+    B, S, _ = x.shape
+    L = cfg.conv_L_cache
+    with jax.named_scope("proj"):
+        gates = _mm(x, p["in_proj"]["kernel"])
+    with jax.named_scope("mix"):
+        b, c, xx = jnp.split(gates, 3, axis=-1)
+        z = jnp.where(valid[..., None], b * xx, 0)
+        zp = jnp.concatenate([jnp.zeros((B, L - 1, z.shape[-1]), z.dtype), z], axis=1)  # slot ``i`` of ``z`` is ``i + L - 1`` here
+        first = jnp.argmax(valid, axis=1)
+        if ctx is not None:
+            (state,), row, length = ctx
+            carried = state[row].astype(z.dtype)  # entry ``j`` lies ``L - 1 - j`` before the position that wrote it
+            kept = conv_in_episode(length - 1, L)
+            at = jnp.arange(S + L - 1)[None, :]
+            for back in range(1, L):  # the gated input ``back`` before the row's first slot
+                here = (at == (first + L - 1 - back)[:, None]) & kept[:, L - back, None]
+                zp = jnp.where(here[..., None], carried[:, L - back, None, :], zp)
+        y = c * _conv_mix(p, [zp[:, j : j + S] for j in range(L)])
+        last = first + valid.sum(axis=1) - 1
+        tail = jnp.take_along_axis(zp, jnp.maximum(last[:, None] + jnp.arange(L)[None, :], 0)[..., None], axis=1)
+    with jax.named_scope("proj"):
+        return _mm(y, p["out_proj"]["kernel"]), (tail,)
+
+
+def conv_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array]) -> Tuple[Array, Tuple[Array]]:
+    """One token per row through the convolution state ``(z [E, L, D],)``: the
+    state moves one entry on and takes the row's gated input, and the entries
+    that lie before the row's first position are put to zero
+    (:func:`conv_in_episode`: a row at position 0 starts from nothing,
+    whatever the episode before left)."""
+    (z_state,) = state
+    L = cfg.conv_L_cache
+    with jax.named_scope("proj"):
+        gates = _mm(x, p["in_proj"]["kernel"])
+    with jax.named_scope("mix"):
+        b, c, xx = jnp.split(gates, 3, axis=-1)
+        z_state = jnp.concatenate([z_state[:, 1:], (b * xx).astype(z_state.dtype)[:, None]], axis=1)
+        z_state = jnp.where(conv_in_episode(positions, L)[..., None], z_state, 0)
+        y = c * _conv_mix(p, [z_state[:, j].astype(x.dtype) for j in range(L)])
+    with jax.named_scope("proj"):
+        return _mm(y, p["out_proj"]["kernel"]), (z_state,)
+
+
+@dataclass(frozen=True)
+class Operator:
+    """What a layer's operator declares: where its parameters lie in the
+    layer's tree and how they are made, its ``jax.named_scope``, its two forms
+    (``sequence(p, cfg, x, positions, valid, ctx) -> (out, own entries)`` and
+    ``decode(p, cfg, x, positions, state) -> (out, state)``) and the shape of
+    each array of its state for one row."""
+
+    key: str
+    scope: str
+    params: Any
+    sequence: Any
+    decode: Any
+    state: Any
+
+
+OPERATORS: Dict[str, Operator] = {
+    LATENT: Operator("attn", "seqpol/attn", _latent_params, mla_sequence, mla_decode,
+                     lambda cfg: ((cfg.context, cfg.kv_lora_rank), (cfg.context, cfg.qk_rope_head_dim))),
+    ATTENTION: Operator("attn", "seqpol/attn", _gqa_params, gqa_sequence, gqa_decode,
+                        lambda cfg: ((cfg.context, cfg.num_key_value_heads * cfg.head_dim),) * 2),
+    CONV: Operator("conv", "seqpol/conv", _conv_params, conv_sequence, conv_decode, lambda cfg: ((cfg.conv_L_cache, cfg.hidden_size),)),
+}  # fmt: skip
+
+
+def state_shapes(cfg: SeqPolConfig, rows: int) -> Tuple[Tuple[Tuple[int, ...], ...], ...]:
+    """The shapes of the state ``rows`` rows carry: for each layer, one shape
+    for each array its operator declares."""
+    return tuple(tuple((rows, *shape) for shape in OPERATORS[cfg.operator(i)].state(cfg)) for i in range(cfg.num_hidden_layers))
 
 
 # --------------------------------------------------------------------------- #
@@ -332,7 +555,7 @@ def route(p: Params, cfg: SeqPolConfig, x: Array) -> Tuple[Array, Array]:
     _, chosen = lax.top_k(scores + p["router"]["bias"], cfg.num_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True) + cfg.router_eps)
     return chosen, weights * cfg.routed_scaling_factor
 
 
@@ -401,7 +624,7 @@ def grouped_rows(cfg: SeqPolConfig, pairs: int) -> int:
 
 
 def moe(p: Params, cfg: SeqPolConfig, x: Array, real: Optional[Array] = None) -> Tuple[Array, Array]:
-    """``x [T, D]`` -> the shared expert plus the held experts' part of the
+    """``x [T, D]`` -> the shared expert (where the layer has one) plus the held experts' part of the
     routed sum, and the layer's counts ``[routed pairs, pairs on each held
     expert...]`` (float32). ``real [T]`` marks the rows that are tokens:
     padding is routed nowhere."""
@@ -419,6 +642,8 @@ def moe(p: Params, cfg: SeqPolConfig, x: Array, real: Optional[Array] = None) ->
             routed = _experts_dense(p["experts"], x, slot, weights, n_held)
         else:
             routed = _experts_grouped(p["experts"], x, slot, weights, n_held, grouped_rows(cfg, T * cfg.num_experts_per_tok))
+    if "shared" not in p:
+        return routed, counts
     with jax.named_scope("seqpol/moe/shared"):
         shared = swiglu(p["shared"], x)
     return shared + routed, counts
@@ -449,40 +674,42 @@ def _ffn(p: Params, cfg: SeqPolConfig, x: Array, real: Optional[Array]) -> Tuple
     return y.reshape(x.shape), counts
 
 
-def block_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
-                   ctx: Optional[Tuple[Array, Array, Array, Array]]) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
-    """One decoder block on whole rows: the output, the rows' own cache entries and the expert layer's counts."""
-    with jax.named_scope("seqpol/attn"):
-        a, kv = mla_sequence(p["attn"], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, valid, ctx)
+def block_sequence(p: Params, cfg: SeqPolConfig, kind: str, x: Array, positions: Array, valid: Array,
+                   ctx: Optional[Context]) -> Tuple[Array, Tuple[Array, ...], Array]:  # fmt: skip
+    """One decoder block on whole rows, its operator of ``kind``: the output,
+    the rows' own entries of the operator's state and the expert layer's counts."""
+    op = OPERATORS[kind]
+    with jax.named_scope(op.scope):
+        a, own = op.sequence(p[op.key], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, valid, ctx)
     x = x + a
     f, counts = _ffn(p, cfg, rms_norm(x, p["ffn_norm"]["scale"], cfg.rms_norm_eps), valid)
-    return x + f, kv, counts
+    return x + f, own, counts
 
 
-def block_by_rows(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
-                  ctx: Optional[Tuple[Array, Array, Array, Array]], remat: bool) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
+def block_by_rows(p: Params, cfg: SeqPolConfig, kind: str, x: Array, positions: Array, valid: Array,
+                  ctx: Optional[Context], remat: bool) -> Tuple[Array, Tuple[Array, ...], Array]:  # fmt: skip
     """:func:`block_sequence`, ``cfg.row_chunk`` rows at a time where the
     update asks for it (``remat``): rows do not see each other, so a chunk's
     intermediates are all that is alive at once, and the backward pass, which
     keeps only each chunk's input, makes them again."""
     B, rc = x.shape[0], cfg.row_chunk
     if not remat:
-        x, kv, counts = block_sequence(p, cfg, x, positions, valid, ctx)
-        return x, kv, counters_of(counts)
-    cache, of_rows = (None, None) if ctx is None else (ctx[:2], ctx[2:])  # the cache is whole in every chunk; its rows and lengths go with theirs
+        x, own, counts = block_sequence(p, cfg, kind, x, positions, valid, ctx)
+        return x, own, counters_of(counts)
+    state, of_rows = (None, None) if ctx is None else (ctx[0], ctx[1:])  # the state is whole in every chunk; its rows and lengths go with theirs
 
     def fn(args):
         x, positions, valid, of_rows = args
-        return block_sequence(p, cfg, x, positions, valid, None if cache is None else (*cache, *of_rows))
+        return block_sequence(p, cfg, kind, x, positions, valid, None if state is None else (state, *of_rows))
 
     fn = jax.checkpoint(fn)
     if B <= rc or B % rc:
-        x, kv, counts = fn((x, positions, valid, of_rows))
-        return x, kv, counters_of(counts)
+        x, own, counts = fn((x, positions, valid, of_rows))
+        return x, own, counters_of(counts)
     split = lambda a: a.reshape(B // rc, rc, *a.shape[1:])  # noqa: E731
-    x, kv, counts = lax.map(fn, jax.tree.map(split, (x, positions, valid, of_rows)))
+    x, own, counts = lax.map(fn, jax.tree.map(split, (x, positions, valid, of_rows)))
     join = lambda a: a.reshape(B, *a.shape[2:])  # noqa: E731
-    return join(x), jax.tree.map(join, kv), counters_of(counts.sum(0))
+    return join(x), jax.tree.map(join, own), counters_of(counts.sum(0))
 
 
 def embed(params: Params, tokens: Array, dtype: Any) -> Array:
@@ -491,54 +718,59 @@ def embed(params: Params, tokens: Array, dtype: Any) -> Array:
 
 
 def forward_sequence(params: Params, cfg: SeqPolConfig, tokens: Array, positions: Array, valid: Array,
-                     ctx: Optional[Tuple[Array, Array, Array, Array]] = None, *, dtype: Any = jnp.float32,
-                     remat: bool = False) -> Tuple[Array, Tuple[Array, Array], Array]:  # fmt: skip
+                     ctx: Optional[Tuple[Sequence[Tuple[Array, ...]], Array, Array]] = None, *, dtype: Any = jnp.float32,
+                     remat: bool = False) -> Tuple[Array, Tuple[Tuple[Array, ...], ...], Array]:  # fmt: skip
     """The trunk over ``tokens [B, S]``: the hidden state before the final
-    norm ``[B, S, D]``, the rows' own cache entries ``(c_kv [L, B, S, c],
-    k_rope [L, B, S, r])`` and the expert layers' counters summed. ``ctx =
-    (c_kv, k_rope, row [B], length [B])`` is a cache the rows continue from
-    (:func:`mla_sequence`), indexed by layer: ``c_kv[i] [E, C, c]``, ``k_rope[i]
-    [E, C, r]``. ``remat`` keeps only each
-    chunk of a block's input for the backward pass."""
+    norm ``[B, S, D]``, for each layer the rows' own entries of its operator's
+    state (what a prefill writes into the rows it fills: ``[B, S, .]`` for a
+    cache of positions, ``[B, L, D]`` for a convolution state) and the expert
+    layers' counters summed. ``ctx = (state, row [B], length [B])`` is the
+    state the rows continue from, ``state[i]`` layer ``i``'s arrays
+    (:func:`state_shapes`). ``remat`` keeps only each chunk of a block's input
+    for the backward pass."""
     x = embed(params, tokens, dtype)
-    kvs, counters = [], jnp.zeros((3,), jnp.float32)
+    entries, counters = [], jnp.zeros((3,), jnp.float32)
     for i in range(cfg.num_hidden_layers):
-        layer_ctx = None if ctx is None else (ctx[0][i], ctx[1][i], ctx[2], ctx[3])
-        x, kv, n = block_by_rows(params["layers"][str(i)], cfg, x, positions, valid, layer_ctx, remat)
-        kvs.append(kv)
+        layer_ctx = None if ctx is None else (ctx[0][i], ctx[1], ctx[2])
+        x, own, n = block_by_rows(params["layers"][str(i)], cfg, cfg.operator(i), x, positions, valid, layer_ctx, remat)
+        entries.append(own)
         counters = merge_counters(counters, n)
-    return x, (jnp.stack([c for c, _ in kvs]), jnp.stack([r for _, r in kvs])), counters
+    return x, tuple(entries), counters
 
 
-def decode_step(params: Params, cfg: SeqPolConfig, tokens: Array, positions: Array, cache_c: Sequence[Array], cache_r: Sequence[Array],
-                *, dtype: Any = jnp.float32) -> Tuple[Array, Tuple[Array, ...], Tuple[Array, ...], Array]:  # fmt: skip
+def decode_step(params: Params, cfg: SeqPolConfig, tokens: Array, positions: Array, state: Sequence[Tuple[Array, ...]],
+                *, dtype: Any = jnp.float32) -> Tuple[Array, Tuple[Tuple[Array, ...], ...], Array]:  # fmt: skip
     """One token for each of ``E`` rows: ``tokens [E]`` at ``positions [E]``
-    through the cache, one array a layer (``cache_c[i] [E, C, c]``,
-    ``cache_r[i] [E, C, r]``: a layer's entries are then written in place and
-    read where they lie, and no layer is sliced out of a stack). Returns the
-    hidden state before the final norm ``[E, D]``, the caches with the rows'
-    entries written, and the expert layers' counters."""
+    through the state, each layer's arrays on their own (:func:`state_shapes`:
+    a layer's entries are then written in place and read where they lie, and
+    no layer is sliced out of a stack). Returns the hidden state before the
+    final norm ``[E, D]``, the state with the rows' entries written, and the
+    expert layers' counters."""
     x = embed(params, tokens, dtype)
     counters = jnp.zeros((3,), jnp.float32)
-    new_c, new_r = [], []
+    new_state = []
     for i in range(cfg.num_hidden_layers):
-        p = params["layers"][str(i)]
-        with jax.named_scope("seqpol/attn"):
-            a, c, r = mla_decode(p["attn"], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, cache_c[i], cache_r[i])
-        new_c.append(c)
-        new_r.append(r)
+        p, op = params["layers"][str(i)], OPERATORS[cfg.operator(i)]
+        with jax.named_scope(op.scope):
+            a, layer_state = op.decode(p[op.key], cfg, rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps), positions, state[i])
+        new_state.append(layer_state)
         x = x + a
         f, n = _ffn(p, cfg, rms_norm(x, p["ffn_norm"]["scale"], cfg.rms_norm_eps), None)
         x = x + f
         counters = merge_counters(counters, counters_of(n))
-    return x, tuple(new_c), tuple(new_r), counters
+    return x, tuple(new_state), counters
+
+
+def head_kernel(params: Params) -> Array:
+    """``[D, vocabulary rows]``: the head's own kernel, or the embedding's rows where the two are tied."""
+    return params["head"]["kernel"] if "head" in params else params["embed"]["embedding"].T
 
 
 def heads(params: Params, cfg: SeqPolConfig, h: Array) -> Tuple[Array, Array]:
     """Final norm, then the logits over the held rows (float32) and the value."""
     with jax.named_scope("seqpol/head"):
         z = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps)
-        logits = _mm(z, params["head"]["kernel"]).astype(jnp.float32)
+        logits = _mm(z, head_kernel(params)).astype(jnp.float32)
         value = jnp.dot(z.astype(jnp.float32), params["value_head"]["kernel"])[..., 0]
     return logits, value
 
@@ -553,7 +785,7 @@ def mtp_hidden(params: Params, cfg: SeqPolConfig, h: Array, next_tokens: Array, 
         m = params["mtp"]
         e = embed(params, next_tokens, h.dtype)
         joined = jnp.concatenate([rms_norm(h, m["hnorm"]["scale"], cfg.rms_norm_eps), rms_norm(e, m["enorm"]["scale"], cfg.rms_norm_eps)], axis=-1)
-        x, _, counters = block_by_rows(m["block"], cfg, _mm(joined, m["eh_proj"]["kernel"]), positions, valid, None, remat)
+        x, _, counters = block_by_rows(m["block"], cfg, LATENT, _mm(joined, m["eh_proj"]["kernel"]), positions, valid, None, remat)
     return x, counters
 
 
@@ -566,7 +798,7 @@ def token_stats(params: Params, cfg: SeqPolConfig, h: Array, norm_scale: Array, 
     @jax.checkpoint
     def one(hb, tb):
         z = rms_norm(hb, norm_scale, cfg.rms_norm_eps)
-        logp = jax.nn.log_softmax(_mm(z, params["head"]["kernel"]).astype(jnp.float32), axis=-1)
+        logp = jax.nn.log_softmax(_mm(z, head_kernel(params)).astype(jnp.float32), axis=-1)
         return jnp.take_along_axis(logp, tb[:, None], axis=-1)[:, 0], -(jnp.exp(logp) * logp).sum(-1)
 
     N = h.shape[0]

@@ -1,13 +1,18 @@
 """The decoder core of recurrent PPO (a policy over tokens) against the plain
-reference, tiny on the CPU in float32: the same ratios as the model it was
-built for (1 dense + 2 expert layers, 8 routed experts of which 4 a token, 1
-shared, every latent and head dim distinct, the multi-token-prediction module
-on). Every tolerance is float32 round-off (readings are 1e-6 or under) with
-room for the order of sums; the same numbers computed with bfloat16 operands
-read 1e-2 and fail each of them, which ``test_bfloat16_fails_the_tolerances``
-holds.
+references, tiny on the CPU in float32, for both models it runs: ``glm`` (the
+ratios of GLM-4.7-Flash: latent attention in every layer, 1 dense + 2 expert
+layers, 8 routed experts of which 4 a token, 1 shared, every latent and head
+dim distinct, the multi-token-prediction module on) and ``lfm2`` (the ratios
+of LFM2-24B-A2B: a gated short convolution over the dense layer, then
+grouped-query attention and a convolution over expert layers with no shared
+expert, 2 query heads a key-value head, embedding and head tied). Every
+tolerance is float32 round-off (readings are 1e-6 or under) with room for the
+order of sums; the same numbers computed with bfloat16 operands read 1e-2 and
+fail each of them, which ``test_bfloat16_fails_the_tolerances`` holds.
 """
 
+import contextlib
+import dataclasses
 import os
 import signal
 
@@ -17,6 +22,7 @@ import numpy as np
 import pytest
 
 from perfbench.references import token_ppo as reference
+from perfbench.references import token_ppo_lfm2 as reference_lfm2
 from sheeprl_tpu.algos.ppo_recurrent import token_policy
 from sheeprl_tpu.cli import run
 from sheeprl_tpu.models import seqpol
@@ -25,18 +31,56 @@ SIZES = dict(hidden_size=32, num_attention_heads=2, q_lora_rank=12, kv_lora_rank
              intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8, held_experts=[0, 1, 2, 3], num_experts_per_tok=4,
              n_shared_experts=1, routed_scaling_factor=1.8, norm_topk_prob=True, first_k_dense_replace=1, num_hidden_layers=3,
              num_nextn_predict_layers=1, vocab_rows=24, context=32, rope_theta=1e6, rms_norm_eps=1e-5)  # fmt: skip
+LFM2_SIZES = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=6, conv_L_cache=3,
+                  layer_types=["conv", "full_attention", "conv"], intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8,
+                  held_experts=[0, 1, 2, 3], num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_prob=True,
+                  router_eps=1e-6, first_k_dense_replace=1, num_hidden_layers=3, num_nextn_predict_layers=0, tie_word_embeddings=True,
+                  vocab_rows=24, context=32, rope_theta=1e6, rms_norm_eps=1e-5)  # fmt: skip
 #: float32 round-off at these widths reads 1e-6; a bfloat16 operand anywhere reads 1e-2
 TOL = 2e-5
-VOCAB, LAYERS, CONTEXT = SIZES["vocab_rows"], SIZES["num_hidden_layers"], SIZES["context"]
+VOCAB, CONTEXT = SIZES["vocab_rows"], SIZES["context"]
 
 
-def core(**changes):
-    sizes = {**SIZES, **changes}
-    return seqpol.SeqPolConfig(**{**sizes, "held_experts": tuple(sizes["held_experts"])})
+def core(sizes=SIZES, **changes):
+    sizes = {**sizes, **changes}
+    for key in ("held_experts", "layer_types"):
+        if key in sizes:
+            sizes[key] = tuple(sizes[key])
+    return seqpol.SeqPolConfig(**sizes)
+
+
+class Model:
+    """One of the two models: its tiny sizes, its plain reference and its seeded weights."""
+
+    def __init__(self, name):
+        self.name = name
+        self.sizes, self.reference = {"glm": (SIZES, reference), "lfm2": (LFM2_SIZES, reference_lfm2)}[name]
+        self.weights = self.reference.init_weights({"model": self.sizes}, 3)
+        self._forward = jax.jit(lambda w, tokens: self.reference.forward(w, self.sizes, tokens)[:2])
+        self.mtp_coef = 0.1 if self.sizes["num_nextn_predict_layers"] else 0.0
+
+    def core(self, **changes):
+        return core(self.sizes, **changes)
+
+    def forward(self, tokens, size=16):
+        """The reference's full forward on ``tokens``, padded to one length (causal: the padding changes nothing before it)."""
+        padded = np.zeros((size,), np.int32)
+        padded[: len(tokens)] = tokens
+        logits, values = self._forward(self.weights, padded)
+        return logits[: len(tokens)], values[: len(tokens)]
+
+    def losses(self, aligned, a):
+        return self.reference.losses_only(self.weights, self.sizes, a, aligned)
+
+
+@pytest.fixture(scope="module", params=["glm", "lfm2"])
+def model(request):
+    return Model(request.param)
 
 
 @pytest.fixture(scope="module")
 def weights():
+    """``glm``'s weights, for the tests of the expert layer alone: it is one layer for both models."""
     return reference.init_weights({"model": SIZES}, 3)
 
 
@@ -51,33 +95,41 @@ def whole(weights, tokens, dtype=jnp.float32, cfg=None):
 
     @jax.jit
     def run_whole(w):
-        h, kv, counters = seqpol.forward_sequence(w, cfg, tokens, positions, np.ones(tokens.shape, bool), dtype=dtype)
-        return (*seqpol.heads(w, cfg, h), kv, counters)
+        h, own, counters = seqpol.forward_sequence(w, cfg, tokens, positions, np.ones(tokens.shape, bool), dtype=dtype)
+        return (*seqpol.heads(w, cfg, h), own, counters)
 
     return run_whole(weights)
 
 
-@jax.jit
-def _reference_forward(weights, tokens):
-    return reference.forward(weights, SIZES, tokens)
+@contextlib.contextmanager
+def conv_state_kept():
+    """A planted fault: the convolution state of the episode before survives a
+    reset. No entry of it reads as zero for lying before the row's first
+    position, and a prefill leaves it as it was (its own entries for that kind
+    of state are none)."""
+    real_before, real_op = seqpol.conv_in_episode, seqpol.OPERATORS[seqpol.CONV]
+
+    def sequence(*args, **kwargs):
+        out, (tail,) = real_op.sequence(*args, **kwargs)
+        return out, (tail[:, :0],)
+
+    seqpol.conv_in_episode = lambda positions, taps: jnp.ones((positions.shape[0], taps), bool)
+    seqpol.OPERATORS[seqpol.CONV] = dataclasses.replace(real_op, sequence=sequence)
+    try:
+        yield
+    finally:
+        seqpol.conv_in_episode, seqpol.OPERATORS[seqpol.CONV] = real_before, real_op
 
 
-def reference_forward(weights, tokens, size=16):
-    """The reference's full forward on ``tokens``, padded to one length (causal: the padding changes nothing before it)."""
-    padded = np.zeros((size,), np.int32)
-    padded[: len(tokens)] = tokens
-    logits, values = _reference_forward(weights, padded)
-    return logits[: len(tokens)], values[: len(tokens)]
+def test_the_programs_own_weights_have_the_references_tree(model):
+    ours = seqpol.init_params(jax.random.PRNGKey(0), model.core())
+    assert jax.tree.structure(ours) == jax.tree.structure(model.weights)
+    assert [a.shape for a in jax.tree.leaves(ours)] == [b.shape for b in jax.tree.leaves(model.weights)]
+    assert ("head" in ours) == (model.name == "glm")  # tied: the head is the embedding's rows
 
 
-def test_the_programs_own_weights_have_the_references_tree(weights):
-    ours = seqpol.init_params(jax.random.PRNGKey(0), core())
-    assert jax.tree.structure(ours) == jax.tree.structure(weights)
-    assert [a.shape for a in jax.tree.leaves(ours)] == [b.shape for b in jax.tree.leaves(weights)]
-
-
-def test_every_leaf_resolves_under_the_partition_rules(weights):
-    """(fabric) kernel, embedding, scale and bias, stacked expert kernels among them: no unmatched-leaf warning."""
+def test_every_leaf_resolves_under_the_partition_rules(model):
+    """(fabric) kernel, embedding, scale and bias, stacked expert kernels and the depthwise kernel among them: no unmatched-leaf warning."""
     import warnings
 
     from sheeprl_tpu.parallel.fabric import Fabric, reset_partition_rule_warnings
@@ -85,71 +137,117 @@ def test_every_leaf_resolves_under_the_partition_rules(weights):
     reset_partition_rule_warnings()
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        specs = Fabric(devices=1, accelerator="cpu").match_partition_rules(weights)
-    assert len(jax.tree.leaves(specs, is_leaf=lambda s: hasattr(s, "index"))) == len(jax.tree.leaves(weights))
+        specs = Fabric(devices=1, accelerator="cpu").match_partition_rules(model.weights)
+    assert len(jax.tree.leaves(specs, is_leaf=lambda s: hasattr(s, "index"))) == len(jax.tree.leaves(model.weights))
 
 
 # (a) the whole-sequence form against the reference's full forward
 @pytest.mark.parametrize("grouped", [False, True], ids=["experts_dense", "experts_grouped"])
-def test_whole_sequence_equals_the_reference(weights, grouped):
+def test_whole_sequence_equals_the_reference(model, grouped):
     tokens = np.random.default_rng(0).integers(0, VOCAB, (2, 12)).astype(np.int32)
-    logits, values, _, counters = whole(weights, tokens, cfg=core(dense_pairs_max=0 if grouped else 10**6))
+    logits, values, own, counters = whole(model.weights, tokens, cfg=model.core(dense_pairs_max=0 if grouped else 10**6))
     for b in range(2):
-        ref_logits, ref_values = reference_forward(weights, tokens[b])
+        ref_logits, ref_values = model.forward(tokens[b])
         assert gap(logits[b], ref_logits) < TOL and gap(values[b], ref_values) < TOL
     assert counters[0] == 2 * 12 * 4 * 2 and 0 < counters[1] < counters[0]  # pairs routed in 2 expert layers, and those on held experts
+    # what each layer's operator declares of its state is what the rows' own entries fit into
+    for entries, shapes in zip(own, seqpol.state_shapes(model.core(), 2)):
+        assert [e.shape[2:] for e in entries] == [s[2:] for s in shapes] and all(e.shape[1] in (12, s[1]) for e, s in zip(entries, shapes))
 
 
-# (b) prefill, then decoding through the cache, a row reset in the middle, against the reference's full forward
-def test_prefill_then_decode_across_a_reset_equals_the_reference(weights):
+def _play(model, steps=12):
+    """Prefill, then decoding through the state, row 1 reset in the middle to a
+    new prompt and row 2 to a prompt of one token (which no prefill touches);
+    returns the worst gap of the logits and of the values to the reference's
+    full forward from each episode's first token."""
     rng = np.random.default_rng(1)
-    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
-    player = token_policy.TokenPlayer(agent, weights, num_envs=3, prefill_rows=2)
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
+    player = token_policy.TokenPlayer(agent, model.weights, num_envs=3, prefill_rows=2)
     key = jax.random.PRNGKey(0)
     inputs = [[], [], []]  # every token each row's policy has been fed, episode by episode
     obs_tokens, n_tokens = np.zeros((3, 6), np.int32), np.zeros((3,), np.int32)
 
-    def reset(row):
-        n = int(rng.integers(1, 7))
+    def reset(row, n=None):
+        n = int(rng.integers(2, 7)) if n is None else n
         obs_tokens[row, :n], n_tokens[row] = rng.integers(0, VOCAB, n), n
         inputs[row] = [int(t) for t in obs_tokens[row, :n]]
 
     for row in range(3):
         reset(row)
-    for step in range(9):
+    worst_logits = worst_values = 0.0
+    for step in range(steps):
         actions, _, values, positions = player.act(obs_tokens, n_tokens, key, step)
         logits = np.asarray(player.last_logits)
         for row in range(3):
             assert positions[row] == len(inputs[row]) - 1
-            ref_logits, ref_values = reference_forward(weights, inputs[row])
-            assert gap(logits[row], ref_logits[-1]) < TOL, (step, row)
-            assert abs(float(values[row]) - float(ref_values[-1])) < TOL * max(1.0, abs(float(ref_values[-1])))
+            ref_logits, ref_values = model.forward(inputs[row], size=24)
+            worst_logits = max(worst_logits, gap(logits[row], ref_logits[-1]))
+            worst_values = max(worst_values, abs(float(values[row]) - float(ref_values[-1])) / max(1.0, abs(float(ref_values[-1]))))
         dones = np.zeros((3,), bool)
-        dones[1] = step == 3  # row 1 ends in the middle: its next prompt is prefilled into the slot it leaves
+        dones[1], dones[2] = step == 3, step == 5  # rows 1 and 2 end in the middle: their next prompts go into the slots they leave
         player.reset_rows(dones)
         for row in range(3):
             if dones[row]:
-                reset(row)
+                reset(row, 1 if row == 2 else None)
             else:
                 obs_tokens[row, 0], n_tokens[row] = int(actions[row]), 1
                 inputs[row].append(int(actions[row]))
-    assert player.rows_prefilled >= 1 and player.tokens_decoded == 27
+    assert player.rows_prefilled == 4 and player.tokens_decoded == 3 * steps and player.rows_reset == 2
+    return worst_logits, worst_values
 
 
-# (c) the shares add up: an uncut layer of 8 experts in shares of 2
-def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(weights):
-    everything = {**SIZES, "held_experts": list(range(8))}
-    uncut = reference.init_weights({"model": everything}, 5)["layers"]["1"]["moe"]
+# (b) prefill, then decoding through both kinds of state, rows reset in the middle, against the reference's full forward
+def test_prefill_then_decode_across_a_reset_equals_the_reference(model):
+    worst_logits, worst_values = _play(model)
+    assert worst_logits < TOL and worst_values < TOL
+
+
+def test_a_convolution_state_that_survives_a_reset_is_seen():
+    with conv_state_kept():
+        worst_logits, _ = _play(Model("lfm2"))
+    assert worst_logits > 1000 * TOL
+
+
+def test_the_convolution_token_by_token_equals_the_whole_sequence_form():
+    """One token at a time through its state, the whole rows at once, and rows that continue from a state: one result."""
+    cfg, p = core(LFM2_SIZES), reference_lfm2.init_weights({"model": LFM2_SIZES}, 3)["layers"]["0"]["conv"]
+    B, S, D, L = 2, 9, LFM2_SIZES["hidden_size"], LFM2_SIZES["conv_L_cache"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (B, S, D))
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    out, (tail,) = seqpol.conv_sequence(p, cfg, x, positions, jnp.ones((B, S), bool))
+    state = (jnp.full((B, L, D), 7.0),)  # what an episode before left: position 0 starts from nothing all the same
+    for t in range(S):
+        step, state = seqpol.conv_decode(p, cfg, x[:, t], jnp.full((B,), t), state)
+        assert gap(step, out[:, t]) < TOL, t
+        if t == 4:
+            halfway = state
+    assert gap(state[0], tail) < TOL  # what a prefill of these rows leaves behind is what decoding them leaves
+    # the rows' last 4 positions again, swapped: the second continues row 0 of the state after 5 positions, the first
+    # begins there, from nothing, whatever row 1 of the state holds
+    valid = jnp.ones((B, S - 5), bool)
+    later, _ = seqpol.conv_sequence(p, cfg, x[::-1, 5:], positions[:, 5:], valid, ((halfway[0],), jnp.asarray([1, 0]), jnp.asarray([0, 5])))
+    assert gap(later[1], out[0, 5:]) < TOL
+    alone, _ = seqpol.conv_sequence(p, cfg, x[1:, 5:], positions[:1, 5:], valid[:1])
+    assert gap(later[0], alone[0]) < TOL
+
+
+# (c) the shares add up: an uncut layer of 8 experts in shares of 2 (with a shared expert, counted once) and of 1 (with none)
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(model):
+    everything = {**model.sizes, "held_experts": list(range(8))}
+    uncut = model.reference.init_weights({"model": everything}, 5)["layers"]["1"]["moe"]
     x = jax.random.normal(jax.random.PRNGKey(2), (20, SIZES["hidden_size"]))
-    want = reference.expert_layer(uncut, everything, x)
-    shared = seqpol.swiglu(uncut["shared"], x)
+    want = model.reference.expert_layer(uncut, everything, x)
+    shared = seqpol.swiglu(uncut["shared"], x) if "shared" in uncut else jnp.zeros_like(x)
+    assert ("shared" in uncut) == (model.name == "glm")
     routed = jnp.zeros_like(x)
-    for held in seqpol.held_shares(core(), 2):
+    shares = seqpol.held_shares(model.core(), 2 if model.name == "glm" else 1)
+    assert len(shares) == (4 if model.name == "glm" else 8)
+    for held in shares:
         share = {**uncut, "experts": {k: {"kernel": uncut["experts"][k]["kernel"][np.asarray(held)]} for k in ("gate", "up", "down")}}
-        y, counters = seqpol.moe(share, core(held_experts=held), x)
+        y, counters = seqpol.moe(share, model.core(held_experts=held), x)
         routed = routed + (y - shared)  # what this share's own experts gave: the shared expert is counted once, below
     assert gap(shared + routed, want) < TOL
-    assert gap(seqpol.moe(uncut, core(held_experts=tuple(range(8))), x)[0], want) < TOL
+    assert gap(seqpol.moe(uncut, model.core(held_experts=tuple(range(8))), x)[0], want) < TOL
 
 
 # (f) routing under a planted imbalance drops nothing and computes nothing wrong
@@ -216,10 +314,10 @@ def test_rows_in_no_group_may_hold_anything(weights, monkeypatch, factor, plante
         assert bool(jnp.isfinite(a).all()) and gap(a, b) < TOL
 
 
-def _batch(weights, rng, agent, continuing):
+def _batch(model, rng, agent, continuing, dtype=jnp.float32):
     """One minibatch: an episode that begins in the rollout, one that
-    continues from a snapshot (or begins too), and a padding sequence; with the
-    reference's aligned form of the same."""
+    continues from a snapshot (or begins too), and a padding sequence; the
+    snapshot of two rows; and the reference's aligned form of the same."""
     P, L = agent.prompt_max, 8
     pad = lambda a, n, dtype=np.float32: np.concatenate([np.asarray(a, dtype), np.zeros((n - len(a),), dtype)])  # noqa: E731
     noise = lambda n: rng.normal(size=n).astype(np.float32)  # noqa: E731
@@ -227,11 +325,11 @@ def _batch(weights, rng, agent, continuing):
     prompt_b, acts_b = rng.integers(0, VOCAB, 3), rng.integers(0, VOCAB, 7)
     inputs_b = np.concatenate([prompt_b, acts_b[:-1]])
     before = 5 if continuing else 0  # episode b's inputs that lie before the rollout: in the snapshot, not in the sequence
-    snap_c = jnp.zeros((LAYERS, 2, CONTEXT, SIZES["kv_lora_rank"]))
-    snap_r = jnp.zeros((LAYERS, 2, CONTEXT, SIZES["qk_rope_head_dim"]))
+    # row 0 of the snapshot holds what an episode before left (a row that begins must not read it), row 1 episode b so far
+    snap = jax.tree.map(lambda shape: jnp.full(shape, 3.0, dtype), seqpol.state_shapes(agent.core, 2), is_leaf=lambda s: isinstance(s[0], int))
     if continuing:
-        _, _, (c, r), _ = whole(weights, inputs_b[:before])
-        snap_c, snap_r = snap_c.at[:, 1, :before].set(c[:, 0]), snap_r.at[:, 1, :before].set(r[:, 0])
+        own = whole(model.weights, inputs_b[:before], cfg=agent.core)[2]
+        snap = jax.tree.map(lambda held, new: held.at[1, : new.shape[1]].set(new[0].astype(dtype)), snap, own)
     seq_a = dict(actions=acts_a, logprobs=-3 + 0.1 * noise(5), advantages=noise(5), returns=noise(5), values=noise(5))
     n_b = 4 if continuing else 7
     seq_b = dict(actions=acts_b[-n_b:], logprobs=-3 + 0.1 * noise(n_b), advantages=noise(n_b), returns=noise(n_b), values=noise(n_b))
@@ -245,8 +343,8 @@ def _batch(weights, rng, agent, continuing):
         "mask": np.stack([pad(np.ones(5), L), pad(np.ones(n_b), L), np.zeros(L, np.float32)]),
     }
     for k in seq_a:
-        dtype = np.int32 if k == "actions" else np.float32
-        batch[k] = np.stack([pad(seq_a[k], L, dtype), pad(seq_b[k], L, dtype), np.zeros(L, dtype)])
+        kind = np.int32 if k == "actions" else np.float32
+        batch[k] = np.stack([pad(seq_a[k], L, kind), pad(seq_b[k], L, kind), np.zeros(L, kind)])
 
     def aligned(tokens, first, seq, size=12):
         out = {"tokens": pad(tokens, size, np.int32), "steps": np.zeros(size, np.float32)}
@@ -257,69 +355,79 @@ def _batch(weights, rng, agent, continuing):
             out[k][first : first + n] = v
         return out
 
-    return batch, snap_c, snap_r, [aligned(np.concatenate([prompt_a, acts_a[:-1]]), 3, seq_a), aligned(inputs_b, first_b, seq_b)]
+    return batch, snap, [aligned(np.concatenate([prompt_a, acts_a[:-1]]), 3, seq_a), aligned(inputs_b, first_b, seq_b)]
 
 
 CONSTS = dict(clip_coef=0.2, vf_coef=0.2, ent_coef=0.001, lr=3e-4, eps=1e-4, weight_decay=0.01, max_grad_norm=0.5)
 LOSS_NAMES = ("policy_loss", "value_loss", "entropy_loss", "mtp_loss")
 
 
-def _program_loss(weights, agent, batch, snap_c, snap_r, mtp_coef):
+def _program_loss(weights, agent, batch, snap, mtp_coef):
     def loss(p):
-        return token_policy.token_loss(p, agent, batch, snap_c, snap_r, 0.2, 0.001, vf_coef=0.2, mtp_coef=mtp_coef)
+        return token_policy.token_loss(p, agent, batch, snap, 0.2, 0.001, vf_coef=0.2, mtp_coef=mtp_coef)
 
     (_, metrics), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(weights)
     return dict(zip(token_policy.METRICS, np.asarray(metrics))), grads
 
 
-# (d) a sequence that starts from a snapshot equals the same episode evaluated whole
-def test_a_sequence_from_a_snapshot_equals_the_episode_evaluated_whole(weights):
-    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
-    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=True)
-    ours, _ = _program_loss(weights, agent, batch, snap_c, snap_r, 0.1)
-    theirs = reference.losses_only(weights, SIZES, {**CONSTS, "mtp_loss_coef": 0.1}, aligned)
+def _same_losses(ours, theirs):
     for name in LOSS_NAMES:
-        assert abs(ours[name] - theirs[name]) < TOL * max(1.0, abs(theirs[name])), name
+        want = theirs.get(name, 0.0)  # a model without the multi-token-prediction module has no such term: the program reports 0
+        assert abs(ours[name] - want) < TOL * max(1.0, abs(want)), name
+
+
+# (d) a sequence that starts from a snapshot, of both kinds of state, equals the same episode evaluated whole
+def test_a_sequence_from_a_snapshot_equals_the_episode_evaluated_whole(model):
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
+    batch, snap, aligned = _batch(model, np.random.default_rng(0), agent, continuing=True)
+    ours, _ = _program_loss(model.weights, agent, batch, snap, model.mtp_coef)
+    _same_losses(ours, model.losses(aligned, {**CONSTS, "mtp_loss_coef": model.mtp_coef}))
     assert ours["real_positions"] == 3 + 5 + 4 and ours["padded_positions"] == 3 * (6 + 8)
+    if model.name == "lfm2":
+        with conv_state_kept():  # the row that begins now reads what the episode before left in its row of the snapshot
+            faulty, _ = _program_loss(model.weights, agent, batch, snap, model.mtp_coef)
+        assert abs(faulty["value_loss"] - ours["value_loss"]) > 1000 * TOL
 
 
 # (e) one update equals the reference's: losses, the gradient by leaf, the weights after; MTP term on and off
-@pytest.mark.parametrize("mtp_coef", [0.1, 0.0], ids=["mtp_on", "mtp_off"])
-def test_one_update_equals_the_reference(weights, mtp_coef):
+@pytest.mark.parametrize("name, mtp_coef", [("glm", 0.1), ("glm", 0.0), ("lfm2", 0.0)], ids=["glm-mtp_on", "glm-mtp_off", "lfm2"])
+def test_one_update_equals_the_reference(name, mtp_coef):
     import optax
 
-    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.float32)
-    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=False)
+    model = Model(name)
+    weights = model.weights
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
+    batch, snap, aligned = _batch(model, np.random.default_rng(0), agent, continuing=False)
     a = {**CONSTS, "mtp_loss_coef": mtp_coef}
-    ours, grads = _program_loss(weights, agent, batch, snap_c, snap_r, mtp_coef)
-    theirs, ref_grads = reference.loss_and_grad(weights, SIZES, a, aligned)
-    for name in LOSS_NAMES:
-        assert abs(ours[name] - theirs[name]) < TOL * max(1.0, abs(theirs[name])), name
+    ours, grads = _program_loss(weights, agent, batch, snap, mtp_coef)
+    theirs, ref_grads = model.reference.loss_and_grad(weights, model.sizes, a, aligned)
+    _same_losses(ours, theirs)
     gaps = jax.tree.map(gap, grads, ref_grads)
     assert max(jax.tree.leaves(gaps)) < 10 * TOL, gaps  # a leaf's gradient sums over every position: ten round-offs of room
-    moved = jax.tree.map(lambda g: float(jnp.abs(g).max()) > 0, ref_grads["mtp"])
-    assert any(jax.tree.leaves(moved)) == (mtp_coef > 0)  # with its coefficient at 0 the module gets no gradient
+    if name == "glm":
+        moved = jax.tree.map(lambda g: float(jnp.abs(g).max()) > 0, ref_grads["mtp"])
+        assert any(jax.tree.leaves(moved)) == (mtp_coef > 0)  # with its coefficient at 0 the module gets no gradient
     # the weights after: the main's optimizer (global-norm clip, then AdamW) against the reference's first step
     from sheeprl_tpu.ops.optim import adam
 
     tx = adam(lr=a["lr"], eps=a["eps"], weight_decay=a["weight_decay"], max_grad_norm=a["max_grad_norm"])
     updates, _ = tx.update(grads, tx.init(weights), weights)
     after = optax.apply_updates(weights, updates)
-    ref_after = reference.adamw_first_step(weights, reference.clip_by_global_norm(ref_grads, a["max_grad_norm"]), a)
+    ref_after = model.reference.adamw_first_step(weights, model.reference.clip_by_global_norm(ref_grads, a["max_grad_norm"]), a)
     change = jax.tree.map(lambda x, y, w: gap(x - w, y - w), after, ref_after, weights)
     assert max(jax.tree.leaves(change)) < 1e-3, change  # g / (|g| + eps) at eps 1e-4 magnifies the gradient's round-off
 
 
-def test_bfloat16_fails_the_tolerances(weights):
+def test_bfloat16_fails_the_tolerances(model):
     """What (a), (b) and (e) hold in float32 does not survive bfloat16 operands."""
     tokens = np.random.default_rng(0).integers(0, VOCAB, (1, 12)).astype(np.int32)
-    logits, values, _, _ = whole(weights, tokens, dtype=jnp.bfloat16)
-    ref_logits, _ = reference_forward(weights, tokens[0])
+    logits, values, _, _ = whole(model.weights, tokens, dtype=jnp.bfloat16, cfg=model.core())
+    ref_logits, _ = model.forward(tokens[0])
     assert gap(logits[0].astype(jnp.float32), ref_logits) > 50 * TOL
-    agent = token_policy.TokenPolicy(core(), prompt_max=6, dtype=jnp.bfloat16)
-    batch, snap_c, snap_r, aligned = _batch(weights, np.random.default_rng(0), agent, continuing=False)
-    _, grads = _program_loss(weights, agent, batch, snap_c.astype(jnp.bfloat16), snap_r.astype(jnp.bfloat16), 0.1)
-    _, ref_grads = reference.loss_and_grad(weights, SIZES, {**CONSTS, "mtp_loss_coef": 0.1}, aligned)
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.bfloat16)
+    batch, snap, aligned = _batch(model, np.random.default_rng(0), agent, continuing=False, dtype=jnp.bfloat16)
+    _, grads = _program_loss(model.weights, agent, batch, snap, model.mtp_coef)
+    _, ref_grads = model.reference.loss_and_grad(model.weights, model.sizes, {**CONSTS, "mtp_loss_coef": model.mtp_coef}, aligned)
     assert max(jax.tree.leaves(jax.tree.map(gap, grads, ref_grads))) > 50 * TOL
 
 
@@ -337,11 +445,12 @@ def test_build_sequences_emits_the_cores_carry():
 
 
 # (g) the recipe through cli.run at the tiny size: it learns to copy, and leaves with 77 on SIGTERM
-def tiny_args(tmp_path):
-    return ["exp=ppo_recurrent_glm47_flash", "fabric=cpu", "fabric.precision=fp32", "fabric.devices=1", "env.num_envs=8", "algo.rollout_steps=16",
-            "algo.per_rank_sequence_length=20", "algo.per_rank_batch_size=32", *[f"algo.core.{k}={v}" for k, v in {
-                **{k: v for k, v in SIZES.items() if k not in ("held_experts", "vocab_rows", "context", "rope_theta", "rms_norm_eps")},
-                "held_experts": "[0,1,2,3]", "vocab_rows": 8, "context": 16, "prompt_max": 4, "prefill_rows": 2}.items()],
+def tiny_args(tmp_path, exp="ppo_recurrent_glm47_flash", sizes=SIZES):
+    as_word = lambda v: "[" + ",".join(str(x) for x in v) + "]" if isinstance(v, (list, tuple)) else v  # noqa: E731
+    return [f"exp={exp}", "fabric=cpu", "fabric.precision=fp32", "fabric.devices=1", "env.num_envs=8", "algo.rollout_steps=16",
+            "algo.per_rank_sequence_length=20", "algo.per_rank_batch_size=32", *[f"algo.core.{k}={as_word(v)}" for k, v in {
+                **{k: v for k, v in sizes.items() if k not in ("vocab_rows", "context", "rope_theta", "rms_norm_eps")},
+                "vocab_rows": 8, "context": 16, "prompt_max": 4, "prefill_rows": 2}.items()],
             "env.wrapper.prompt_min=1", "env.wrapper.prompt_max=2", "algo.optimizer.lr=3e-3", "metric.log_level=1", "algo.run_test=False",
             "checkpoint.save_last=False", "checkpoint.every=0", f"log_base_dir={tmp_path}/logs"]  # fmt: skip
 
@@ -373,6 +482,25 @@ def test_the_recipe_learns_to_copy_and_leaves_with_77_on_sigterm(tmp_path, monke
     rewards = [float(line.rsplit("=", 1)[1]) for line in out.splitlines() if "reward_env_" in line]
     fifth = len(rewards) // 5
     assert fifth > 50 and np.mean(rewards[-fifth:]) > np.mean(rewards[:fifth]) + 0.1, (np.mean(rewards[:fifth]), np.mean(rewards[-fifth:]))
+
+
+def test_the_hybrid_recipe_trains_through_the_same_main(tmp_path, monkeypatch, capsys):
+    """``exp=ppo_recurrent_lfm2_24b_a2b`` at the tiny size: rollouts whose episodes straddle them (both kinds of
+    snapshot are used), updates of two epochs, the three programs under the names the other recipe gives them."""
+    monkeypatch.chdir(tmp_path)
+    seen = []
+    real = token_policy.make_player_programs
+
+    def programs(agent):
+        made = real(agent)
+        seen.extend(made[k].__wrapped__.__name__ for k in ("prefill", "decode"))
+        return made
+
+    monkeypatch.setattr(token_policy, "make_player_programs", programs)
+    run([*tiny_args(tmp_path, "ppo_recurrent_lfm2_24b_a2b", LFM2_SIZES), "algo.total_steps=768"])  # 6 updates of 128 policy steps
+    assert seen == ["seqpol_prefill", "seqpol_decode"]
+    out = capsys.readouterr().out
+    assert sum("reward_env_" in line for line in out.splitlines()) > 50
 
 
 def test_the_lstm_recipe_leaves_with_77_on_sigterm(tmp_path, monkeypatch):
