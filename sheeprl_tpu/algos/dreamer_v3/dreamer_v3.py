@@ -22,6 +22,7 @@ The redesign (SURVEY.md §7 hard parts, all addressed here):
 
 from __future__ import annotations
 
+import collections
 import os
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
@@ -101,6 +102,25 @@ METRIC_ORDER = (
     "Grads/actor",
     "Grads/critic",
 )
+
+
+#: arguments of the per-step train program whose buffers its results take over:
+#: the three parameter trees, the three optimizer states and the moments
+TRAIN_STEP_DONATED = (0, 1, 2, 4, 5, 6, 7)
+
+
+def result_buffers(args: Sequence[Any], out: Any, donated: Sequence[int]) -> Dict[str, int]:
+    """How many result leaves of a jitted call can take over the buffer of a
+    donated argument leaf (one of the same shape and dtype, each used once, as
+    ``jax.jit`` pairs them), and how many the runtime has to allocate afresh at
+    every dispatch."""
+    free = collections.Counter(
+        (leaf.shape, jnp.dtype(leaf.dtype)) for i in donated for leaf in jax.tree.leaves(args[i])
+    )
+    results = collections.Counter((leaf.shape, jnp.dtype(leaf.dtype)) for leaf in jax.tree.leaves(out))
+    total = sum(results.values())
+    aliased = sum(min(n, free[aval]) for aval, n in results.items())
+    return {"result_leaves": total, "aliased_result_leaves": aliased, "fresh_result_leaves": total - aliased}
 
 
 def make_train_step(
@@ -379,16 +399,37 @@ def make_train_fn(
         # single device, or a model-axis mesh: GSPMD partitions the global
         # program from the inputs' committed shardings
         train_fn = local_train
-    # donate only optimizer/aux state: param buffers stay un-donated because
-    # concurrent readers (async param streaming to the host player, the ema /
-    # hard-copy target refresh) may still be in flight when the next train
-    # dispatch would otherwise alias over them
+    # Every argument that comes back as a result is donated, the three
+    # parameter trees too, so each result leaf but the metrics vector lands in
+    # the buffer its argument held: a fresh result buffer costs the host some
+    # 47 us to allocate, 174 of them at XL at the head of every train window
+    # (howto/telemetry.md, ``dv3/train_step_buffers``). Argument 3, the target
+    # critic, is no result and stays. What makes that sound is the loops' rule
+    # that no handle on a parameter tree outlives the next train dispatch, and
+    # what each reader of the parameters does about it:
+    # - the player on the learner's device holds the step's own result arrays;
+    #   all it enqueues on them is enqueued before the next window's first
+    #   dispatch, which the runtime orders behind those reads, and it is handed
+    #   the newest trees right behind the window's last dispatch;
+    # - the host player's stream packs a tree into one vector with a program
+    #   enqueued before ``update_params`` returns and keeps that vector, never
+    #   a leaf (``fabric._StreamPipe``);
+    # - the target refresh is a program of its own (``dv3_target_ema``),
+    #   enqueued before the next dispatch, and its result is fresh;
+    # - checkpoints ``device_get`` the loop's current bindings, the newest
+    #   trees; after a dispatch that raised those are gone, and the crash
+    #   guard's checkpoint fails as one that could not be written;
+    # - a NaN rollback restores from the newest committed checkpoint and reads
+    #   of the live trees only where they are placed (``resil.place_like``).
     def dv3_train_step(*args):
         # the jitted function's name is the XLA module's (``jit_dv3_train_step``):
         # what a device trace files the step's ops under
-        return train_fn(*args)
+        out = train_fn(*args)
+        # as the step is traced, so once for each program built on it
+        telemetry_counters("dv3/train_step_buffers", **result_buffers(args, out, TRAIN_STEP_DONATED))
+        return out
 
-    return jax.jit(dv3_train_step, donate_argnums=(4, 5, 6, 7))
+    return jax.jit(dv3_train_step, donate_argnums=TRAIN_STEP_DONATED)
 
 
 def make_fused_train_fn(
@@ -1143,6 +1184,8 @@ def main(fabric, cfg: Dict[str, Any]):
                         # loop (one device round trip per train block); the queue
                         # drains at log time instead
                         pending_metrics.append(metrics)
+                # the per-step program took the trees the player held: it gets
+                # the window's newest before anything touches it again. Then,
                 # behind the train steps and before the host waits for them:
                 # the next turn's forward, then the next window's first target
                 # refresh and key split, which depend on nothing newer either

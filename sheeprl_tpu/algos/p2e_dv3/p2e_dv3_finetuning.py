@@ -176,6 +176,12 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
     def ema(cp, tcp, tau):
         return jax.tree.map(lambda c, t: tau * c + (1 - tau) * t, cp, tcp)
 
+    # the step donates its parameter trees (``dreamer_v3.make_train_fn``), and
+    # this loop holds to the rule that allows it: the player is handed the
+    # newest trees right behind a window's last dispatch and enqueues nothing
+    # inside a window, the target refresh is a program of its own enqueued
+    # before the step that follows it, and a checkpoint reads the bindings of
+    # after the window
     train_fn = make_train_fn(
         fabric, wm, actor, critic, world_tx, actor_tx, critic_tx, cfg, is_continuous, actions_dim
     )
@@ -368,8 +374,10 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
                         cumulative_per_rank_gradient_steps += 1
                     metrics = np.asarray(jax.device_get(metrics))
                     train_step += num_processes
-                # non-blocking in host-player mode: the trees stream through the
-                # async pipe and flip a block or two later (fabric.stream_attr)
+                # the trees the player held went into the window's steps: it gets
+                # the newest before anything else touches it. Non-blocking in
+                # host-player mode: the trees stream through the async pipe and
+                # flip a block or two later (fabric.stream_attr)
                 player.stream_attr("wm_params", wm_params)
                 player.stream_attr("actor_params", actor_task_params)
                 if cfg.metric.log_level > 0:

@@ -738,7 +738,15 @@ class _ParamStreamer:
     # what lets a host-pinned player refresh params without ever stalling
     # the env loop on the transfer.
     def begin(self, tree: Any) -> Any:
-        flat = self._pack(jax.tree.leaves(tree))
+        return self.send(self.pack(tree))
+
+    # ``begin`` in its two halves, for a tree that has to wait its turn: packed
+    # at once, so that nothing holds the tree's own leaves past the call (a
+    # train step may donate them), and sent when the link is free.
+    def pack(self, tree: Any) -> Any:
+        return self._pack(jax.tree.leaves(tree))
+
+    def send(self, flat: Any) -> Any:
         try:
             flat.copy_to_host_async()
         except AttributeError:  # non-jax.Array inputs (already host)
@@ -788,10 +796,12 @@ class _StreamPipe:
     """At-most-one-in-flight async param stream with a pending candidate.
 
     ``offer`` never blocks: if a transfer is in flight the newest tree is
-    stashed and streamed when the current one lands. ``poll`` returns a
-    materialized tree once the in-flight copy is old enough to have landed
-    (age gate — there is no completion event to poll for a host copy), else
-    None."""
+    packed, the pack stashed and streamed when the current one lands. Either
+    way the tree is read by a program enqueued before ``offer`` returns and
+    none of its leaves is kept, so the caller may donate them to its next
+    dispatch. ``poll`` returns a materialized tree once the in-flight copy is
+    old enough to have landed (age gate — there is no completion event to
+    poll for a host copy), else None."""
 
     def __init__(self, streamer: "_ParamStreamer") -> None:
         self.streamer = streamer
@@ -832,7 +842,7 @@ class _StreamPipe:
         if self._inflight is None:
             self._inflight = (self.streamer.begin(tree), time.perf_counter())
         else:
-            self._candidate = tree
+            self._candidate = self.streamer.pack(tree)
 
     def poll(self) -> Any:
         import time
@@ -845,7 +855,7 @@ class _StreamPipe:
         tree = self.streamer.finish(flat)
         self._inflight = None
         if self._candidate is not None:
-            self._inflight = (self.streamer.begin(self._candidate), time.perf_counter())
+            self._inflight = (self.streamer.send(self._candidate), time.perf_counter())
             self._candidate = None
         return tree
 
@@ -857,7 +867,7 @@ class _StreamPipe:
             out = self.streamer.finish(self._inflight[0])
             self._inflight = None
         if self._candidate is not None:
-            out = self.streamer.finish(self.streamer.begin(self._candidate))
+            out = self.streamer.finish(self._candidate)
             self._candidate = None
         return out
 

@@ -73,10 +73,70 @@ def test_p2e_dv3_exploration_to_finetuning_roundtrip(tmp_path, monkeypatch):
     assert find_checkpoints(f"{tmp_path}/logs_ft")
 
 
-def test_p2e_dv3_evaluate_roundtrip(tmp_path, monkeypatch):
-    monkeypatch.chdir(tmp_path)
-    run(expl_args(tmp_path))
+@pytest.fixture(scope="module")
+def exploration_ckpt(tmp_path_factory):
+    """One exploration run's checkpoint, for the tests that start from one."""
+    tmp_path = tmp_path_factory.mktemp("exploration")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.chdir(tmp_path)
+        run(expl_args(tmp_path))
     (ckpt,) = find_checkpoints(tmp_path)
+    return ckpt
+
+
+def test_p2e_dv3_evaluate_roundtrip(tmp_path, monkeypatch, exploration_ckpt):
+    monkeypatch.chdir(tmp_path)
     from sheeprl_tpu.cli import evaluation
 
-    evaluation([f"checkpoint_path={ckpt}"])
+    evaluation([f"checkpoint_path={exploration_ckpt}"])
+
+
+def test_p2e_dv3_finetuning_trains_two_windows_on_donated_parameters(tmp_path, monkeypatch, exploration_ckpt):
+    """The finetuning loop runs ``dreamer_v3.make_train_fn``'s step, which
+    donates its parameter trees: every tree a window hands in is gone at the
+    window's end, the player holds the newest, and the last checkpoint is of
+    the last window's weights."""
+    import jax
+    import numpy as np
+
+    from sheeprl_tpu.algos.p2e_dv3 import p2e_dv3_finetuning as program
+    from sheeprl_tpu.utils.checkpoint import load_checkpoint
+
+    monkeypatch.chdir(tmp_path)
+    seen = {"steps": [], "player": None}
+    real_make, real_build = program.make_train_fn, program.build_agent
+
+    def make_train_fn(*args, **kwargs):
+        fn = real_make(*args, **kwargs)
+
+        def train(*a):
+            out = fn(*a)
+            seen["steps"].append((a[:3], out[:3]))
+            return out
+
+        return train
+
+    def build_agent(*args, **kwargs):
+        built = real_build(*args, **kwargs)
+        seen["player"] = built[-1]
+        return built
+
+    monkeypatch.setattr(program, "make_train_fn", make_train_fn)
+    monkeypatch.setattr(program, "build_agent", build_agent)
+    run(
+        ["exp=p2e_dv3_finetuning", "env.id=dummy_discrete", f"log_base_dir={tmp_path}/logs_ft"]
+        + [a for a in TINY if a not in ("dry_run=True", "algo.run_test=True")]
+        + [f"checkpoint.exploration_ckpt_path={exploration_ckpt}", "dry_run=False", "algo.total_steps=4", "algo.run_test=False", "fabric.devices=1"]
+    )
+    steps, player = seen["steps"], seen["player"]
+    assert len(steps) >= 2  # two updates of two envs, a window each
+    for handed, _ in steps:
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(handed))
+    newest = steps[-1][1]
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(newest))
+    assert player.wm_params is newest[0] and player.actor_params is newest[1]
+    (ckpt,) = find_checkpoints(f"{tmp_path}/logs_ft")
+    state = load_checkpoint(ckpt)
+    for saved, tree in ((state["world_model"], newest[0]), (state["actor_task"], newest[1]), (state["critic_task"], newest[2])):
+        for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(tree)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
