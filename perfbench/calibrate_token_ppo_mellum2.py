@@ -1,0 +1,104 @@
+"""Readings for the limits of the window-and-full-attention token policy's cell, by hand on the chip::
+
+    python3 perfbench/calibrate_token_ppo_mellum2.py --workload mellum2_12b_ep8.train --seed <n> [--seconds 10]
+
+One run of the cell through the harness, then every number ``correct`` compares
+(``algorithms/token_ppo_mellum2.py``), for the program and for what must not
+pass, each put in the program's place against the float32 reference: the
+reference with its weights in bfloat16, and rounded to 4 exponent and 3
+mantissa bits, and five planted faults (one held expert left out; half of the
+minibatch left out; ``window_ignored``: the three window layers attend to the
+whole episode; ``yarn_left_out``: the full layer rotated by the default table,
+no attention factor; ``ring_kept``: a reset that leaves the episode before in
+the ring and a prefill that does not overwrite it). One JSON line a side, with
+the numbers that side can move: ``half_batch`` no player's, ``ring_kept`` the
+player's alone, ``float8``, ``window_ignored`` and ``yarn_left_out`` the
+player's and the first step's losses (a gradient takes minutes more and every
+side fails by what it has). Each side's numbers then go through the limits the
+cell's file has, as the program's do (``correct.judge`` over the numbers that
+side can move): the line says whether the side came out ``correct`` and by
+which limits it fails, and the run's own ``correct`` is false if the program
+fails or any control or fault passes. It reads what
+``token_ppo_mellum2.verify`` compares; ``calibrate_token_ppo_lfm2.py`` is the
+hybrid model's tool.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))
+
+#: name -> (the weights' precision, held experts left out, half the minibatch left out, the forward's faults, the ring kept, which parts are read)
+SIDES = {"half_batch": ("float32", (), True, (), False, ("train",)),
+         "expert_left_out": ("float32", (1,), False, (), False, ("train", "player")),
+         "bfloat16_weights": ("bfloat16_weights", (), False, (), False, ("train", "player")),
+         "window_ignored": ("float32", (), False, ("window_ignored",), False, ("losses", "player")),
+         "yarn_left_out": ("float32", (), False, ("yarn_left_out",), False, ("losses", "player")),
+         "ring_kept": ("float32", (), False, (), True, ("player",)),
+         "float8": ("float8", (), False, (), False, ("losses", "player"))}  # fmt: skip
+
+
+def judged(side: str, numbers, limits):
+    """The side's line: its numbers, and what the cell's limits make of them."""
+    from perfbench.correct import judge
+
+    fails = sorted(k for k, v in judge(numbers, {k: limits[k] for k in numbers if k in limits}).items() if not v["ok"])
+    return {"side": side, "correct": not fails, "fails_by": fails, **numbers}
+
+
+def readings(cfg, seed, capture, limits, stamps):
+    import jax
+
+    from perfbench.algorithms import token_ppo_mellum2 as algorithm
+    from perfbench.calibrate_token_ppo import _release
+    from perfbench.references import token_ppo_mellum2 as reference
+
+    ok, compared, not_compared = algorithm.verify(cfg, seed, capture, limits, stamps)
+    print(json.dumps({"side": "program", "correct": ok, **{k: v["value"] for k, v in compared.items()}, **not_compared}), flush=True)
+    # a state returned unchanged reads 1 in ``change`` by construction
+    lines = [judged("state_unchanged", {"change": 1.0}, limits)]
+    print(json.dumps(lines[0]), flush=True)
+    weights = jax.device_put(capture.seeded)
+    m, a = cfg["model"], cfg["algo"]
+    ref_train = algorithm.train_side(cfg, weights, capture.steps)
+    _release()
+    for name, (precision, without, half, faults, kept, parts) in SIDES.items():
+        theirs = reference.cast(weights, precision)
+        numbers = {}
+        if "train" in parts:
+            numbers.update(algorithm.train_gaps(algorithm.train_side(cfg, theirs, capture.steps, without, half, faults), ref_train, capture.seeded))
+        if "losses" in parts:
+            got = reference.losses_only(theirs, m, a, algorithm.aligned_sequences(capture.steps[0]["batch"]), without, faults)
+            want = ref_train["losses"][0]
+            numbers.update({k: abs(got[k] - want[k]) / max(abs(want["policy_scale" if k == "policy_loss" else k]), 1e-12) for k in got if k != "policy_scale"})
+        if "player" in parts:
+            _, arrays = algorithm.player_gaps(m, theirs, capture.player, without=without, faults=faults, ring_kept=kept)
+            numbers.update(algorithm.player_gaps(m, weights, capture.player, against=arrays)[0])
+            del arrays
+        lines.append(judged(name, numbers, limits))
+        print(json.dumps(lines[-1]), flush=True)
+        del theirs
+        _release()
+    passing = [line["side"] for line in lines if line["correct"]]
+    print(json.dumps({"side": "verdict", "program_correct": ok, "controls_and_faults_that_pass": passing}), flush=True)
+    return ok and not passing, compared, not_compared
+
+
+def main() -> None:
+    from perfbench import run
+
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--seconds", type=float, default=10.0)  # the window opens on an update of 7.7 s: it has to reach the rollout behind it
+    args = parser.parse_args()
+    print(json.dumps(run.run_cell(args.workload, args.seed, args.seconds, False, verify=readings)), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -3,8 +3,9 @@
 ``ppo_recurrent``'s sequence machinery with another thing carried from step to
 step. Where the LSTM core carries ``(hx, cx)`` per env, this core carries, for
 each layer, the state its operator declares (``models/seqpol.py``
-``state_shapes``: a cache of positions for an attention layer, the last few
-gated inputs for a convolution layer) and one length per env: one env
+``state_shapes``: a cache of positions for an attention layer, a ring of the
+last ``sliding_window`` positions for a window layer, the last few gated
+inputs for a convolution layer) and one length per env: one env
 step is one token, the reset observation carries the prompt, and the update
 runs teacher-forced over each sequence of the rollout. A sequence that
 continues an episode begun before the rollout starts from that state **as it
@@ -31,6 +32,7 @@ static.
 from __future__ import annotations
 
 import os
+from collections import Counter
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -101,8 +103,8 @@ def make_player_programs(agent: TokenPolicy) -> Dict[str, Any]:
         slots = jnp.arange(tokens.shape[1])[None, :]
         _, own, counters = seqpol.forward_sequence(p, core, tokens, jnp.broadcast_to(slots, tokens.shape), slots < n_prefix[:, None], dtype=dtype)
         # each array of a layer's state takes the rows' own entries from its first position on: a cache the prompt's
-        # slots, a convolution state all it holds. A row index past the last env marks an unused slot of this call:
-        # its write is dropped
+        # slots, a convolution state and a window layer's ring all they hold (the prompt's last entries, a ring's in
+        # ring order). A row index past the last env marks an unused slot of this call: its write is dropped
         state = jax.tree.map(lambda held, new: held.at[rows, : new.shape[1]].set(new.astype(held.dtype), mode="drop"), state, own)
         return state, counters
 
@@ -139,7 +141,10 @@ class TokenPlayer:
                            for layer in seqpol.state_shapes(core, self.num_envs))  # fmt: skip
         self.lengths = np.zeros((self.num_envs,), np.int32)
         self.last_logits: Optional[Array] = None
-        #: counters since the start: rows prefilled, tokens decoded, cache entries the decodes attended to, rows reset
+        #: entries of each layer that keeps positions (a cache: ``context``; a window layer's ring: ``sliding_window``) -> how many such layers
+        self._cache_sizes = Counter(layer[0][1] for i, layer in enumerate(seqpol.state_shapes(core, 1)) if seqpol.OPERATORS[core.operator(i)].key == "attn")
+        #: counters since the start: rows prefilled, tokens decoded, entries the decodes attended to (a mean over the
+        #: layers that keep positions: a window layer attends to ``min(length, sliding_window)``), rows reset
         self.rows_prefilled = 0
         self.tokens_decoded = 0
         self.cache_positions = 0
@@ -201,7 +206,8 @@ class TokenPlayer:
             )
             self.lengths += 1
             self.tokens_decoded += self.num_envs
-            self.cache_positions += int(positions.sum()) + self.num_envs
+            attended = sum(n * int(np.minimum(positions + 1, size).sum()) for size, n in self._cache_sizes.items())
+            self.cache_positions += attended / max(sum(self._cache_sizes.values()), 1)
         return actions, logprobs, values, positions
 
 
@@ -300,6 +306,19 @@ def make_token_train_fn(fabric: Any, agent: TokenPolicy, tx: optax.GradientTrans
         return params, opt_state, params_lo, metrics
 
     return jax.jit(seqpol_train_step, donate_argnums=(0, 1, 2))
+
+
+def window_keys(seqs: Dict[str, np.ndarray], core: seqpol.SeqPolConfig) -> int:
+    """Over the real queries of ``seqs`` (a prompt's prefix and the steps), the
+    keys inside each one's window, summed over the window layers: a query at
+    position ``q`` has ``min(q + 1, sliding_window)``. What the band cannot avoid."""
+    layers = sum(core.operator(i) == seqpol.SLIDING for i in range(core.num_hidden_layers))
+    if not layers:
+        return 0
+    count = (seqs["n0"] - 1) + seqs["mask"].sum(axis=1).astype(np.int64)  # a sequence's real slots lie at len0, len0 + 1, ...
+    at = np.arange(int(count.max(initial=0)))[None, :]
+    keys = np.minimum(seqs["len0"][:, None] + at + 1, core.sliding_window)
+    return layers * int(np.where(at < count[:, None], keys, 0).sum())
 
 
 def token_sequences(local_data: Dict[str, np.ndarray], seq_steps: int, num_envs: int, batch_size: int) -> Dict[str, np.ndarray]:
@@ -418,6 +437,7 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
                                 aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
                                 print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep['r'][i]}")
 
+        wrapped = int((player.lengths >= (agent.core.sliding_window or np.inf)).sum())
         local_data = buf.arrays()
         # the value of the observation the rollout stopped at: one more decode on a copy of the carry, kept out of the cache
         with timer("Time/train_time"):
@@ -440,7 +460,8 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
                 metrics = np.stack(jax.device_get(pending))
         _report(metrics, aggregator if cfg.metric.log_level > 0 else None, core=agent.core,
                 rows_prefilled=player.rows_prefilled - prefilled, tokens_decoded=player.tokens_decoded - decoded,
-                cache_positions=player.cache_positions - attended, rows_reset=player.rows_reset - reset)  # fmt: skip
+                cache_positions=player.cache_positions - attended, rows_reset=player.rows_reset - reset,
+                window_keys=update_epochs * window_keys(seqs, agent.core), ring_wrapped_rows=wrapped)  # fmt: skip
 
         if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
             logger.log_metrics(aggregator.compute(), policy_step)
@@ -457,7 +478,7 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
 
 
 def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, rows_prefilled: int, tokens_decoded: int,
-            cache_positions: int, rows_reset: int) -> None:  # fmt: skip
+            cache_positions: float, rows_reset: int, window_keys: int, ring_wrapped_rows: int) -> None:  # fmt: skip
     """One update's losses into the aggregator and its counters into ``telemetry.jsonl``."""
     mean = dict(zip(METRICS, metrics.mean(0)))
     total = dict(zip(METRICS, metrics.sum(0)))
@@ -479,8 +500,13 @@ def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, 
         padded_positions=float(total["padded_positions"]),
         rows_prefilled=int(rows_prefilled),
         tokens_decoded=int(tokens_decoded),
-        # the rows' lengths summed over the decodes: what each layer that keeps a cache of positions attended to
-        cache_positions=int(cache_positions),
+        # the rows' lengths summed over the decodes: what each layer that keeps positions attended to (a window
+        # layer's ring ``min(length, sliding_window)``; the mean over such layers where they differ)
+        cache_positions=float(cache_positions),
         # convolution states put back to nothing: a row's reset clears one in every convolution layer
         conv_state_resets=int(rows_reset) * sum(core.operator(i) == seqpol.CONV for i in range(core.num_hidden_layers)),
+        # over the update's real queries, the keys inside each one's window, summed over the window layers and the epochs
+        window_keys=int(window_keys),
+        # rows that stood at position ``sliding_window`` or beyond as the rollout ended: their rings had wrapped
+        ring_wrapped_rows=int(ring_wrapped_rows),
     )

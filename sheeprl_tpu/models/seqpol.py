@@ -1,13 +1,17 @@
-"""A decoder-only token policy: RMSNorm, rotary embedding, a layer operator
-chosen layer by layer (multi-head latent attention, grouped-query attention or
-a gated short convolution, each in a whole-sequence and a one-token form),
-SwiGLU, a routed expert layer that is told which experts it holds, the decoder
-block, and the multi-token-prediction module.
+"""A decoder-only token policy: RMSNorm, rotary embedding (one table of
+frequencies a layer type, YaRN's among them), a layer operator chosen layer by
+layer (multi-head latent attention, grouped-query attention over the whole
+context or inside a sliding window, or a gated short convolution, each in a
+whole-sequence and a one-token form), SwiGLU, a routed expert layer that is
+told which experts it holds, the decoder block, and the
+multi-token-prediction module.
 
 The equations are the published forms: DeepSeek-V2/V3's latent attention and
-expert layer (``glm4_moe_lite`` follows them), and ``lfm2_moe``'s gated short
-convolution beside grouped-query attention with normed queries and keys. Every
-size comes from :class:`SeqPolConfig`, nothing is fixed here.
+expert layer (``glm4_moe_lite`` follows them), ``lfm2_moe``'s gated short
+convolution beside grouped-query attention with normed queries and keys, and
+``mellum``'s sliding-window layers beside full-attention layers under YaRN over
+a softmax router. Every size comes from :class:`SeqPolConfig`, nothing is
+fixed here.
 
 Unlike ``blocks.py`` this module is plain functions over one parameter tree
 (nested dicts whose leaves are named ``kernel``, ``embedding``, ``scale`` and
@@ -31,6 +35,14 @@ Grouped-query attention: keys after norm and rotation, and values, ``[E,
 context, key-value heads x head_dim]`` each. The gated short convolution: the
 last ``conv_L_cache`` gated inputs, ``[E, conv_L_cache, D]``; the entries that
 lie before a row's first position read as zero, which is the reset.
+Sliding-window attention: keys and values again, but **a ring** of
+``sliding_window`` entries a row, ``[E, W, key-value heads x head_dim]``:
+position ``p`` lies at entry ``p mod W``, so the row's last ``W`` positions are
+all it keeps, and entry ``j`` of a row whose newest position is ``q`` holds
+position ``q - ((q - j) mod W)`` (:func:`ring_positions`), or nothing of the
+row's episode where that is negative: what the episode before left there is
+thereby unseen, which is this state's reset. A cache of ``context`` positions
+is the ring that never wraps.
 
 The expert layer routes over all ``n_routed_experts`` at the published width
 and computes only the ``held`` experts' part for the tokens routed to them,
@@ -41,7 +53,10 @@ held expert is computed, under whatever imbalance.
 
 from __future__ import annotations
 
+import contextlib
+import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -52,7 +67,24 @@ Array = jax.Array
 Params = Dict[str, Any]
 
 #: a layer's operator, as ``layer_types`` names it (latent attention where a configuration names none)
-LATENT, ATTENTION, CONV = "latent_attention", "full_attention", "conv"
+LATENT, ATTENTION, SLIDING, CONV = "latent_attention", "full_attention", "sliding_attention", "conv"
+
+
+@dataclass(frozen=True)
+class RopeTable:
+    """One layer type's rotary table, under the keys a published
+    ``rope_parameters`` entry has: the default table (``theta^(-i / half)``) or
+    YaRN's (the frequencies under ``low`` rotations of the original context
+    kept, those over ``high`` divided by ``factor``, a ramp between; cosine
+    and sine times ``attention_factor``, so a score carries its square)."""
+
+    rope_theta: float
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max_position_embeddings: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
 
 
 @dataclass(frozen=True)
@@ -78,18 +110,26 @@ class SeqPolConfig:
     qk_nope_head_dim: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
-    #: each layer's operator (:data:`LATENT`, :data:`ATTENTION`, :data:`CONV`); latent attention everywhere if not given
+    #: each layer's operator (:data:`LATENT`, :data:`ATTENTION`, :data:`SLIDING`, :data:`CONV`); latent attention everywhere if not given
     layer_types: Optional[Tuple[str, ...]] = None
     # grouped-query attention's sizes
     num_key_value_heads: Optional[int] = None
     head_dim: Optional[int] = None
+    #: positions a sliding-window layer attends to, the query's own among them: the entries of its ring state
+    sliding_window: Optional[int] = None
     #: gated inputs the short convolution spans, the current one among them
     conv_L_cache: int = 3
     #: the head reads the embedding's rows and the tree has no ``head``
     tie_word_embeddings: bool = False
     #: added to the sum the chosen experts' scores are divided by
     router_eps: float = 1e-20
+    #: the router's scores: ``sigmoid`` (each expert's own, the choice by score plus a correction bias) or
+    #: ``softmax`` (over all experts in float32, no bias)
+    router_scoring: str = "sigmoid"
+    #: the rotary table of every layer type that ``rope_parameters`` does not name: the table with one entry
     rope_theta: float = 1e6
+    #: ``(layer type, its table)``: a model whose layer types rotate by different tables
+    rope_parameters: Optional[Tuple[Tuple[str, RopeTable], ...]] = None
     rms_norm_eps: float = 1e-5
     #: routed pairs up to which the expert layer multiplies every held expert
     #: with every token under a mask (a decode step); above it pairs are sorted
@@ -105,6 +145,10 @@ class SeqPolConfig:
     def operator(self, layer: int) -> str:
         return self.layer_types[layer] if self.layer_types else LATENT
 
+    def rope(self, kind: str) -> RopeTable:
+        """The rotary table of the layers of ``kind``."""
+        return dict(self.rope_parameters or ()).get(kind) or RopeTable(rope_theta=self.rope_theta)
+
 
 def config_from(node: Any) -> SeqPolConfig:
     """``algo.core`` of a composed recipe (a mapping) as a :class:`SeqPolConfig`."""
@@ -113,6 +157,8 @@ def config_from(node: Any) -> SeqPolConfig:
     fields["held_experts"] = tuple(int(e) for e in fields["held_experts"])
     if "layer_types" in fields:
         fields["layer_types"] = tuple(str(t) for t in fields["layer_types"])
+    if "rope_parameters" in fields:
+        fields["rope_parameters"] = tuple((str(kind), RopeTable(**dict(table))) for kind, table in dict(fields["rope_parameters"]).items())
     return SeqPolConfig(**fields)
 
 
@@ -171,13 +217,14 @@ def _layer_params(key: Array, cfg: SeqPolConfig, dense: bool, kind: str = LATENT
         return layer
     n_held, inner = len(cfg.held_experts), cfg.moe_intermediate_size
     layer["moe"] = {
-        # the router keeps its published width; its bias is e_score_correction_bias
-        "router": {"kernel": 0.02 * jax.random.normal(k[6], (d, cfg.n_routed_experts), jnp.float32),
-                   "bias": jnp.zeros((cfg.n_routed_experts,), jnp.float32)},  # fmt: skip
+        # the router keeps its published width
+        "router": {"kernel": 0.02 * jax.random.normal(k[6], (d, cfg.n_routed_experts), jnp.float32)},
         # the held experts' weights, stacked on a leading axis in the order of ``held_experts``
         "experts": {name: {"kernel": 0.02 * jax.random.normal(kk, (n_held, *shape), jnp.float32)}
                     for name, kk, shape in (("gate", k[7], (d, inner)), ("up", k[8], (d, inner)), ("down", k[9], (inner, d)))},  # fmt: skip
     }
+    if cfg.router_scoring == "sigmoid":  # e_score_correction_bias; a softmax router has none
+        layer["moe"]["router"]["bias"] = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
     if cfg.n_shared_experts:
         layer["moe"]["shared"] = _swiglu_params(k[5], d, inner * cfg.n_shared_experts)
     return layer
@@ -252,13 +299,38 @@ def rms_norm(x: Array, scale: Array, eps: float) -> Array:
     return (xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * scale).astype(x.dtype)
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
+def rope_frequencies(table: RopeTable, dim: int) -> Tuple[Array, float]:
+    """``(inv_freq [dim / 2], what cosine and sine are multiplied by)`` of a
+    head of ``dim`` rotated dims. YaRN's, with ``f_i`` the default frequency
+    and ``c(r) = dim ln(original context / (2 pi r)) / (2 ln theta)`` the dim
+    that turns ``r`` times over the original context: ``f_i`` below ``low =
+    floor(c(beta_fast))``, ``f_i / factor`` above ``high = ceil(c(beta_slow))``,
+    a linear ramp between; fixed, whatever the sequence's length."""
+    half = dim // 2
+    inv_freq = table.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if table.rope_type == "default":
+        return inv_freq, 1.0
+    if table.rope_type != "yarn":
+        raise ValueError(f"no rotary table of type {table.rope_type!r}")
+
+    def turns_at(rotations: float) -> float:
+        return dim * math.log(table.original_max_position_embeddings / (rotations * 2 * math.pi)) / (2 * math.log(table.rope_theta))
+
+    low, high = max(math.floor(turns_at(table.beta_fast)), 0), min(math.ceil(turns_at(table.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    factor = table.attention_factor if table.attention_factor is not None else 0.1 * math.log(table.factor) + 1.0
+    return inv_freq / table.factor * ramp + inv_freq * (1.0 - ramp), float(factor)
+
+
+def rope(x: Array, positions: Array, table: RopeTable) -> Array:
     """Rotary embedding of the last axis by ``positions`` (broadcast against
-    ``x``'s leading axes): pairs are ``(i, i + d/2)``, all ``d`` dims rotated."""
+    ``x``'s leading axes) under ``table``: pairs are ``(i, i + d/2)``, all ``d`` dims rotated."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    inv_freq, factor = rope_frequencies(table, x.shape[-1])
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
@@ -277,14 +349,14 @@ def _queries(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[
     c_q = rms_norm(_mm(x, p["q_a"]["kernel"]), p["q_norm"]["scale"], cfg.rms_norm_eps)
     q = _mm(c_q, p["q_b"]["kernel"]).reshape(*x.shape[:-1], cfg.num_attention_heads, cfg.qk_head_dim)
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    return q_nope, rope(q_rope, positions[..., None], cfg.rope_theta)
+    return q_nope, rope(q_rope, positions[..., None], cfg.rope(LATENT))
 
 
 def latent_kv(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[Array, Array]:
     """What the cache holds of ``x``: ``c_kv`` after its norm, ``k_rope`` after its rotation."""
     kv = _mm(x, p["kv_a"]["kernel"])
     c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], p["kv_norm"]["scale"], cfg.rms_norm_eps)
-    return c_kv, rope(kv[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+    return c_kv, rope(kv[..., cfg.kv_lora_rank :], positions, cfg.rope(LATENT))
 
 
 def _kv_b(p: Params, cfg: SeqPolConfig, dtype: Any) -> Tuple[Array, Array]:
@@ -302,33 +374,68 @@ QUERY_BLOCK = 128
 Context = Tuple[Tuple[Array, ...], Array, Array]
 
 
+#: rows of up to this many slots whose length is no multiple of :data:`QUERY_BLOCK` are scored in one block (the
+#: scores of a few hundred queries fit); longer ones in whole blocks and a last shorter one
+WHOLE_UP_TO = 1024
+
+
 def _by_query_blocks(block: Any, queries: Tuple[Array, ...], B: int, S: int) -> Array:
     """``block(*queries of a block, their slots)`` over blocks of
-    :data:`QUERY_BLOCK` queries, under ``jax.checkpoint``; ``[B, S, ...]``."""
+    :data:`QUERY_BLOCK` queries, under ``jax.checkpoint``; ``[B, S, ...]``.
+    A length that is no multiple of the block is scored whole up to
+    :data:`WHOLE_UP_TO` slots, and beyond in whole blocks with a last shorter
+    one behind them."""
     block = jax.checkpoint(block)
-    n = max(1, S // QUERY_BLOCK) if S % QUERY_BLOCK == 0 else 1
-    if n == 1:
+    n, rest = divmod(S, QUERY_BLOCK)
+    if n <= 1 or (rest and S <= WHOLE_UP_TO):
         return block(*queries, jnp.arange(S))
-    split = lambda a: jnp.moveaxis(a.reshape(B, n, S // n, *a.shape[2:]), 1, 0)  # noqa: E731
-    out = lax.map(lambda t: block(*t), (*(split(q) for q in queries), jnp.arange(S).reshape(n, S // n)))
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, *out.shape[3:])
+    whole = n * QUERY_BLOCK
+    split = lambda a: jnp.moveaxis(a[:, :whole].reshape(B, n, QUERY_BLOCK, *a.shape[2:]), 1, 0)  # noqa: E731
+    out = lax.map(lambda t: block(*t), (*(split(q) for q in queries), jnp.arange(whole).reshape(n, QUERY_BLOCK)))
+    out = jnp.moveaxis(out, 0, 1).reshape(B, whole, *out.shape[3:])
+    if not rest:
+        return out
+    return jnp.concatenate([out, block(*(q[:, whole:] for q in queries), jnp.arange(whole, S))], axis=1)
 
 
-def _with_context(ctx: Optional[Context], own: Tuple[Array, ...], valid: Array) -> Tuple[Tuple[Array, ...], Array, Array]:
+def ring_positions(newest: Array, size: int) -> Array:
+    """``[rows, size]``: the position that entry ``j`` of a ring of ``size``
+    entries holds for a row whose newest position is ``newest [rows]``
+    (position ``p`` lies at entry ``p mod size``): ``newest - ((newest - j) mod
+    size)``; negative where the entry holds nothing of the row's episode. A
+    cache of ``context`` positions is the ring that never wraps: entry ``j``
+    holds position ``j``, or nothing."""
+    return newest[:, None] - jnp.mod(newest[:, None] - jnp.arange(size)[None, :], size)
+
+
+def ring_seen(positions: Array, size: int) -> Array:
+    """``[rows, size]``: which entries of a ring a query at ``positions
+    [rows]``, whose own entry is written, may see: those that hold a position
+    of its episode (:func:`ring_positions` is not negative there): the entries
+    up to its own, and all of them once the ring has wrapped."""
+    return (jnp.arange(size)[None, :] <= positions[:, None]) | (positions[:, None] >= size)
+
+
+def _with_context(ctx: Optional[Context], own: Tuple[Array, ...], valid: Array,
+                  positions: Array) -> Tuple[Tuple[Array, ...], Array, Array, Array]:  # fmt: skip
     """The keys a row's queries may see: its own entries ``own`` (``[B, S,
-    .]`` each) behind the first ``length`` entries of the cache row it
-    continues. Returns the joined entries, which of them exist, and each
-    one's slot: a key is seen by the queries at or after it, by slot among the
-    row's own, always for the cache (slot ``-1``)."""
+    .]`` each, at ``positions [B, S]``) behind the entries of the cache row it
+    continues, a ring of ``C`` entries that held ``length`` positions: the
+    first ``length`` of them, all once it had wrapped. Returns the joined
+    entries, which of them exist, each one's slot (a key is seen by the
+    queries at or after it, by slot among the row's own, always for the cache:
+    slot ``-1``) and each one's position (:func:`ring_positions` for the
+    cache's: what a window is counted in)."""
     B, S = valid.shape
     key_slot = jnp.broadcast_to(jnp.arange(S), (B, S))
     if ctx is None:
-        return own, valid, key_slot
+        return own, valid, key_slot, positions
     state, row, length = ctx
     C = state[0].shape[1]
     keys = tuple(jnp.concatenate([c[row].astype(o.dtype), o], axis=1) for c, o in zip(state, own))
     key_ok = jnp.concatenate([jnp.arange(C)[None, :] < length[:, None], valid], axis=1)
-    return keys, key_ok, jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1)
+    key_position = jnp.concatenate([ring_positions(length - 1, C), positions], axis=1)
+    return keys, key_ok, jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1), key_position
 
 
 def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
@@ -344,7 +451,7 @@ def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid
     H = cfg.num_attention_heads
     q_nope, q_rope = _queries(p, cfg, x, positions)
     c_kv, k_rope = latent_kv(p, cfg, x, positions)
-    (keys_c, keys_r), key_ok, key_slot = _with_context(ctx, (c_kv, k_rope), valid)
+    (keys_c, keys_r), key_ok, key_slot, _ = _with_context(ctx, (c_kv, k_rope), valid, positions)
     w_k, w_v = _kv_b(p, cfg, x.dtype)
     k_nope = jnp.einsum("bkc,chd->bkhd", keys_c, w_k)
     v = jnp.einsum("bkc,chd->bkhd", keys_c, w_v)
@@ -386,60 +493,97 @@ def mla_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: 
     return _mm(out.reshape(E, -1), p["o"]["kernel"]), (cache_c, cache_r)
 
 
-def _gqa_qkv(p: Params, cfg: SeqPolConfig, x: Array, positions: Array) -> Tuple[Array, Array, Array]:
+def _gqa_qkv(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, table: RopeTable) -> Tuple[Array, Array, Array]:
     """Queries ``[..., H, d]``, keys ``[..., G, d]`` (both RMS-normed over the
-    head's dims, then rotated on all of them) and values ``[..., G, d]``."""
+    head's dims, then rotated on all of them under ``table``) and values ``[..., G, d]``."""
     H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     lead = x.shape[:-1]
     q = rms_norm(_mm(x, p["q"]["kernel"]).reshape(*lead, H, hd), p["q_norm"]["scale"], cfg.rms_norm_eps)
     k = rms_norm(_mm(x, p["k"]["kernel"]).reshape(*lead, G, hd), p["k_norm"]["scale"], cfg.rms_norm_eps)
     v = _mm(x, p["v"]["kernel"]).reshape(*lead, G, hd)
-    return rope(q, positions[..., None], cfg.rope_theta), rope(k, positions[..., None], cfg.rope_theta), v
+    return rope(q, positions[..., None], table), rope(k, positions[..., None], table), v
+
+
+def _window(cfg: SeqPolConfig, kind: str) -> Optional[int]:
+    """The window of a grouped-query layer of ``kind``: ``None`` where it attends to everything."""
+    return cfg.sliding_window if kind == SLIDING else None
+
+
+def _core_scope(window: Optional[int]):
+    """A window layer's scores, softmax and weighted sum lie under ``seqpol/attn/window``, its projections do not."""
+    return jax.named_scope("window") if window else contextlib.nullcontext()
+
+
+def _ring_of(entries: Tuple[Array, ...], positions: Array, valid: Array, size: int) -> Tuple[Array, ...]:
+    """The ring a prefill of these rows leaves: of each row's ``entries [B, S,
+    .]`` (one run of real slots a row, at ``positions``) the last ``size``, the
+    one at position ``p`` at entry ``p mod size``; zero where the row has no
+    such position."""
+    last = jnp.argmax(valid, axis=1) + valid.sum(axis=1) - 1  # the slot of each row's newest position
+    newest = jnp.take_along_axis(positions, jnp.maximum(last, 0)[:, None], axis=1)[:, 0]
+    held = ring_positions(jnp.where(valid.any(axis=1), newest, -1), size)
+    slot = jnp.maximum(last[:, None] - (newest[:, None] - held), 0)
+    return tuple(jnp.where((held >= 0)[..., None], jnp.take_along_axis(e, slot[..., None], axis=1), 0) for e in entries)
 
 
 def gqa_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
-                 ctx: Optional[Context] = None) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
+                 ctx: Optional[Context] = None, *, kind: str = ATTENTION) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
     """Whole-sequence grouped-query attention, with :func:`mla_sequence`'s
     arguments: query head ``i`` reads key-value head ``i // (H / G)``. ``ctx``'s
-    state is ``(keys [E, C, G x d], values [E, C, G x d])``. Returns the output
-    and the rows' own keys (after norm and rotation) and values, ``[B, S, G x d]``."""
+    state is ``(keys [E, C, G x d], values [E, C, G x d])``. A layer of ``kind``
+    :data:`SLIDING` masks by position too: a query at ``q`` sees the keys at
+    ``q - W < p <= q``, among the row's own and in the ring it continues, whose
+    entries it stops seeing one by one. Returns the output and the rows' own
+    keys (after norm and rotation) and values: ``[B, S, G x d]``, or for a
+    window layer the ring a prefill leaves, ``[B, W, G x d]`` (:func:`_ring_of`)."""
     B, S, _ = x.shape
     H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q, k, v = _gqa_qkv(p, cfg, x, positions)
+    window = _window(cfg, kind)
+    q, k, v = _gqa_qkv(p, cfg, x, positions, cfg.rope(kind))
     own = (k.reshape(B, S, G * hd), v.reshape(B, S, G * hd))
-    (keys, values), key_ok, key_slot = _with_context(ctx, own, valid)
+    (keys, values), key_ok, key_slot, key_position = _with_context(ctx, own, valid, positions)
     keys, values = keys.reshape(B, -1, G, hd), values.reshape(B, -1, G, hd)
 
-    def block(qb, q_slot):
+    def block(qb, *at):  # under a window the queries' positions, then their slots
+        q_slot = at[-1]
         qb = qb.reshape(B, -1, G, H // G, hd)
         s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, keys, preferred_element_type=jnp.float32)
         seen = key_ok[:, None, None, None, :] & (key_slot[:, None, None, None, :] <= q_slot[None, None, None, :, None])
+        if window:
+            q_position = at[0]
+            seen = seen & (q_position[:, None, None, :, None] - key_position[:, None, None, None, :] < window)
         w = jax.nn.softmax(jnp.where(seen, s * hd**-0.5, -1e30), axis=-1)
         return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(x.dtype), values).reshape(B, -1, H * hd)
 
-    return _mm(_by_query_blocks(block, (q,), B, S), p["o"]["kernel"]), own
+    with _core_scope(window):
+        out = _by_query_blocks(block, (q, positions) if window else (q,), B, S)
+    return _mm(out, p["o"]["kernel"]), _ring_of(own, positions, valid, window) if window else own
 
 
-def gqa_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array, Array]) -> Tuple[Array, Tuple[Array, Array]]:
+def gqa_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array, Array],
+               *, kind: str = ATTENTION) -> Tuple[Array, Tuple[Array, Array]]:  # fmt: skip
     """One token per row against one layer's key-value cache, ``state = (keys
-    [E, C, G x d], values [E, C, G x d])``, with :func:`mla_decode`'s arguments.
+    [E, C, G x d], values [E, C, G x d])``, with :func:`mla_decode`'s arguments:
+    a ring of ``C`` entries, the whole context's (it never wraps) or a window
+    layer's ``W``. The row's entry is written at ``position mod C``, in place,
+    and the row sees the entries that hold its episode (:func:`ring_seen`).
     Every query head is laid out over the cache's whole width, zero outside its
     own key-value head's dims: the scores and the output are then products with
     the cache entries as they lie (what the zeros add is exactly nothing), and
     no head is sliced or transposed out of the cache."""
     cache_k, cache_v = state
-    E = x.shape[0]
+    E, C = x.shape[0], cache_k.shape[1]
     H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q, k, v = _gqa_qkv(p, cfg, x, positions)
-    rows = jnp.arange(E)
-    cache_k = cache_k.at[rows, positions].set(k.reshape(E, G * hd).astype(cache_k.dtype), mode="drop")
-    cache_v = cache_v.at[rows, positions].set(v.reshape(E, G * hd).astype(cache_v.dtype), mode="drop")
+    q, k, v = _gqa_qkv(p, cfg, x, positions, cfg.rope(kind))
+    rows, at = jnp.arange(E), positions % C
+    cache_k = cache_k.at[rows, at].set(k.reshape(E, G * hd).astype(cache_k.dtype), mode="drop")
+    cache_v = cache_v.at[rows, at].set(v.reshape(E, G * hd).astype(cache_v.dtype), mode="drop")
     own = (jnp.arange(H)[:, None] // (H // G) == jnp.arange(G)[None, :])[None, :, :, None]  # [1, H, G, 1]
     q_wide = jnp.where(own, q[:, :, None, :], 0).reshape(E, H, G * hd)
-    s = jnp.einsum("ehc,etc->eht", q_wide, cache_k.astype(x.dtype), preferred_element_type=jnp.float32)
-    seen = jnp.arange(cache_k.shape[1])[None, None, :] <= positions[:, None, None]
-    w = jax.nn.softmax(jnp.where(seen, s * hd**-0.5, -1e30), axis=-1)
-    o_wide = jnp.einsum("eht,etc->ehc", w.astype(x.dtype), cache_v.astype(x.dtype))
+    with _core_scope(_window(cfg, kind)):
+        s = jnp.einsum("ehc,etc->eht", q_wide, cache_k.astype(x.dtype), preferred_element_type=jnp.float32)
+        w = jax.nn.softmax(jnp.where(ring_seen(positions, C)[:, None, :], s * hd**-0.5, -1e30), axis=-1)
+        o_wide = jnp.einsum("eht,etc->ehc", w.astype(x.dtype), cache_v.astype(x.dtype))
     out = jnp.where(own, o_wide.reshape(E, H, G, hd), 0).sum(2)
     return _mm(out.reshape(E, H * hd), p["o"]["kernel"]), (cache_k, cache_v)
 
@@ -532,6 +676,8 @@ OPERATORS: Dict[str, Operator] = {
                      lambda cfg: ((cfg.context, cfg.kv_lora_rank), (cfg.context, cfg.qk_rope_head_dim))),
     ATTENTION: Operator("attn", "seqpol/attn", _gqa_params, gqa_sequence, gqa_decode,
                         lambda cfg: ((cfg.context, cfg.num_key_value_heads * cfg.head_dim),) * 2),
+    SLIDING: Operator("attn", "seqpol/attn", _gqa_params, partial(gqa_sequence, kind=SLIDING), partial(gqa_decode, kind=SLIDING),
+                      lambda cfg: ((cfg.sliding_window, cfg.num_key_value_heads * cfg.head_dim),) * 2),
     CONV: Operator("conv", "seqpol/conv", _conv_params, conv_sequence, conv_decode, lambda cfg: ((cfg.conv_L_cache, cfg.hidden_size),)),
 }  # fmt: skip
 
@@ -548,11 +694,14 @@ def state_shapes(cfg: SeqPolConfig, rows: int) -> Tuple[Tuple[Tuple[int, ...], .
 
 
 def route(p: Params, cfg: SeqPolConfig, x: Array) -> Tuple[Array, Array]:
-    """``(expert ids [T, k], weights [T, k])`` over all ``n_routed_experts``:
-    sigmoid scores in float32, the choice by score plus correction bias, the
-    weights the scores themselves at the chosen experts, normalised and scaled."""
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["router"]["kernel"], precision=lax.Precision.HIGHEST))
-    _, chosen = lax.top_k(scores + p["router"]["bias"], cfg.num_experts_per_tok)
+    """``(expert ids [T, k], weights [T, k])`` over all ``n_routed_experts``,
+    the scores in float32 by ``cfg.router_scoring``: each expert's sigmoid or a
+    softmax over all of them; the choice by score (plus the correction bias,
+    where the tree has one); the weights the scores themselves at the chosen
+    experts, normalised and scaled."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"]["kernel"], precision=lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1) if cfg.router_scoring == "softmax" else jax.nn.sigmoid(logits)
+    _, chosen = lax.top_k(scores + p["router"]["bias"] if "bias" in p["router"] else scores, cfg.num_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
         weights = weights / (weights.sum(-1, keepdims=True) + cfg.router_eps)

@@ -1,11 +1,15 @@
 """The decoder core of recurrent PPO (a policy over tokens) against the plain
-references, tiny on the CPU in float32, for both models it runs: ``glm`` (the
-ratios of GLM-4.7-Flash: latent attention in every layer, 1 dense + 2 expert
-layers, 8 routed experts of which 4 a token, 1 shared, every latent and head
-dim distinct, the multi-token-prediction module on) and ``lfm2`` (the ratios
+references, tiny on the CPU in float32, for the three models it runs: ``glm``
+(the ratios of GLM-4.7-Flash: latent attention in every layer, 1 dense + 2
+expert layers, 8 routed experts of which 4 a token, 1 shared, every latent and
+head dim distinct, the multi-token-prediction module on), ``lfm2`` (the ratios
 of LFM2-24B-A2B: a gated short convolution over the dense layer, then
 grouped-query attention and a convolution over expert layers with no shared
-expert, 2 query heads a key-value head, embedding and head tied). Every
+expert, 2 query heads a key-value head, embedding and head tied) and
+``mellum2`` (the ratios of Mellum2-12B-A2.5B: two sliding-window layers whose
+state is a ring of 8 positions a row and a full-attention layer under the
+published YaRN table, every layer over 8 experts of which 2 a token by a
+softmax, no dense layer, no shared expert, embedding and head untied). Every
 tolerance is float32 round-off (readings are 1e-6 or under) with room for the
 order of sums; the same numbers computed with bfloat16 operands read 1e-2 and
 fail each of them, which ``test_bfloat16_fails_the_tolerances`` holds.
@@ -23,6 +27,7 @@ import pytest
 
 from perfbench.references import token_ppo as reference
 from perfbench.references import token_ppo_lfm2 as reference_lfm2
+from perfbench.references import token_ppo_mellum2 as reference_mellum2
 from sheeprl_tpu.algos.ppo_recurrent import token_policy
 from sheeprl_tpu.cli import run
 from sheeprl_tpu.models import seqpol
@@ -36,25 +41,30 @@ LFM2_SIZES = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, 
                   held_experts=[0, 1, 2, 3], num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_prob=True,
                   router_eps=1e-6, first_k_dense_replace=1, num_hidden_layers=3, num_nextn_predict_layers=0, tie_word_embeddings=True,
                   vocab_rows=24, context=32, rope_theta=1e6, rms_norm_eps=1e-5)  # fmt: skip
+ROPE_PARAMETERS = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000.0, "factor": 16.0, "original_max_position_embeddings": 8192,
+                                      "beta_fast": 32.0, "beta_slow": 1.0, "attention_factor": 1.2772588722239782},
+                   "sliding_attention": {"rope_type": "default", "rope_theta": 500000.0}}  # fmt: skip
+MELLUM2_SIZES = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8, sliding_window=8,
+                     layer_types=["sliding_attention", "sliding_attention", "full_attention"], rope_parameters=ROPE_PARAMETERS,
+                     intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8, held_experts=[0, 1, 2, 3], num_experts_per_tok=2,
+                     n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_prob=True, router_scoring="softmax", first_k_dense_replace=0,
+                     num_hidden_layers=3, num_nextn_predict_layers=0, tie_word_embeddings=False, vocab_rows=24, context=32,
+                     rms_norm_eps=1e-6)  # fmt: skip
 #: float32 round-off at these widths reads 1e-6; a bfloat16 operand anywhere reads 1e-2
 TOL = 2e-5
 VOCAB, CONTEXT = SIZES["vocab_rows"], SIZES["context"]
 
 
 def core(sizes=SIZES, **changes):
-    sizes = {**sizes, **changes}
-    for key in ("held_experts", "layer_types"):
-        if key in sizes:
-            sizes[key] = tuple(sizes[key])
-    return seqpol.SeqPolConfig(**sizes)
+    return seqpol.config_from({**sizes, **changes})
 
 
 class Model:
-    """One of the two models: its tiny sizes, its plain reference and its seeded weights."""
+    """One of the three models: its tiny sizes, its plain reference and its seeded weights."""
 
     def __init__(self, name):
         self.name = name
-        self.sizes, self.reference = {"glm": (SIZES, reference), "lfm2": (LFM2_SIZES, reference_lfm2)}[name]
+        self.sizes, self.reference = {"glm": (SIZES, reference), "lfm2": (LFM2_SIZES, reference_lfm2), "mellum2": (MELLUM2_SIZES, reference_mellum2)}[name]
         self.weights = self.reference.init_weights({"model": self.sizes}, 3)
         self._forward = jax.jit(lambda w, tokens: self.reference.forward(w, self.sizes, tokens)[:2])
         self.mtp_coef = 0.1 if self.sizes["num_nextn_predict_layers"] else 0.0
@@ -73,7 +83,7 @@ class Model:
         return self.reference.losses_only(self.weights, self.sizes, a, aligned)
 
 
-@pytest.fixture(scope="module", params=["glm", "lfm2"])
+@pytest.fixture(scope="module", params=["glm", "lfm2", "mellum2"])
 def model(request):
     return Model(request.param)
 
@@ -121,11 +131,54 @@ def conv_state_kept():
         seqpol.conv_in_episode, seqpol.OPERATORS[seqpol.CONV] = real_before, real_op
 
 
+@contextlib.contextmanager
+def _config_changed(change):
+    """While open, every decoder core is built from ``change(its configuration)``."""
+    real = seqpol.config_from
+    seqpol.config_from = lambda node: change(real(node))
+    try:
+        yield
+    finally:
+        seqpol.config_from = real
+
+
+def window_ignored():
+    """A planted fault: the window layers attend to the whole episode (a ring of ``context`` entries never wraps, and no key is a window behind its query)."""
+    return _config_changed(lambda cfg: dataclasses.replace(cfg, sliding_window=cfg.context if cfg.sliding_window else None))
+
+
+def yarn_left_out():
+    """A planted fault: the full-attention layers are rotated by the window layers' default table, no attention factor."""
+    return _config_changed(lambda cfg: dataclasses.replace(cfg, rope_parameters=tuple(
+        (kind, cfg.rope(seqpol.SLIDING) if kind == seqpol.ATTENTION else table) for kind, table in cfg.rope_parameters or ())))  # fmt: skip
+
+
+@contextlib.contextmanager
+def ring_kept():
+    """A planted fault: a reset leaves the episode before in a window layer's
+    ring (every entry is seen, whatever position it holds), and a prefill
+    does not overwrite it (its own entries for that state are none)."""
+    real_seen, real_op = seqpol.ring_seen, seqpol.OPERATORS[seqpol.SLIDING]
+
+    def sequence(p, cfg, *args, **kwargs):
+        out, own = real_op.sequence(p, cfg, *args, **kwargs)
+        return out, tuple(e[:, :0] for e in own)
+
+    # a ring is the state that can wrap: a cache of ``context`` positions holds a row's every position
+    seqpol.ring_seen = lambda positions, size: real_seen(positions, size) | (size < 16)
+    seqpol.OPERATORS[seqpol.SLIDING] = dataclasses.replace(real_op, sequence=sequence)
+    try:
+        yield
+    finally:
+        seqpol.ring_seen, seqpol.OPERATORS[seqpol.SLIDING] = real_seen, real_op
+
+
 def test_the_programs_own_weights_have_the_references_tree(model):
     ours = seqpol.init_params(jax.random.PRNGKey(0), model.core())
     assert jax.tree.structure(ours) == jax.tree.structure(model.weights)
     assert [a.shape for a in jax.tree.leaves(ours)] == [b.shape for b in jax.tree.leaves(model.weights)]
-    assert ("head" in ours) == (model.name == "glm")  # tied: the head is the embedding's rows
+    assert ("head" in ours) == (model.name != "lfm2")  # tied: the head is the embedding's rows
+    assert ("bias" in ours["layers"]["1"]["moe"]["router"]) == (model.name != "mellum2")  # a softmax router has no correction bias
 
 
 def test_every_leaf_resolves_under_the_partition_rules(model):
@@ -149,26 +202,28 @@ def test_whole_sequence_equals_the_reference(model, grouped):
     for b in range(2):
         ref_logits, ref_values = model.forward(tokens[b])
         assert gap(logits[b], ref_logits) < TOL and gap(values[b], ref_values) < TOL
-    assert counters[0] == 2 * 12 * 4 * 2 and 0 < counters[1] < counters[0]  # pairs routed in 2 expert layers, and those on held experts
+    expert_layers = model.sizes["num_hidden_layers"] - model.sizes["first_k_dense_replace"]
+    assert counters[0] == 2 * 12 * model.sizes["num_experts_per_tok"] * expert_layers and 0 < counters[1] < counters[0]  # pairs routed, and those on held experts
     # what each layer's operator declares of its state is what the rows' own entries fit into
     for entries, shapes in zip(own, seqpol.state_shapes(model.core(), 2)):
         assert [e.shape[2:] for e in entries] == [s[2:] for s in shapes] and all(e.shape[1] in (12, s[1]) for e, s in zip(entries, shapes))
 
 
-def _play(model, steps=12):
+def _play(model, steps=12, prompt=(2, 6)):
     """Prefill, then decoding through the state, row 1 reset in the middle to a
     new prompt and row 2 to a prompt of one token (which no prefill touches);
-    returns the worst gap of the logits and of the values to the reference's
-    full forward from each episode's first token."""
+    prompts of ``prompt[0]`` to ``prompt[1]`` tokens; returns the worst gap of
+    the logits and of the values to the reference's full forward from each
+    episode's first token."""
     rng = np.random.default_rng(1)
-    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=prompt[1], dtype=jnp.float32)
     player = token_policy.TokenPlayer(agent, model.weights, num_envs=3, prefill_rows=2)
     key = jax.random.PRNGKey(0)
     inputs = [[], [], []]  # every token each row's policy has been fed, episode by episode
-    obs_tokens, n_tokens = np.zeros((3, 6), np.int32), np.zeros((3,), np.int32)
+    obs_tokens, n_tokens = np.zeros((3, prompt[1]), np.int32), np.zeros((3,), np.int32)
 
     def reset(row, n=None):
-        n = int(rng.integers(2, 7)) if n is None else n
+        n = int(rng.integers(prompt[0], prompt[1] + 1)) if n is None else n
         obs_tokens[row, :n], n_tokens[row] = rng.integers(0, VOCAB, n), n
         inputs[row] = [int(t) for t in obs_tokens[row, :n]]
 
@@ -180,7 +235,7 @@ def _play(model, steps=12):
         logits = np.asarray(player.last_logits)
         for row in range(3):
             assert positions[row] == len(inputs[row]) - 1
-            ref_logits, ref_values = model.forward(inputs[row], size=24)
+            ref_logits, ref_values = model.forward(inputs[row], size=prompt[1] + steps + 6)
             worst_logits = max(worst_logits, gap(logits[row], ref_logits[-1]))
             worst_values = max(worst_values, abs(float(values[row]) - float(ref_values[-1])) / max(1.0, abs(float(ref_values[-1]))))
         dones = np.zeros((3,), bool)
@@ -200,6 +255,21 @@ def _play(model, steps=12):
 def test_prefill_then_decode_across_a_reset_equals_the_reference(model):
     worst_logits, worst_values = _play(model)
     assert worst_logits < TOL and worst_values < TOL
+
+
+def test_a_prompt_longer_than_the_window_is_prefilled_then_decoded():
+    """Prompts of 9 to 12 tokens behind a window of 8: the prefill leaves the ring wrapped, in ring order, and the
+    decodes behind it, across a reset and past a second wrap, read it as the reference's banded forward reads the episode."""
+    worst_logits, worst_values = _play(Model("mellum2"), prompt=(9, 12))
+    assert worst_logits < TOL and worst_values < TOL
+
+
+@pytest.mark.parametrize("fault, prompt", [(window_ignored, (2, 6)), (yarn_left_out, (2, 6)), (ring_kept, (2, 6)), (ring_kept, (9, 12))],
+                         ids=["window_ignored", "yarn_left_out", "ring_kept", "ring_kept-long_prompts"])  # fmt: skip
+def test_a_planted_fault_of_the_window_layers_is_seen(fault, prompt):
+    with fault():
+        worst_logits, _ = _play(Model("mellum2"), prompt=prompt)
+    assert worst_logits > 1000 * TOL
 
 
 def test_a_convolution_state_that_survives_a_reset_is_seen():
@@ -229,6 +299,83 @@ def test_the_convolution_token_by_token_equals_the_whole_sequence_form():
     assert gap(later[1], out[0, 5:]) < TOL
     alone, _ = seqpol.conv_sequence(p, cfg, x[1:, 5:], positions[:1, 5:], valid[:1])
     assert gap(later[0], alone[0]) < TOL
+
+
+def test_the_window_layer_token_by_token_through_its_ring_equals_the_whole_sequence_form():
+    """One token at a time through the ring past two wraps, the whole rows at once under the band, the reference's
+    banded attention, and rows that continue from a wrapped ring: one result."""
+    cfg, p = core(MELLUM2_SIZES), reference_mellum2.init_weights({"model": MELLUM2_SIZES}, 3)["layers"]["0"]["attn"]
+    B, S, D, W = 2, 20, MELLUM2_SIZES["hidden_size"], MELLUM2_SIZES["sliding_window"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (B, S, D))
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    out, ring = seqpol.gqa_sequence(p, cfg, x, positions, jnp.ones((B, S), bool), kind=seqpol.SLIDING)
+    for b in range(B):
+        assert gap(out[b], reference_mellum2.attention(p, MELLUM2_SIZES, x[b], positions[b], seqpol.SLIDING)[0]) < TOL
+    state = tuple(jnp.full((B, W, ring[0].shape[-1]), 7.0) for _ in ring)  # what an episode before left: unseen all the same
+    for t in range(S):
+        step, state = seqpol.gqa_decode(p, cfg, x[:, t], jnp.full((B,), t), state, kind=seqpol.SLIDING)
+        assert gap(step, out[:, t]) < TOL, t
+        if t == 10:
+            halfway = state  # 11 positions: the ring has wrapped, entries 0..2 hold positions 8..10
+    for held, left in zip(state, ring):
+        assert held.shape == left.shape == (B, W, 16) and gap(held, left) < TOL  # what a prefill of these rows leaves is what decoding them leaves
+    assert seqpol.ring_positions(jnp.asarray([10, 3, -1]), W).tolist() == [[8, 9, 10, 3, 4, 5, 6, 7], [0, 1, 2, 3, -4, -3, -2, -1], [-8, -7, -6, -5, -4, -3, -2, -1]]
+    # the rows' last 9 positions again, swapped: the second continues row 0 of the ring after 11 positions and stops
+    # seeing its entries one by one, the first begins there, from nothing, whatever row 1 of the ring holds
+    valid = jnp.ones((B, S - 11), bool)
+    alone, _ = seqpol.gqa_sequence(p, cfg, x[1:, 11:], positions[:1, 11:] - 11, valid[:1], kind=seqpol.SLIDING)
+    begun, _ = seqpol.gqa_sequence(p, cfg, x[::-1, 11:], jnp.stack([positions[0, 11:] - 11, positions[1, 11:]]), valid,
+                                   (halfway, jnp.asarray([1, 0]), jnp.asarray([0, 11])), kind=seqpol.SLIDING)  # fmt: skip
+    assert gap(begun[0], alone[0]) < TOL and gap(begun[1], out[0, 11:]) < TOL
+
+
+@pytest.mark.parametrize("S, blocks", [(18, "whole: a short row that is no multiple"), (24, "6 blocks"), (38, "9 blocks and a last one of 2")])
+def test_the_query_blocks_give_what_one_block_gives(monkeypatch, S, blocks):
+    """Rows of a length that is no multiple of the block, past ``WHOLE_UP_TO``, are scored in whole blocks and a last shorter one."""
+    cfg, p = core(MELLUM2_SIZES, context=64), reference_mellum2.init_weights({"model": MELLUM2_SIZES}, 3)["layers"]["0"]["attn"]
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, S, MELLUM2_SIZES["hidden_size"]))
+    positions, valid = jnp.broadcast_to(jnp.arange(S), (2, S)), jnp.ones((2, S), bool)
+    want = [seqpol.gqa_sequence(p, cfg, x, positions, valid, kind=kind)[0] for kind in (seqpol.SLIDING, seqpol.ATTENTION)]
+    monkeypatch.setattr(seqpol, "QUERY_BLOCK", 4)
+    monkeypatch.setattr(seqpol, "WHOLE_UP_TO", 20)
+    calls, real = [], jax.lax.map
+    monkeypatch.setattr(seqpol.lax, "map", lambda f, xs: calls.append(jax.tree.leaves(xs)[0].shape[0]) or real(f, xs))
+    for kind, one in zip((seqpol.SLIDING, seqpol.ATTENTION), want):
+        assert gap(seqpol.gqa_sequence(p, cfg, x, positions, valid, kind=kind)[0], one) < TOL
+    assert calls == {18: [], 24: [6, 6], 38: [9, 9]}[S]
+
+
+def test_yarns_table_against_a_hand_computed_case():
+    """The published table at a head of 128: ``c(32) = 18.08`` and ``c(1) = 34.98`` dims turn 32 times and once over
+    the original 8,192 positions, so dims under 18 keep their frequency, dims from 35 on have it divided by 16."""
+    table = core(MELLUM2_SIZES).rope(seqpol.ATTENTION)
+    inv_freq, factor = seqpol.rope_frequencies(table, 128)
+    assert factor == 1.2772588722239782 == pytest.approx(0.1 * np.log(16.0) + 1.0)
+    want = {0: 1.0, 18: 0.024955408670558694, 19: 0.019208015577607825, 35: 4.7781061769823416e-05, 63: 1.5344629944572555e-07}
+    for i, value in want.items():  # 19: the ramp's first step, f (1 - 1/17) + f / 16 / 17 with f = 500000^(-19/64)
+        assert float(inv_freq[i]) == pytest.approx(value, rel=2e-6), i
+    ours, theirs = seqpol.rope_frequencies(table, 128)[0], reference_mellum2.rotary_table(ROPE_PARAMETERS["full_attention"], 128)[0]
+    assert gap(ours, theirs) < 1e-6
+    plain, _ = seqpol.rope_frequencies(core(MELLUM2_SIZES).rope(seqpol.SLIDING), 128)
+    assert float(plain[63]) == pytest.approx(16 * 1.5344629944572555e-07, rel=2e-6) and seqpol.rope_frequencies(core().rope(seqpol.LATENT), 64)[1] == 1.0
+    # cosine and sine both carry the factor: position 0 scales, and a score of two rotated vectors carries its square
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 128))
+    assert gap(seqpol.rope(x, jnp.zeros((3,)), table), factor * x) < 1e-6
+    a, b = seqpol.rope(x, jnp.asarray([5, 5, 5]), table), seqpol.rope(x, jnp.asarray([5, 5, 5]), dataclasses.replace(table, attention_factor=1.0))
+    assert gap((a * a).sum(-1), factor**2 * (b * b).sum(-1)) < 1e-5
+
+
+def test_the_softmax_router_equals_the_reference():
+    layer = reference_mellum2.init_weights({"model": MELLUM2_SIZES}, 3)["layers"]["1"]["moe"]
+    cfg = core(MELLUM2_SIZES)
+    x = jax.random.normal(jax.random.PRNGKey(6), (40, MELLUM2_SIZES["hidden_size"]))
+    chosen, weights = seqpol.route(layer, cfg, x)
+    score = jax.nn.softmax(x @ layer["router"]["kernel"], -1)
+    assert chosen.shape == (40, 2) and np.allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)  # the chosen scores over their sum, no scaling
+    assert np.array_equal(np.sort(np.asarray(chosen), -1), np.sort(np.asarray(jax.lax.top_k(score, 2)[1]), -1)) and "bias" not in layer["router"]
+    for grouped in (False, True):
+        y, counters = seqpol.moe(layer, core(MELLUM2_SIZES, dense_pairs_max=0 if grouped else 10**6), x)
+        assert gap(y, reference_mellum2.expert_layer(layer, MELLUM2_SIZES, x)) < TOL and counters[0] == 80
 
 
 # (c) the shares add up: an uncut layer of 8 experts in shares of 2 (with a shared expert, counted once) and of 1 (with none)
@@ -314,24 +461,27 @@ def test_rows_in_no_group_may_hold_anything(weights, monkeypatch, factor, plante
         assert bool(jnp.isfinite(a).all()) and gap(a, b) < TOL
 
 
-def _batch(model, rng, agent, continuing, dtype=jnp.float32):
+def _batch(model, rng, agent, continuing, dtype=jnp.float32, lengths=(3, 7, 5, 4)):
     """One minibatch: an episode that begins in the rollout, one that
     continues from a snapshot (or begins too), and a padding sequence; the
-    snapshot of two rows; and the reference's aligned form of the same."""
+    snapshot of two rows; and the reference's aligned form of the same.
+    ``lengths``: the second episode's prompt and actions, and where it
+    continues, how many of its inputs lie before the rollout and how many
+    steps in it."""
     P, L = agent.prompt_max, 8
     pad = lambda a, n, dtype=np.float32: np.concatenate([np.asarray(a, dtype), np.zeros((n - len(a),), dtype)])  # noqa: E731
     noise = lambda n: rng.normal(size=n).astype(np.float32)  # noqa: E731
     prompt_a, acts_a = rng.integers(0, VOCAB, 4), rng.integers(0, VOCAB, 5)
-    prompt_b, acts_b = rng.integers(0, VOCAB, 3), rng.integers(0, VOCAB, 7)
+    prompt_b, acts_b = rng.integers(0, VOCAB, lengths[0]), rng.integers(0, VOCAB, lengths[1])
     inputs_b = np.concatenate([prompt_b, acts_b[:-1]])
-    before = 5 if continuing else 0  # episode b's inputs that lie before the rollout: in the snapshot, not in the sequence
+    before = lengths[2] if continuing else 0  # episode b's inputs that lie before the rollout: in the snapshot, not in the sequence
     # row 0 of the snapshot holds what an episode before left (a row that begins must not read it), row 1 episode b so far
     snap = jax.tree.map(lambda shape: jnp.full(shape, 3.0, dtype), seqpol.state_shapes(agent.core, 2), is_leaf=lambda s: isinstance(s[0], int))
     if continuing:
         own = whole(model.weights, inputs_b[:before], cfg=agent.core)[2]
         snap = jax.tree.map(lambda held, new: held.at[1, : new.shape[1]].set(new[0].astype(dtype)), snap, own)
     seq_a = dict(actions=acts_a, logprobs=-3 + 0.1 * noise(5), advantages=noise(5), returns=noise(5), values=noise(5))
-    n_b = 4 if continuing else 7
+    n_b = lengths[3] if continuing else lengths[1]
     seq_b = dict(actions=acts_b[-n_b:], logprobs=-3 + 0.1 * noise(n_b), advantages=noise(n_b), returns=noise(n_b), values=noise(n_b))
     first_b = len(inputs_b) - n_b
     batch = {
@@ -346,7 +496,7 @@ def _batch(model, rng, agent, continuing, dtype=jnp.float32):
         kind = np.int32 if k == "actions" else np.float32
         batch[k] = np.stack([pad(seq_a[k], L, kind), pad(seq_b[k], L, kind), np.zeros(L, kind)])
 
-    def aligned(tokens, first, seq, size=12):
+    def aligned(tokens, first, seq, size=max(12, len(inputs_b) + 1)):
         out = {"tokens": pad(tokens, size, np.int32), "steps": np.zeros(size, np.float32)}
         n = len(seq["actions"])
         out["steps"][first : first + n] = 1
@@ -389,8 +539,23 @@ def test_a_sequence_from_a_snapshot_equals_the_episode_evaluated_whole(model):
         assert abs(faulty["value_loss"] - ours["value_loss"]) > 1000 * TOL
 
 
+def test_a_sequence_from_a_wrapped_rings_snapshot_equals_the_episode_evaluated_whole():
+    """The second episode has 13 of its 19 inputs before the rollout: the window layers' rings in the snapshot have
+    wrapped (they hold positions 5 to 12, entry 0 position 8), and its 6 steps stop seeing those entries one by one."""
+    model = Model("mellum2")
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
+    batch, snap, aligned = _batch(model, np.random.default_rng(0), agent, continuing=True, lengths=(5, 15, 13, 6))
+    assert batch["len0"].tolist() == [0, 13, 0] and snap[0][0].shape == (2, 8, 16) and snap[2][0].shape == (2, 32, 16)
+    ours, _ = _program_loss(model.weights, agent, batch, snap, 0.0)
+    _same_losses(ours, model.losses(aligned, {**CONSTS, "mtp_loss_coef": 0.0}))
+    for fault in (window_ignored, yarn_left_out):  # the snapshot was made by the sound program: the update alone is faulty
+        with fault():
+            faulty, _ = _program_loss(model.weights, token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32), batch, snap, 0.0)
+        assert abs(faulty["value_loss"] - ours["value_loss"]) > 100 * TOL, fault.__name__
+
+
 # (e) one update equals the reference's: losses, the gradient by leaf, the weights after; MTP term on and off
-@pytest.mark.parametrize("name, mtp_coef", [("glm", 0.1), ("glm", 0.0), ("lfm2", 0.0)], ids=["glm-mtp_on", "glm-mtp_off", "lfm2"])
+@pytest.mark.parametrize("name, mtp_coef", [("glm", 0.1), ("glm", 0.0), ("lfm2", 0.0), ("mellum2", 0.0)], ids=["glm-mtp_on", "glm-mtp_off", "lfm2", "mellum2"])
 def test_one_update_equals_the_reference(name, mtp_coef):
     import optax
 
@@ -449,7 +614,7 @@ def tiny_args(tmp_path, exp="ppo_recurrent_glm47_flash", sizes=SIZES):
     as_word = lambda v: "[" + ",".join(str(x) for x in v) + "]" if isinstance(v, (list, tuple)) else v  # noqa: E731
     return [f"exp={exp}", "fabric=cpu", "fabric.precision=fp32", "fabric.devices=1", "env.num_envs=8", "algo.rollout_steps=16",
             "algo.per_rank_sequence_length=20", "algo.per_rank_batch_size=32", *[f"algo.core.{k}={as_word(v)}" for k, v in {
-                **{k: v for k, v in sizes.items() if k not in ("vocab_rows", "context", "rope_theta", "rms_norm_eps")},
+                **{k: v for k, v in sizes.items() if k not in ("vocab_rows", "context", "rope_theta", "rope_parameters", "rms_norm_eps")},
                 "vocab_rows": 8, "context": 16, "prompt_max": 4, "prefill_rows": 2}.items()],
             "env.wrapper.prompt_min=1", "env.wrapper.prompt_max=2", "algo.optimizer.lr=3e-3", "metric.log_level=1", "algo.run_test=False",
             "checkpoint.save_last=False", "checkpoint.every=0", f"log_base_dir={tmp_path}/logs"]  # fmt: skip
@@ -484,9 +649,11 @@ def test_the_recipe_learns_to_copy_and_leaves_with_77_on_sigterm(tmp_path, monke
     assert fifth > 50 and np.mean(rewards[-fifth:]) > np.mean(rewards[:fifth]) + 0.1, (np.mean(rewards[:fifth]), np.mean(rewards[-fifth:]))
 
 
-def test_the_hybrid_recipe_trains_through_the_same_main(tmp_path, monkeypatch, capsys):
-    """``exp=ppo_recurrent_lfm2_24b_a2b`` at the tiny size: rollouts whose episodes straddle them (both kinds of
-    snapshot are used), updates of two epochs, the three programs under the names the other recipe gives them."""
+@pytest.mark.parametrize("exp, sizes", [("ppo_recurrent_lfm2_24b_a2b", LFM2_SIZES), ("ppo_recurrent_mellum2_12b", MELLUM2_SIZES)], ids=["lfm2", "mellum2"])
+def test_the_other_recipes_train_through_the_same_main(tmp_path, monkeypatch, capsys, exp, sizes):
+    """``exp=ppo_recurrent_lfm2_24b_a2b`` and ``exp=ppo_recurrent_mellum2_12b`` at the tiny size: rollouts whose
+    episodes straddle them (every kind of snapshot is used: a cache, a convolution state, a ring of 8 that wraps
+    inside the context of 16), the three programs under the names the first recipe gives them."""
     monkeypatch.chdir(tmp_path)
     seen = []
     real = token_policy.make_player_programs
@@ -497,7 +664,7 @@ def test_the_hybrid_recipe_trains_through_the_same_main(tmp_path, monkeypatch, c
         return made
 
     monkeypatch.setattr(token_policy, "make_player_programs", programs)
-    run([*tiny_args(tmp_path, "ppo_recurrent_lfm2_24b_a2b", LFM2_SIZES), "algo.total_steps=768"])  # 6 updates of 128 policy steps
+    run([*tiny_args(tmp_path, exp, sizes), "algo.total_steps=768"])  # 6 updates of 128 policy steps
     assert seen == ["seqpol_prefill", "seqpol_decode"]
     out = capsys.readouterr().out
     assert sum("reward_env_" in line for line in out.splitlines()) > 50
