@@ -17,9 +17,7 @@ counts, and the device's time under the window layers' own scope.
 from __future__ import annotations
 
 import contextlib
-import glob
 import json
-import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -162,26 +160,15 @@ def scored_keys(config: Dict[str, Any]) -> float:
 
 def window_scope_ms(run: Any) -> Optional[float]:
     """Device self time per train-step execution under ``seqpol/attn/window``,
-    forward and backward, read like ``token_counters.train_steps`` reads the
-    step (over the trace to its end) in a reduction of its own, whose scopes
-    are the inner one's two forms; ``None`` where the run has no trace or the program
-    no such scope."""
+    forward and backward, over the traced stretch as ``device_time.of_run``
+    reads the step, in a reduction of its own whose scopes are the inner
+    one's two forms; ``None`` where the run has no trace or the program no
+    such scope."""
     from perfbench import device_time
 
-    if "_window_scope_ms" in run.__dict__:
-        return run.__dict__["_window_scope_ms"]
-    run.__dict__["_window_scope_ms"] = None
-    found = sorted(glob.glob(os.path.join(run.run_dir, "trace", "plugins", "profile", "*", "*.xplane.pb")))
-    sync = getattr(getattr(run, "watcher", None), "sync", None)
-    neutral = device_time.load(found[-1]) if found and sync is not None else None
-    if neutral is None:
-        return None
-    none = np.zeros((0, 2), np.float64)
-    reduced = device_time.reduce(neutral, programs=programs, train_program=train_program, scopes=window_scopes,
-                                 sync_mono_ns=(sync["before_ns"] + sync["inside_ns"]) / 2.0,
-                                 window_mono_ns=(float(sync["inside_ns"]), float(sync["inside_ns"]) + 3600e9),
-                                 spans_mono_ns=none, env_steps_mono_ns=none)  # fmt: skip
-    run.__dict__["_window_scope_ms"] = device_time.scope_ms(reduced, window_scopes) if reduced else None
+    if "_window_scope_ms" not in run.__dict__:
+        reduced = device_time.reduce_run(run, window_scopes, leaves=False)
+        run.__dict__["_window_scope_ms"] = device_time.scope_ms(reduced, window_scopes) if reduced else None
     return run.__dict__["_window_scope_ms"]
 
 

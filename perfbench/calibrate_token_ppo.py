@@ -1,6 +1,6 @@
 """Readings for the limits of the token policy's cell, by hand on the chip::
 
-    python3 perfbench/calibrate_token_ppo.py --workload glm47_flash_ep8.train --seed <n> [--seconds 5] [--losses-only]
+    python3 perfbench/calibrate_token_ppo.py --workload glm47_flash_ep8.train --seed <n> [--seconds 8] [--losses-only]
 
 One run of the cell through the harness, then every number ``correct`` compares
 (``algorithms/token_ppo.py``), for the program and for what must not pass, each
@@ -78,7 +78,7 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
-    parser.add_argument("--seconds", type=float, default=5.0)
+    parser.add_argument("--seconds", type=float, default=8.0)  # the window holds whole cycles: one of 5.6 to 5.8 s at least
     parser.add_argument("--losses-only", action="store_true", help="the controls' first-step losses alone, not their gradients nor their player")
     args = parser.parse_args()
     verify = functools.partial(readings, losses_only=args.losses_only)

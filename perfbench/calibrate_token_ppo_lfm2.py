@@ -1,6 +1,6 @@
 """Readings for the limits of the hybrid token policy's cell, by hand on the chip::
 
-    python3 perfbench/calibrate_token_ppo_lfm2.py --workload lfm2_24b_a2b_ep8.train --seed <n> [--seconds 5]
+    python3 perfbench/calibrate_token_ppo_lfm2.py --workload lfm2_24b_a2b_ep8.train --seed <n> [--seconds 8]
 
 One run of the cell through the harness, then every number ``correct`` compares
 (``algorithms/token_ppo_lfm2.py``), for the program and for what must not pass,
@@ -89,7 +89,7 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
-    parser.add_argument("--seconds", type=float, default=5.0)
+    parser.add_argument("--seconds", type=float, default=8.0)  # the window holds whole cycles: one of 5.6 to 5.8 s at least
     args = parser.parse_args()
     print(json.dumps(run.run_cell(args.workload, args.seed, args.seconds, False, verify=readings)), flush=True)
 

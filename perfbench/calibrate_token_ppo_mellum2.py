@@ -1,6 +1,6 @@
 """Readings for the limits of the window-and-full-attention token policy's cell, by hand on the chip::
 
-    python3 perfbench/calibrate_token_ppo_mellum2.py --workload mellum2_12b_ep8.train --seed <n> [--seconds 10]
+    python3 perfbench/calibrate_token_ppo_mellum2.py --workload mellum2_12b_ep8.train --seed <n> [--seconds 12]
 
 One run of the cell through the harness, then every number ``correct`` compares
 (``algorithms/token_ppo_mellum2.py``), for the program and for what must not
@@ -95,7 +95,7 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
-    parser.add_argument("--seconds", type=float, default=10.0)  # the window opens on an update of 7.7 s: it has to reach the rollout behind it
+    parser.add_argument("--seconds", type=float, default=12.0)  # the window holds whole cycles: one of 9.5 s at least
     args = parser.parse_args()
     print(json.dumps(run.run_cell(args.workload, args.seed, args.seconds, False, verify=readings)), flush=True)
 

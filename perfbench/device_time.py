@@ -335,32 +335,46 @@ def _trace_file(run: Any) -> Optional[str]:
     return found[-1] if found else None
 
 
-def of_run(run: Any) -> Optional[Dict[str, Any]]:
-    """The reduction of a finished traced run, made once per run (the trace
-    directory is ``<run.run_dir>/trace``); ``None`` where it has no trace, no
-    sync or no device plane."""
-    if "_device_time" in run.__dict__:
-        return run.__dict__["_device_time"]
-    run.__dict__["_device_time"] = None
-    path = _trace_file(run)
-    sync = getattr(getattr(run, "watcher", None), "sync", None)
-    if path is None or sync is None:
-        return None
-    neutral = load(path)
+def neutral_of_run(run: Any) -> Optional[Neutral]:
+    """The neutral form of a finished run's trace (the trace directory is
+    ``<run.run_dir>/trace``), loaded once per run; ``None`` where it has no
+    trace, no sync, no traced stretch or no device plane."""
+    if "_neutral" not in run.__dict__:
+        path = _trace_file(run)
+        ready = path is not None and getattr(getattr(run, "watcher", None), "sync", None) is not None
+        run.__dict__["_neutral"] = load(path) if ready and getattr(run, "stretch_ns", None) is not None else None
+    return run.__dict__["_neutral"]
+
+
+def reduce_run(run: Any, scopes: Sequence[str], leaves: bool = True) -> Optional[Dict[str, Any]]:
+    """``reduce`` of a finished run's trace over its traced stretch
+    (``run.stretch_ns``: ``run.trace_stretch``) with the tables of the cell's
+    algorithm and these ``scopes``; with ``leaves`` the idle time is held
+    against the algorithm's leaf spans and env 0's ``step()``."""
+    neutral = neutral_of_run(run)
     if neutral is None:
         return None
     tables = loader.algorithm(run.cell)
-    leaves = [(t0, t0 + d * 1e9) for name in tables.leaf_spans for t0, d in spans(run, name)]
-    run.__dict__["_device_time"] = reduce(
+    sync = run.watcher.sync
+    found = [(t0, t0 + d * 1e9) for name in tables.leaf_spans for t0, d in spans(run, name)] if leaves else []
+    return reduce(
         neutral,
         programs=tables.programs,
         train_program=tables.train_program,
-        scopes=tables.scopes,
+        scopes=scopes,
         sync_mono_ns=(sync["before_ns"] + sync["inside_ns"]) / 2.0,
-        window_mono_ns=(float(sync["inside_ns"]), float(run.window["close_ns"])),
-        spans_mono_ns=np.asarray(leaves, np.float64).reshape(-1, 2),
-        env_steps_mono_ns=np.stack([run.entry_ns, run.exit_ns], 1),
+        window_mono_ns=(float(run.stretch_ns[0]), float(run.stretch_ns[1])),
+        spans_mono_ns=np.asarray(found, np.float64).reshape(-1, 2),
+        env_steps_mono_ns=np.stack([run.entry_ns, run.exit_ns], 1) if leaves else np.zeros((0, 2), np.float64),
     )
+
+
+def of_run(run: Any) -> Optional[Dict[str, Any]]:
+    """The reduction of a finished traced run by the tables of the cell's
+    algorithm, made once per run; ``None`` where it has no trace, no sync or
+    no device plane."""
+    if "_device_time" not in run.__dict__:
+        run.__dict__["_device_time"] = reduce_run(run, loader.algorithm(run.cell).scopes)
     return run.__dict__["_device_time"]
 
 

@@ -1,7 +1,7 @@
 """Device time of one ``seqpol_train_step`` execution (a gradient step on one
-minibatch of sequences), over its whole executions in the trace to its end:
-``perfbench/token_counters.py`` says why not over the stretch that
-``train_step.device_ms`` reads."""
+minibatch of sequences), over its whole executions in the traced stretch: one
+whole cycle of the token loop, so every gradient step of one update
+(``perfbench/token_counters.py``)."""
 
 from perfbench import token_counters
 

@@ -22,7 +22,7 @@ import shutil  # noqa: E402
 import signal  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
-from typing import Any, Dict, List, Optional  # noqa: E402
+from typing import Any, Callable, Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -31,7 +31,9 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-#: seconds of device trace, taken at the end of a traced run's window
+from perfbench import window  # noqa: E402
+
+#: seconds of device trace, taken at the end of a traced run's window where the cell names no cycle
 TRACE_SECONDS = 3.0
 #: a run whose window has not opened by then is given up
 SETUP_LIMIT_S = 1500.0
@@ -92,25 +94,68 @@ class CompileLog:
         return sum(e["dur"] for e in self.events if e["end_ns"] <= until_ns)
 
 
+#: how far the longest cycle seen may be outrun by the next two (a cycle cell's trace)
+CYCLE_MARGIN = 1.05
+
+
+def trace_lead(cycle: int) -> int:
+    """Vector steps before a rollout's end at which a cycle cell's profiler
+    starts (a fifth of a second in the three token cells): the update's first
+    train step then runs whole inside the trace."""
+    return max(4, cycle // 8)
+
+
+def starts_trace(exit_ns: Callable[[int], float], open_index: int, cycle: int, end: int, deadline_ns: float) -> bool:
+    """Whether a cycle cell traces the cycle that begins at rollout end
+    ``end``, judged as vector step ``end - trace_lead(cycle)`` returns
+    (``exit_ns(i)``: when step ``i`` returned, known up to that step). That
+    end is reckoned by the longest lead seen and the cycle by the longest seen
+    (both from the cycle that ends as the window opens), each ``CYCLE_MARGIN``
+    times over: the cycle is traced unless the next one also returns by the
+    deadline. So the traced cycle returns by the deadline wherever in
+    a cycle the deadline falls, as long as no cycle outruns the longest seen
+    before it by more than the margin."""
+    lead = trace_lead(cycle)
+    ends = [j for j in range(open_index - cycle, end, cycle) if j - lead >= 0]
+    if len(ends) < 2:
+        return True
+    longest = max(exit_ns(b) - exit_ns(a) for a, b in zip(ends, ends[1:]))
+    lead_ns = max(exit_ns(j) - exit_ns(j - lead) for j in ends)
+    return deadline_ns < exit_ns(end - lead) + CYCLE_MARGIN * (lead_ns + 2.0 * longest)
+
+
 class Watcher(threading.Thread):
     """Opens the window, takes the trace, and closes the window with the
-    signal the program treats as a preemption."""
+    signal the program treats as a preemption. With ``cycle`` (vector steps a
+    rollout) the window opens on a rollout's end, and the trace holds the
+    window's last whole cycle: the watcher starts the profiler a little
+    before the rollout end that begins it (``traced_from``) and, as the next
+    rollout end returns, stops it in a thread of its own (``stopper``):
+    collecting a cycle's trace takes tens of seconds, which neither the
+    window's end at its deadline nor the comparison after it waits for."""
 
     def __init__(self, stamps, compiles: CompileLog, *, action_repeat: int, open_after: int, quiet_steps: int,
-                 seconds: float, trace_dir: Optional[str]) -> None:  # fmt: skip
+                 seconds: float, trace_dir: Optional[str], cycle: Optional[int] = None) -> None:  # fmt: skip
         super().__init__(name="perfbench-watcher", daemon=True)
         self.stamps, self.compiles = stamps, compiles
         self.action_repeat, self.open_after, self.quiet_steps = action_repeat, open_after, quiet_steps
-        self.seconds, self.trace_dir = seconds, trace_dir
+        self.seconds, self.trace_dir, self.cycle = seconds, trace_dir, cycle
         self.open_index: Optional[int] = None
         self.deadline_ns: Optional[int] = None
-        self.trace_span_ns: Optional[List[int]] = None
+        self.traced_from: Optional[int] = None
+        self.tracing = False
+        self.stopper: Optional[threading.Thread] = None
+        self.stop_ns: Optional[List[int]] = None
         self.sync: Optional[Dict[str, int]] = None
         self.gave_up = False
+        self.ended = False
         self.cancel = threading.Event()
 
     def _exit_ns(self, index: int) -> int:
         return int(self.stamps[2 + 2 * ((index + 1) * self.action_repeat - 1)])
+
+    def _done(self) -> int:
+        return int(self.stamps[0]) // self.action_repeat
 
     def _sleep_until(self, t_ns: int) -> None:
         while not self.cancel.is_set():
@@ -119,41 +164,89 @@ class Watcher(threading.Thread):
                 return
             time.sleep(min(left, 0.05))
 
+    def _end_window(self) -> None:
+        self.ended = True
+        os.kill(os.getpid(), signal.SIGTERM)
+
+    def _await_step(self, index: int) -> bool:
+        """Until vector step ``index`` has returned, ending the window at its
+        deadline meanwhile; ``False`` where the program left first."""
+        while not self.cancel.is_set():
+            if self._done() > index:
+                return True
+            if self.deadline_ns is not None and not self.ended and time.monotonic_ns() >= self.deadline_ns:
+                self._end_window()
+            time.sleep(0.002)
+        return False
+
+    def _start_trace(self) -> None:
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # Python's own calls are not wanted and cost the loop time
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        self.tracing = True
+        t_a = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation("perfbench/sync"):
+            t_b = time.monotonic_ns()
+        self.sync = {"before_ns": t_a, "inside_ns": t_b}
+
+    def stop_trace(self) -> None:
+        """Stops the profiler once: from ``stopper``, or from the run's end
+        where the watcher made none."""
+        if self.tracing:
+            import jax
+
+            t_stop = time.monotonic_ns()
+            jax.profiler.stop_trace()
+            self.tracing = False
+            self.stop_ns = [t_stop, time.monotonic_ns()]
+
+    def _trace_a_cycle(self) -> None:
+        """Starts the profiler ``trace_lead`` vector steps before the rollout
+        end that begins the window's last whole cycle (``starts_trace``) and
+        stops it behind that cycle's last step."""
+        cycle, lead = self.cycle, trace_lead(self.cycle)
+        end = self.open_index + cycle
+        while True:
+            if not self._await_step(end - lead):
+                return
+            if self._done() - 1 < end and starts_trace(self._exit_ns, self.open_index, cycle, end, self.deadline_ns):
+                break
+            end += cycle  # not this cycle, or the watcher fell behind its lead
+        self._start_trace()
+        self.traced_from = end
+        if self._await_step(end + cycle):
+            self.stopper = threading.Thread(target=self.stop_trace, name="perfbench-trace-stop", daemon=True)
+            self.stopper.start()
+
     def run(self) -> None:
         give_up_ns = _T0_NS + int(SETUP_LIMIT_S * 1e9)
         while not self.cancel.is_set():
-            done = int(self.stamps[0]) // self.action_repeat
+            done = self._done()
             # quiet: no compile since the vector step `quiet_steps` back returned
             if done > self.open_after and self.compiles.last_end_ns() < self._exit_ns(done - 1 - self.quiet_steps):
-                self.open_index = done - 1
+                self.open_index = done - 1 if self.cycle is None else window.rollout_end(done - 1, self.cycle)
                 break
             if time.monotonic_ns() > give_up_ns:
                 self.gave_up = True
-                os.kill(os.getpid(), signal.SIGTERM)
+                self._end_window()
                 return
             time.sleep(0.002)
-        if self.open_index is None:
+        if self.open_index is None or not self._await_step(self.open_index):
             return
-        open_ns = self._exit_ns(self.open_index)
-        self.deadline_ns = open_ns + int(self.seconds * 1e9)
-        if self.trace_dir is not None:
-            import jax
-
+        self.deadline_ns = self._exit_ns(self.open_index) + int(self.seconds * 1e9)
+        if self.trace_dir is not None and self.cycle is not None:
+            self._trace_a_cycle()
+        elif self.trace_dir is not None:
             self._sleep_until(self.deadline_ns - int(min(TRACE_SECONDS, self.seconds / 2) * 1e9))
             if self.cancel.is_set():
                 return
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0  # Python's own calls are not wanted and cost the loop time
-            options.host_tracer_level = 2
-            jax.profiler.start_trace(self.trace_dir, profiler_options=options)
-            t_a = time.monotonic_ns()
-            with jax.profiler.TraceAnnotation("perfbench/sync"):
-                t_b = time.monotonic_ns()
-            self.sync = {"before_ns": t_a, "inside_ns": t_b}
-            self.trace_span_ns = [t_b, self.deadline_ns]
+            self._start_trace()
         self._sleep_until(self.deadline_ns)
-        if not self.cancel.is_set():
-            os.kill(os.getpid(), signal.SIGTERM)
+        if not self.cancel.is_set() and not self.ended:
+            self._end_window()
 
 
 def _device_line(devices, peak_bytes: Optional[int]) -> Dict[str, Any]:
@@ -197,7 +290,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     run) are for the tests, which drive this same path tiny on the CPU and
     with the timed path broken; ``verify`` takes the algorithm's own place
     for ``calibrate.py``, which reads more than a run compares."""
-    from perfbench import env as bench_env, loader, window
+    from perfbench import env as bench_env, loader
 
     cell = loader.Cell(workload, root)
     algorithm = loader.algorithm(cell)
@@ -225,6 +318,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     algo = cell.config["algo"]
     num_envs, action_repeat = int(algo["num_envs"]), int(algo["action_repeat"])
     learning_starts = int(algo["learning_starts"]) // num_envs
+    cycle = int(algo["rollout_steps"]) if "rollout_steps" in algo else None
     warm = int(cell.workload["warm_steps"] if warm_steps is None else warm_steps)
     say(f"[perfbench] {workload} seed={seed} seconds={seconds} trace={int(trace)} device={devices[0].device_kind} x{len(devices)}")
     say(f"[perfbench] compile cache {cache_dir}; run dir {os.path.relpath(run_dir, root)}")
@@ -234,7 +328,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     capture = algorithm.Capture(cell.config, program_seed)
     trace_dir = os.path.join(run_dir, "trace") if trace else None
     watcher = Watcher(stamps, compiles, action_repeat=action_repeat, open_after=learning_starts + warm,
-                      quiet_steps=warm, seconds=float(seconds), trace_dir=trace_dir)  # fmt: skip
+                      quiet_steps=warm, seconds=float(seconds), trace_dir=trace_dir, cycle=cycle)  # fmt: skip
     from sheeprl_tpu.cli import run as program_run
 
     exit_code: Any = None
@@ -248,8 +342,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
         t_left_ns = time.monotonic_ns()
         watcher.cancel.set()
         watcher.join()
-        if trace_dir is not None and watcher.trace_span_ns is not None:
-            jax.profiler.stop_trace()
+        if watcher.stopper is None:
+            watcher.stop_trace()  # a cycle's trace is being collected by the watcher's stopper, which the comparison need not wait for
         compiles.stop()
     if watcher.gave_up or watcher.open_index is None or exit_code != PREEMPTED:
         raise SystemExit(f"perfbench: the program left with {exit_code!r} and the window "
@@ -258,7 +352,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     peak_bytes = _peak_bytes(devices)
 
     entry, exit_ = window.vector_steps(bench_env.open_stamps(stamp_path), action_repeat)
-    win = window.measure(entry, exit_, watcher.open_index, watcher.deadline_ns, num_envs)
+    win = window.measure(entry, exit_, watcher.open_index, watcher.deadline_ns, num_envs, cycle)
     setup_s = (win["open_ns"] - _T0_NS) / 1e9
     in_window = compiles.between(win["open_ns"], win["close_ns"])
     first_ns = int(entry[0])
@@ -282,6 +376,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
         f"in {win['seconds']:.3f}s; wait p50 {win['env_wait_ms_p50']:.3f} ms p95 {win['env_wait_ms_p95']:.3f} ms, longest {win['longest_waits_ms']}; "
         f"compiles inside {len(in_window)}; train calls {capture.calls}; after the window {(t_left_ns - win['close_ns']) / 1e9:.2f}s to leave"
     )
+    if cycle is not None:
+        say(f"[perfbench] the deadline fell {(watcher.deadline_ns - win['close_ns']) / 1e9:.3f}s after the last whole cycle's end "
+            f"(cycles of {cycle} vector steps: {win['vector_steps'] // cycle} whole, the longest {_longest_cycle_s(exit_, win, cycle):.3f}s)")  # fmt: skip
+        if watcher.traced_from is not None:
+            lo, hi = trace_stretch(watcher, exit_, win)
+            say(f"[perfbench] traced: the cycle from vector step {watcher.traced_from}, {(hi - lo) / 1e9:.3f}s "
+                f"(the profiler began {(lo - watcher.sync['inside_ns']) / 1e9:.3f}s before it)")  # fmt: skip
     record = _run_record(os.path.join(run_dir, "RUNS.jsonl"))
     resolved = record.get("resolved") or {}
     say("[perfbench] placement: " + json.dumps(capture.placement, default=str))
@@ -296,12 +397,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     say("[perfbench] read and not compared (no limit holds, PERF.md section 2): " + json.dumps(not_compared))
 
     run = RunFacts(cell=cell, run_dir=run_dir, window=win, setup_s=setup_s, compiles=compiles, watcher=watcher,
-                   entry_ns=entry, exit_ns=exit_, peak=peak, peak_bytes=peak_bytes, capture=capture, record=record)  # fmt: skip
+                   entry_ns=entry, exit_ns=exit_, peak=peak, peak_bytes=peak_bytes, capture=capture, record=record,
+                   stretch_ns=trace_stretch(watcher, exit_, win))  # fmt: skip
     device = _device_line(devices, peak_bytes)
     result: Dict[str, Any] = {"correct": bool(ok), "attempted": win["vector_steps"], "failed": 0}
     if trace:
         from perfbench import trace_reduce
 
+        if watcher.stopper is not None:
+            watcher.stopper.join()
+        if watcher.stop_ns is not None:
+            t_stop, t_stopped = watcher.stop_ns
+            say(f"[perfbench] the profiler was stopped {(t_stop - win['close_ns']) / 1e9:.3f}s after the window's last step; collecting "
+                f"the trace took {(t_stopped - t_stop) / 1e9:.2f}s and ended {(t_stopped - t_left_ns) / 1e9:.2f}s after the program left")  # fmt: skip
+        t_reduce = time.monotonic()
         reduced = trace_reduce.reduce_dir(trace_dir, run)
         run.trace = reduced
         metrics = {}
@@ -310,6 +419,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
             value = readers[spec["name"]](run)
             if value is not None:
                 metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
+        from perfbench import device_time
+
+        whole = (device_time.of_run(run) or {}).get("train_executions")
+        say(f"[perfbench] the trace's reduction and the per-layer readers took {time.monotonic() - t_reduce:.2f}s; "
+            f"the traced stretch {reduced.get('window_s')}s holds {whole} whole executions of the train program")  # fmt: skip
         device.update({"busy_s": reduced.get("busy_s"), "window_s": reduced.get("window_s")})
         result.update({"metrics": metrics, "device": device, "breakdown": reduced.get("breakdown", {})})
     else:
@@ -320,6 +434,25 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, root: str
     for name, v in compared.items():
         print(f"[compared] {name} {v['value']} limit {v['limit']} {'ok' if v['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
     return result
+
+
+def _longest_cycle_s(exit_: np.ndarray, win: Dict[str, Any], cycle: int) -> float:
+    ends = exit_[win["first"] - 1 : win["last"] + 1 : cycle]
+    return float(np.diff(ends).max()) / 1e9
+
+
+def trace_stretch(watcher: Watcher, exit_ns: np.ndarray, win: Dict[str, Any]) -> Optional[List[float]]:
+    """The traced stretch that the trace's readers reduce, on the host's
+    monotonic clock: the cycle from the rollout end ``traced_from`` to the next
+    one (or to the last step the program took, where it left first), or, in a
+    cell that names no cycle, from the profiler's start to the window's last
+    vector step. ``None`` where nothing was traced."""
+    if watcher.sync is None:
+        return None
+    if watcher.traced_from is None:
+        return [float(watcher.sync["inside_ns"]), float(win["close_ns"])]
+    end = min(watcher.traced_from + watcher.cycle, len(exit_ns) - 1)
+    return [float(exit_ns[watcher.traced_from]), float(exit_ns[end])]
 
 
 class RunFacts:

@@ -6,19 +6,13 @@ decoded, cache entries attended to), those of the window. A program without
 them gives an empty list, and every reader then returns ``None``. And the
 device's time in its train step, whole and under one ``jax.named_scope``.
 
-The train step is read over the trace to its end, not over the stretch the
-harness cuts at the window's last vector step: the window opens as a rollout
-ends, so its deadline falls inside an update, the last step before it is the
-rollout's last, and the cut stretch holds no train step at all. The profiler
-runs until the program has left, and the program finishes the update it is in
-before it leaves: those are the same ``seqpol_train_step`` executions on the
-same minibatches as any other update's."""
+The train step is read over the traced stretch, which in a cell that names a
+cycle (``algo.rollout_steps``) is one whole cycle: from a rollout's end, over
+the update that follows it, to the next rollout's end (``run.trace_stretch``).
+So the stretch holds every ``seqpol_train_step`` execution of one update,
+whole, beside the rollout's decodes and prefills."""
 
-import glob
-import os
 from typing import Any, Dict, List, Optional
-
-import numpy as np
 
 from perfbench import device_time, loader
 
@@ -42,43 +36,15 @@ def per_gradient_step(run: Any, field: str) -> Optional[float]:
     return total(run, field) / steps if steps else None
 
 
-def train_steps(run: Any) -> Optional[Dict[str, Any]]:
-    """``device_time.reduce`` from the traced stretch's start to the trace's
-    end, made once per run: the train program's whole executions and their
-    self time by scope. ``None`` where the run has no trace, no sync or no
-    device plane."""
-    if "_token_train_steps" in run.__dict__:
-        return run.__dict__["_token_train_steps"]
-    run.__dict__["_token_train_steps"] = None
-    found = sorted(glob.glob(os.path.join(run.run_dir, "trace", "plugins", "profile", "*", "*.xplane.pb")))
-    sync = getattr(getattr(run, "watcher", None), "sync", None)
-    neutral = device_time.load(found[-1]) if found and sync is not None else None
-    if neutral is None:
-        return None
-    tables = loader.algorithm(run.cell)
-    none = np.zeros((0, 2), np.float64)
-    run.__dict__["_token_train_steps"] = device_time.reduce(
-        neutral,
-        programs=tables.programs,
-        train_program=tables.train_program,
-        scopes=tables.scopes,
-        sync_mono_ns=(sync["before_ns"] + sync["inside_ns"]) / 2.0,
-        window_mono_ns=(float(sync["inside_ns"]), float(sync["inside_ns"]) + 3600e9),
-        spans_mono_ns=none,
-        env_steps_mono_ns=none,
-    )
-    return run.__dict__["_token_train_steps"]
-
-
 def train_step_ms(run: Any) -> Optional[float]:
-    """Device milliseconds per whole ``seqpol_train_step`` execution in the trace."""
-    return device_time.program_ms(train_steps(run), loader.algorithm(run.cell).train_program)
+    """Device milliseconds per whole ``seqpol_train_step`` execution in the traced stretch."""
+    return device_time.program_ms(device_time.of_run(run), loader.algorithm(run.cell).train_program)
 
 
 def scope_ms(run: Any, scope: str) -> Optional[float]:
     """Device self time per train-step execution under ``scope``, forward and
     backward; ``None`` where the trace holds no whole step or knows no such scope."""
-    reduced = train_steps(run)
+    reduced = device_time.of_run(run)
     if not reduced or scope not in reduced["scopes"]:
         return None
     return device_time.scope_ms(reduced, (scope,))

@@ -182,11 +182,16 @@ def reduce(planes: Planes, *, sync_mono_ns: float, window_mono_ns: Tuple[float, 
 def reduce_dir(trace_dir: str, run: Any) -> Dict[str, Any]:
     """The reduction of a finished run's trace directory."""
     found = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not found or run.watcher.sync is None:
+    if not found or run.watcher.sync is None or run.stretch_ns is None:
         return {}
-    planes = load(found[-1])
+    return reduce_run(load(found[-1]), run)
+
+
+def reduce_run(planes: Planes, run: Any) -> Dict[str, Any]:
+    """``reduce`` of a finished run's trace over its traced stretch
+    (``run.stretch_ns``: ``run.trace_stretch``)."""
     sync = (run.watcher.sync["before_ns"] + run.watcher.sync["inside_ns"]) / 2.0
-    window = (float(run.watcher.sync["inside_ns"]), float(run.window["close_ns"]))
+    window = (float(run.stretch_ns[0]), float(run.stretch_ns[1]))
     steps = np.stack([run.entry_ns, run.exit_ns], 1)
     keep = os.environ.get("PERFBENCH_KEEP_TRACE")
     if keep:

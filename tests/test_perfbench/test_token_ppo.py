@@ -152,11 +152,11 @@ def test_the_configurations_count_is_the_algorithms():
     assert sum(int(np.prod(shape)) for shape in reference._shapes(config["model"]).values()) == pytest.approx(706.5e6, rel=2e-3)
 
 
-def test_the_train_step_is_read_after_the_windows_last_step(tmp_path, monkeypatch):
-    """The window opens as a rollout ends, so its deadline falls in an update
-    and the stretch the harness cuts (to the window's last vector step) holds
-    decodes only: the cell's train-step readers read the trace to its end, the
-    existing ``train_step.device_ms`` reads nothing there."""
+def test_the_train_step_is_read_over_the_traced_cycle(tmp_path, monkeypatch):
+    """The traced stretch of a cell that names a cycle runs from one rollout
+    end to the next (``run.trace_stretch``), so it holds the update's train
+    steps whole beside the rollout's decodes: the cell's train-step readers
+    read them there, and so would ``train_step.device_ms``."""
     from perfbench import device_time, token_counters
 
     ms = 1e6
@@ -172,16 +172,19 @@ def test_the_train_step_is_read_after_the_windows_last_step(tmp_path, monkeypatc
     trace.mkdir(parents=True)
     (trace / "host.xplane.pb").write_bytes(b"")
 
-    class Watcher:
+    class Watcher:  # the traced cycle: a rollout end 5 ms after the sync, an update, the next rollout's end 215 ms after it
         sync = {"before_ns": sync_ns, "inside_ns": sync_ns}
+        traced_from, cycle = 0, 1
 
     cell = loader.Cell(CELL)
-    facts = dict(cell=cell, run_dir=str(tmp_path), watcher=Watcher(), peak={"bf16_flops_per_s": 197e12}, telemetry_events=[],
-                 window={"open_ns": int(sync_ns - 27e9), "close_ns": int(sync_ns + 50 * ms)}, entry_ns=np.zeros(0), exit_ns=np.zeros(0))  # fmt: skip
+    exit_ns = np.asarray([sync_ns + 5 * ms, sync_ns + 215 * ms])
+    window = {"open_ns": int(sync_ns - 27e9), "close_ns": int(sync_ns + 215 * ms)}
+    facts = dict(cell=cell, run_dir=str(tmp_path), watcher=Watcher(), peak={"bf16_flops_per_s": 197e12}, telemetry_events=[], window=window,
+                 entry_ns=exit_ns - ms, exit_ns=exit_ns, stretch_ns=run.trace_stretch(Watcher(), exit_ns, window))  # fmt: skip
     made = run.RunFacts(**facts)
     readers = loader.layer_readers(cell)
     assert readers["train_step.seqpol_device_ms"](made) == pytest.approx(50.0)
     assert readers["train_step.seqpol_device_mfu"](made) == pytest.approx(100 * cell.config["model_flops_per_grad_step"] / (0.050 * 197e12))
     assert readers["train_step.attn_device_ms"](made) == pytest.approx(40.0) and readers["train_step.moe_experts_device_ms"](made) == pytest.approx(10.0)
-    assert readers["player.decode_device_ms"](made) == pytest.approx(3.0)  # inside the cut stretch, read as before
-    assert device_time.train_ms(made) is None  # the harness's cut ends 50 ms after the sync: before the first train step
+    assert readers["player.decode_device_ms"](made) == pytest.approx(3.0)  # the rollout's decode, in the same stretch
+    assert device_time.train_ms(made) == pytest.approx(50.0)  # the stretch's own reduction holds the train steps

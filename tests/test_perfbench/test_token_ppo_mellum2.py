@@ -194,19 +194,22 @@ def test_the_new_readers_on_a_made_up_trace(tmp_path, monkeypatch):
     trace.mkdir(parents=True)
     (trace / "host.xplane.pb").write_bytes(b"")
 
-    class Watcher:
+    class Watcher:  # the traced cycle runs from the rollout end 5 ms after the sync to the next, 1.7 s after it
         sync = {"before_ns": sync_ns, "inside_ns": sync_ns}
+        traced_from, cycle = 0, 1
 
     cell = loader.Cell(CELL)
     config = cell.config
     open_ns = int(sync_ns - 27e9)
+    exit_ns = np.asarray([sync_ns + 5 * ms, sync_ns + 1700 * ms])
     keys = 3 * 60_000_000.0  # three gradient steps' worth, under the most the cell's shapes allow
     counters = [{"event": "counters", "name": "seqpol/update", "t_mono_ns": open_ns + int(i * 7e9), "gradient_steps": 3, "held_pairs": 3 * 188416.0,
                  "routed_pairs": 3 * 1507328.0, "tokens_decoded": 192 * 64, "rows_prefilled": 19, "cache_positions": 192 * 64 * 1100.5,
                  "window_keys": keys, "ring_wrapped_rows": 40 + i} for i in range(3)]  # fmt: skip
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    facts = dict(cell=cell, run_dir=str(tmp_path), watcher=Watcher(), peak=peak, telemetry_events=counters,
-                 window={"open_ns": open_ns, "close_ns": int(sync_ns + 20 * ms)}, entry_ns=np.zeros(0), exit_ns=np.zeros(0))  # fmt: skip
+    window = {"open_ns": open_ns, "close_ns": int(sync_ns + 1700 * ms)}
+    facts = dict(cell=cell, run_dir=str(tmp_path), watcher=Watcher(), peak=peak, telemetry_events=counters, window=window,
+                 entry_ns=exit_ns - ms, exit_ns=exit_ns, stretch_ns=run.trace_stretch(Watcher(), exit_ns, window))  # fmt: skip
     made = run.RunFacts(**facts)
     readers = loader.layer_readers(cell)
     assert readers["train_step.attn_window_device_ms"](made) == pytest.approx(900.0)  # 300 + 500 + 100: the projections' 200 and the experts' 50 are not its
