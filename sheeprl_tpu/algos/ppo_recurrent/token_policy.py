@@ -55,9 +55,9 @@ Array = jax.Array
 #: what a rollout stores of the core's carry at each step, and ``build_sequences`` emits per sequence
 CARRY_KEYS = ("prev_len", "prev_env")
 TRAIN_KEYS = ["tokens", "n_tokens", "actions", "logprobs", "values", "returns", "advantages"]
-#: what ``seqpol_train_step`` returns, in order
+#: what ``seqpol_train_step`` returns, in order (the last only where the core has window layers)
 METRICS = ("policy_loss", "value_loss", "entropy_loss", "mtp_loss", "routed_pairs", "held_pairs", "max_expert_pairs",
-           "real_positions", "padded_positions")  # fmt: skip
+           "real_positions", "padded_positions", "window_pairs_scored")  # fmt: skip
 
 
 class TokenPolicy:
@@ -281,7 +281,10 @@ def token_loss(params: Any, agent: TokenPolicy, batch: Dict[str, Array], snap: A
         counters = seqpol.merge_counters(counters, extra)
     total = pg + vf_coef * v + ent_coef * ent + mtp_coef * mtp
     positions = jnp.stack([lay["valid"].sum().astype(jnp.float32), jnp.asarray(float(lay["valid"].size), jnp.float32)])
-    return total, jnp.concatenate([jnp.stack([pg, v, ent, mtp]), counters, positions])
+    metrics = [jnp.stack([pg, v, ent, mtp]), counters, positions]
+    if seqpol.window_layers(core):
+        metrics.append(seqpol.window_pairs_scored(core, lay["positions"], lay["valid"], batch["len0"], remat)[None])
+    return total, jnp.concatenate(metrics)
 
 
 def make_token_train_fn(fabric: Any, agent: TokenPolicy, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
@@ -312,7 +315,7 @@ def window_keys(seqs: Dict[str, np.ndarray], core: seqpol.SeqPolConfig) -> int:
     """Over the real queries of ``seqs`` (a prompt's prefix and the steps), the
     keys inside each one's window, summed over the window layers: a query at
     position ``q`` has ``min(q + 1, sliding_window)``. What the band cannot avoid."""
-    layers = sum(core.operator(i) == seqpol.SLIDING for i in range(core.num_hidden_layers))
+    layers = seqpol.window_layers(core)
     if not layers:
         return 0
     count = (seqs["n0"] - 1) + seqs["mask"].sum(axis=1).astype(np.int64)  # a sequence's real slots lie at len0, len0 + 1, ...
@@ -507,6 +510,9 @@ def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, 
         conv_state_resets=int(rows_reset) * sum(core.operator(i) == seqpol.CONV for i in range(core.num_hidden_layers)),
         # over the update's real queries, the keys inside each one's window, summed over the window layers and the epochs
         window_keys=int(window_keys),
+        # the pairs of a query and a key that the window layers' blocks scored, with the ring or without, over the
+        # update's gradient steps: ``window_keys`` is the share of them that the band needed
+        window_pairs_scored=int(total.get("window_pairs_scored", 0)),
         # rows that stood at position ``sliding_window`` or beyond as the rollout ended: their rings had wrapped
         ring_wrapped_rows=int(ring_wrapped_rows),
     )

@@ -379,21 +379,29 @@ Context = Tuple[Tuple[Array, ...], Array, Array]
 WHOLE_UP_TO = 1024
 
 
+def _query_block(S: int) -> int:
+    """The queries of a block of a row of ``S`` slots: :data:`QUERY_BLOCK`, or
+    ``S`` where the row is scored in one block (:func:`_by_query_blocks`)."""
+    n, rest = divmod(S, QUERY_BLOCK)
+    return S if n <= 1 or (rest and S <= WHOLE_UP_TO) else QUERY_BLOCK
+
+
 def _by_query_blocks(block: Any, queries: Tuple[Array, ...], B: int, S: int) -> Array:
     """``block(*queries of a block, their slots)`` over blocks of
-    :data:`QUERY_BLOCK` queries, under ``jax.checkpoint``; ``[B, S, ...]``.
+    :func:`_query_block` queries, under ``jax.checkpoint``; ``[B, S, ...]``.
     A length that is no multiple of the block is scored whole up to
     :data:`WHOLE_UP_TO` slots, and beyond in whole blocks with a last shorter
     one behind them."""
     block = jax.checkpoint(block)
-    n, rest = divmod(S, QUERY_BLOCK)
-    if n <= 1 or (rest and S <= WHOLE_UP_TO):
+    b = _query_block(S)
+    if b == S:
         return block(*queries, jnp.arange(S))
-    whole = n * QUERY_BLOCK
-    split = lambda a: jnp.moveaxis(a[:, :whole].reshape(B, n, QUERY_BLOCK, *a.shape[2:]), 1, 0)  # noqa: E731
-    out = lax.map(lambda t: block(*t), (*(split(q) for q in queries), jnp.arange(whole).reshape(n, QUERY_BLOCK)))
+    n = S // b
+    whole = n * b
+    split = lambda a: jnp.moveaxis(a[:, :whole].reshape(B, n, b, *a.shape[2:]), 1, 0)  # noqa: E731
+    out = lax.map(lambda t: block(*t), (*(split(q) for q in queries), jnp.arange(whole).reshape(n, b)))
     out = jnp.moveaxis(out, 0, 1).reshape(B, whole, *out.shape[3:])
-    if not rest:
+    if whole == S:
         return out
     return jnp.concatenate([out, block(*(q[:, whole:] for q in queries), jnp.arange(whole, S))], axis=1)
 
@@ -416,26 +424,22 @@ def ring_seen(positions: Array, size: int) -> Array:
     return (jnp.arange(size)[None, :] <= positions[:, None]) | (positions[:, None] >= size)
 
 
-def _with_context(ctx: Optional[Context], own: Tuple[Array, ...], valid: Array,
-                  positions: Array) -> Tuple[Tuple[Array, ...], Array, Array, Array]:  # fmt: skip
+def _with_context(ctx: Optional[Context], own: Tuple[Array, ...], valid: Array) -> Tuple[Tuple[Array, ...], Array, Array]:
     """The keys a row's queries may see: its own entries ``own`` (``[B, S,
-    .]`` each, at ``positions [B, S]``) behind the entries of the cache row it
-    continues, a ring of ``C`` entries that held ``length`` positions: the
-    first ``length`` of them, all once it had wrapped. Returns the joined
-    entries, which of them exist, each one's slot (a key is seen by the
+    .]`` each) behind the entries of the cache row it continues, which held
+    ``length`` positions: the first ``length`` of them. Returns the joined
+    entries, which of them exist and each one's slot (a key is seen by the
     queries at or after it, by slot among the row's own, always for the cache:
-    slot ``-1``) and each one's position (:func:`ring_positions` for the
-    cache's: what a window is counted in)."""
+    slot ``-1``)."""
     B, S = valid.shape
     key_slot = jnp.broadcast_to(jnp.arange(S), (B, S))
     if ctx is None:
-        return own, valid, key_slot, positions
+        return own, valid, key_slot
     state, row, length = ctx
     C = state[0].shape[1]
     keys = tuple(jnp.concatenate([c[row].astype(o.dtype), o], axis=1) for c, o in zip(state, own))
     key_ok = jnp.concatenate([jnp.arange(C)[None, :] < length[:, None], valid], axis=1)
-    key_position = jnp.concatenate([ring_positions(length - 1, C), positions], axis=1)
-    return keys, key_ok, jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1), key_position
+    return keys, key_ok, jnp.concatenate([jnp.full((B, C), -1), key_slot], axis=1)
 
 
 def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid: Array,
@@ -451,7 +455,7 @@ def mla_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid
     H = cfg.num_attention_heads
     q_nope, q_rope = _queries(p, cfg, x, positions)
     c_kv, k_rope = latent_kv(p, cfg, x, positions)
-    (keys_c, keys_r), key_ok, key_slot, _ = _with_context(ctx, (c_kv, k_rope), valid, positions)
+    (keys_c, keys_r), key_ok, key_slot = _with_context(ctx, (c_kv, k_rope), valid)
     w_k, w_v = _kv_b(p, cfg, x.dtype)
     k_nope = jnp.einsum("bkc,chd->bkhd", keys_c, w_k)
     v = jnp.einsum("bkc,chd->bkhd", keys_c, w_v)
@@ -535,29 +539,99 @@ def gqa_sequence(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, valid
     ``q - W < p <= q``, among the row's own and in the ring it continues, whose
     entries it stops seeing one by one. Returns the output and the rows' own
     keys (after norm and rotation) and values: ``[B, S, G x d]``, or for a
-    window layer the ring a prefill leaves, ``[B, W, G x d]`` (:func:`_ring_of`)."""
+    window layer the ring a prefill leaves, ``[B, W, G x d]`` (:func:`_ring_of`).
+    A window layer's blocks of queries score only the keys their band can
+    reach (:func:`_band_blocks`)."""
     B, S, _ = x.shape
     H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     window = _window(cfg, kind)
     q, k, v = _gqa_qkv(p, cfg, x, positions, cfg.rope(kind))
     own = (k.reshape(B, S, G * hd), v.reshape(B, S, G * hd))
-    (keys, values), key_ok, key_slot, key_position = _with_context(ctx, own, valid, positions)
-    keys, values = keys.reshape(B, -1, G, hd), values.reshape(B, -1, G, hd)
 
-    def block(qb, *at):  # under a window the queries' positions, then their slots
-        q_slot = at[-1]
+    def attend(qb, keys, values, seen):  # ``seen [B, 1, 1, q, k]``: which keys each query of the block sees
         qb = qb.reshape(B, -1, G, H // G, hd)
         s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, keys, preferred_element_type=jnp.float32)
-        seen = key_ok[:, None, None, None, :] & (key_slot[:, None, None, None, :] <= q_slot[None, None, None, :, None])
-        if window:
-            q_position = at[0]
-            seen = seen & (q_position[:, None, None, :, None] - key_position[:, None, None, None, :] < window)
         w = jax.nn.softmax(jnp.where(seen, s * hd**-0.5, -1e30), axis=-1)
         return jnp.einsum("bgrqk,bkgd->bqgrd", w.astype(x.dtype), values).reshape(B, -1, H * hd)
 
-    with _core_scope(window):
-        out = _by_query_blocks(block, (q, positions) if window else (q,), B, S)
-    return _mm(out, p["o"]["kernel"]), _ring_of(own, positions, valid, window) if window else own
+    if window:
+        with _core_scope(window):
+            out = _band_blocks(attend, q, (k, v), valid, positions, ctx, window)
+        return _mm(out, p["o"]["kernel"]), _ring_of(own, positions, valid, window)
+    (keys, values), key_ok, key_slot = _with_context(ctx, own, valid)
+    keys, values = keys.reshape(B, -1, G, hd), values.reshape(B, -1, G, hd)
+
+    def block(qb, q_slot):
+        return attend(qb, keys, values, key_ok[:, None, None, None, :] & (key_slot[:, None, None, None, :] <= q_slot[None, None, None, :, None]))
+
+    return _mm(_by_query_blocks(block, (q,), B, S), p["o"]["kernel"]), own
+
+
+def ring_reach(positions: Array, valid: Array, length: Array, window: int) -> Array:
+    """``[B, S]``: which queries, at ``positions`` in rows that continue rings
+    that held ``length [B]`` positions, are real and see an entry of the ring
+    through a band of ``window`` positions: the newest entry, position ``length
+    - 1``, lies inside the band (a ring holds nothing newer, and nothing of a
+    row that begins: ``length`` 0)."""
+    return valid & (length[:, None] > 0) & (positions - (length[:, None] - 1) < window)
+
+
+def _band_width(S: int, window: int) -> int:
+    """The slots of a row's own keys that a block of queries of a window layer scores (:func:`_band_blocks`)."""
+    return min(S, window + _query_block(S))
+
+
+def _band_blocks(attend: Any, q: Array, own: Tuple[Array, Array], valid: Array, positions: Array,
+                 ctx: Optional[Context], window: int) -> Array:  # fmt: skip
+    """A window layer's blocks of queries (:func:`_by_query_blocks`), each
+    scored by ``attend(queries, keys, values, seen)`` against the keys its band
+    can reach: a slice of ``min(S, W + b)`` of the row's own slots (``own``:
+    keys and values ``[B, S, G, d]``) that ends at the block's last slot,
+    clamped to the row, under the slot and position masks as over the whole
+    row; and before it the ring the rows continue (``ctx``) only where a real
+    query of the block sees one of its entries (:func:`ring_reach`), by
+    ``lax.cond``. Along a row a real slot's position is at least one more than
+    the real slot's before it, so no key ``W`` or more slots before a query is
+    inside its band: the slice holds every key a query of the block sees, and
+    a key left out would have added ``exp(-1e30 - max) = 0``. Each of the two
+    forms is under ``jax.checkpoint`` of its own inside the block's: the
+    backward pass then makes again the scores of the form the block took, and
+    gives neither form room for what the other would keep."""
+    B, S = valid.shape
+    keys, values = own
+    width = _band_width(S, window)
+
+    def own_part(q_pos, q_slot, start):
+        take = lambda a: lax.dynamic_slice_in_dim(a, start, width, axis=1)  # noqa: E731
+        slot = start + jnp.arange(width)
+        seen = take(valid)[:, None, :] & (slot[None, None, :] <= q_slot[None, :, None]) & (q_pos[:, :, None] - take(positions)[:, None, :] < window)
+        return take(keys), take(values), seen
+
+    def alone(qb, q_pos, q_slot, start):
+        k, v, seen = own_part(q_pos, q_slot, start)
+        return attend(qb, k, v, seen[:, None, None])
+
+    if ctx is not None:
+        state, row, length = ctx
+        C = state[0].shape[1]
+        ring_k, ring_v = (c[row].astype(keys.dtype).reshape(B, C, *keys.shape[2:]) for c in state)
+        ring_ok, ring_at = jnp.arange(C)[None, :] < length[:, None], ring_positions(length - 1, C)
+
+        def with_ring(qb, q_pos, q_slot, start):
+            k, v, seen = own_part(q_pos, q_slot, start)
+            ring_seen = ring_ok[:, None, :] & (q_pos[:, :, None] - ring_at[:, None, :] < window)
+            seen = jnp.concatenate([ring_seen, seen], axis=-1)[:, None, None]
+            return attend(qb, jnp.concatenate([ring_k, k], axis=1), jnp.concatenate([ring_v, v], axis=1), seen)
+
+    def block(qb, q_pos, *at):  # where the rows continue a ring, which queries see it (:func:`ring_reach`); then the slots
+        q_slot = at[-1]
+        start = jnp.clip(q_slot[-1] + 1 - width, 0, S - width)
+        if ctx is None:
+            return alone(qb, q_pos, q_slot, start)
+        return lax.cond(at[0].any(), jax.checkpoint(with_ring), jax.checkpoint(alone), qb, q_pos, q_slot, start)
+
+    reach = () if ctx is None else (ring_reach(positions, valid, ctx[2], window),)
+    return _by_query_blocks(block, (q, positions, *reach), B, S)
 
 
 def gqa_decode(p: Params, cfg: SeqPolConfig, x: Array, positions: Array, state: Tuple[Array, Array],
@@ -835,13 +909,42 @@ def block_sequence(p: Params, cfg: SeqPolConfig, kind: str, x: Array, positions:
     return x + f, own, counts
 
 
+def _chunk_rows(cfg: SeqPolConfig, B: int, remat: bool) -> int:
+    """The rows of one chunk of :func:`block_by_rows` over ``B`` rows: ``cfg.row_chunk`` where they divide into such chunks
+    and the update asks for it, else all of them."""
+    return cfg.row_chunk if remat and B > cfg.row_chunk and not B % cfg.row_chunk else B
+
+
+def window_layers(cfg: SeqPolConfig) -> int:
+    """How many layers attend inside a sliding window."""
+    return sum(cfg.operator(i) == SLIDING for i in range(cfg.num_hidden_layers))
+
+
+def window_pairs_scored(cfg: SeqPolConfig, positions: Array, valid: Array, length: Array, remat: bool) -> Array:
+    """The pairs of a query and a key that the window layers of
+    :func:`forward_sequence` score over rows ``[B, S]`` that continue rings
+    which held ``length [B]`` positions, by the rule their blocks follow
+    (:func:`_band_blocks`): in each chunk of rows (:func:`block_by_rows`) and
+    each block of queries, the block's queries times the slice of the row's own
+    keys, and times the ring's entries too where a real query of the chunk's
+    block sees one (:func:`ring_reach`). Float32, summed over the window layers."""
+    B, S = valid.shape
+    rows, b, W = _chunk_rows(cfg, B, remat), _query_block(S), cfg.sliding_window
+    n = -(-S // b)
+    reach = jnp.pad(ring_reach(positions, valid, length, W), ((0, 0), (0, n * b - S)))
+    with_ring = reach.reshape(B // rows, rows, n, b).any(axis=(1, 3))  # [chunks, blocks]
+    queries = jnp.minimum(b, S - b * jnp.arange(n)).astype(jnp.float32)  # of each block
+    return window_layers(cfg) * rows * (queries * (_band_width(S, W) + W * with_ring)).sum()
+
+
 def block_by_rows(p: Params, cfg: SeqPolConfig, kind: str, x: Array, positions: Array, valid: Array,
                   ctx: Optional[Context], remat: bool) -> Tuple[Array, Tuple[Array, ...], Array]:  # fmt: skip
     """:func:`block_sequence`, ``cfg.row_chunk`` rows at a time where the
     update asks for it (``remat``): rows do not see each other, so a chunk's
     intermediates are all that is alive at once, and the backward pass, which
     keeps only each chunk's input, makes them again."""
-    B, rc = x.shape[0], cfg.row_chunk
+    B = x.shape[0]
+    rc = _chunk_rows(cfg, B, remat)
     if not remat:
         x, own, counts = block_sequence(p, cfg, kind, x, positions, valid, ctx)
         return x, own, counters_of(counts)
@@ -852,7 +955,7 @@ def block_by_rows(p: Params, cfg: SeqPolConfig, kind: str, x: Array, positions: 
         return block_sequence(p, cfg, kind, x, positions, valid, None if state is None else (state, *of_rows))
 
     fn = jax.checkpoint(fn)
-    if B <= rc or B % rc:
+    if rc == B:
         x, own, counts = fn((x, positions, valid, of_rows))
         return x, own, counters_of(counts)
     split = lambda a: a.reshape(B // rc, rc, *a.shape[1:])  # noqa: E731

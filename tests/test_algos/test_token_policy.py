@@ -19,6 +19,7 @@ import contextlib
 import dataclasses
 import os
 import signal
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -173,6 +174,21 @@ def ring_kept():
         seqpol.ring_seen, seqpol.OPERATORS[seqpol.SLIDING] = real_seen, real_op
 
 
+@pytest.fixture(params=["blocks_as_they_are", "blocks_of_4"])
+def query_blocks(request, monkeypatch):
+    """The whole-sequence attention's blocks of queries as they are, or of 4 queries with no row scored in one block:
+    the tiny rows then cross several blocks, a window layer's slices of its own keys clamped to the row, a last
+    shorter block, and blocks that take the ring beside blocks that do not."""
+    if request.param == "blocks_of_4":
+        _blocks_of_4(monkeypatch)
+    return request.param
+
+
+def _blocks_of_4(monkeypatch):
+    monkeypatch.setattr(seqpol, "QUERY_BLOCK", 4)
+    monkeypatch.setattr(seqpol, "WHOLE_UP_TO", 0)
+
+
 def test_the_programs_own_weights_have_the_references_tree(model):
     ours = seqpol.init_params(jax.random.PRNGKey(0), model.core())
     assert jax.tree.structure(ours) == jax.tree.structure(model.weights)
@@ -301,7 +317,7 @@ def test_the_convolution_token_by_token_equals_the_whole_sequence_form():
     assert gap(later[0], alone[0]) < TOL
 
 
-def test_the_window_layer_token_by_token_through_its_ring_equals_the_whole_sequence_form():
+def test_the_window_layer_token_by_token_through_its_ring_equals_the_whole_sequence_form(query_blocks):
     """One token at a time through the ring past two wraps, the whole rows at once under the band, the reference's
     banded attention, and rows that continue from a wrapped ring: one result."""
     cfg, p = core(MELLUM2_SIZES), reference_mellum2.init_weights({"model": MELLUM2_SIZES}, 3)["layers"]["0"]["attn"]
@@ -324,9 +340,12 @@ def test_the_window_layer_token_by_token_through_its_ring_equals_the_whole_seque
     # seeing its entries one by one, the first begins there, from nothing, whatever row 1 of the ring holds
     valid = jnp.ones((B, S - 11), bool)
     alone, _ = seqpol.gqa_sequence(p, cfg, x[1:, 11:], positions[:1, 11:] - 11, valid[:1], kind=seqpol.SLIDING)
-    begun, _ = seqpol.gqa_sequence(p, cfg, x[::-1, 11:], jnp.stack([positions[0, 11:] - 11, positions[1, 11:]]), valid,
-                                   (halfway, jnp.asarray([1, 0]), jnp.asarray([0, 11])), kind=seqpol.SLIDING)  # fmt: skip
+    later = jnp.stack([positions[0, 11:] - 11, positions[1, 11:]])
+    begun, _ = seqpol.gqa_sequence(p, cfg, x[::-1, 11:], later, valid, (halfway, jnp.asarray([1, 0]), jnp.asarray([0, 11])), kind=seqpol.SLIDING)
     assert gap(begun[0], alone[0]) < TOL and gap(begun[1], out[0, 11:]) < TOL
+    if query_blocks == "blocks_of_4":  # positions 11 to 17 see the ring's newest entry, 10: the blocks of 4 and 4 take it, the last of 1 does not
+        reach = seqpol.ring_reach(later, valid, jnp.asarray([0, 11]), W)
+        assert [bool(reach[:, at : at + 4].any()) for at in (0, 4, 8)] == [True, True, False]
 
 
 @pytest.mark.parametrize("S, blocks", [(18, "whole: a short row that is no multiple"), (24, "6 blocks"), (38, "9 blocks and a last one of 2")])
@@ -343,6 +362,95 @@ def test_the_query_blocks_give_what_one_block_gives(monkeypatch, S, blocks):
     for kind, one in zip((seqpol.SLIDING, seqpol.ATTENTION), want):
         assert gap(seqpol.gqa_sequence(p, cfg, x, positions, valid, kind=kind)[0], one) < TOL
     assert calls == {18: [], 24: [6, 6], 38: [9, 9]}[S]
+
+
+def _banded_whole(p, cfg, x, positions, valid, ctx):
+    """A window layer over whole rows as one masked computation: every query against every key a row holds, the
+    ring's entries (position ``newest - ((newest - j) mod W)`` at entry ``j``, the first ``length`` of them) and the
+    row's own, each seen where it exists, lies at or before the query's slot and inside its band."""
+    B, S, _ = x.shape
+    H, G, hd, W = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.sliding_window
+    state, row, length = ctx
+    q, k, v = seqpol._gqa_qkv(p, cfg, x, positions, cfg.rope(seqpol.SLIDING))
+    keys = jnp.concatenate([state[0][row].reshape(B, W, G, hd), k], axis=1)
+    values = jnp.concatenate([state[1][row].reshape(B, W, G, hd), v], axis=1)
+    newest = (length - 1)[:, None]
+    key_position = jnp.concatenate([newest - jnp.mod(newest - jnp.arange(W)[None, :], W), positions], axis=1)
+    key_ok = jnp.concatenate([jnp.arange(W)[None, :] < length[:, None], valid], axis=1)
+    key_slot = jnp.concatenate([jnp.full((B, W), -1), jnp.broadcast_to(jnp.arange(S), (B, S))], axis=1)
+    seen = key_ok[:, None, :] & (key_slot[:, None, :] <= jnp.arange(S)[None, :, None]) & (positions[:, :, None] - key_position[:, None, :] < W)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(B, S, G, H // G, hd), keys) * hd**-0.5
+    w = jax.nn.softmax(jnp.where(seen[:, None, None], scores, -1e30), axis=-1)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", w, values).reshape(B, S, H * hd) @ p["o"]["kernel"]
+
+
+def test_the_window_layer_scores_its_band_as_the_whole_rows_masked_compute():
+    """Rows of 384 slots in three blocks of 128 queries behind a window of 8: a row that begins (``length`` 0), a row
+    that continues a ring not yet wrapped (5 positions) and a row that continues a wrapped one (20), their real slots
+    from 0, 130 and 256 on. The first block takes no ring, the second and third do. The output at every real query and
+    the gradients of the real queries' outputs with respect to the inputs, the ring and the layer's parameters are
+    the whole rows' masked computation's."""
+    cfg, p = core(MELLUM2_SIZES), reference_mellum2.init_weights({"model": MELLUM2_SIZES}, 3)["layers"]["0"]["attn"]
+    B, S, D, W = 3, 384, MELLUM2_SIZES["hidden_size"], MELLUM2_SIZES["sliding_window"]
+    assert S == 3 * seqpol.QUERY_BLOCK and S > W + seqpol.QUERY_BLOCK
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    x = jax.random.normal(keys[0], (B, S, D))
+    first, length = jnp.asarray([0, 130, 256]), jnp.asarray([0, 5, 20])
+    slot = jnp.arange(S)[None, :]
+    valid = (slot >= first[:, None]) & ((slot < 300) | (jnp.arange(B) > 0)[:, None])  # the row that begins ends at slot 299
+    positions = jnp.maximum(length[:, None] + slot - first[:, None], 0)
+    state = tuple(jax.random.normal(kk, (B, W, 16)) for kk in keys[1:3])  # entries past a ring's length hold what they may
+    row = jnp.asarray([2, 0, 1])
+    ct = jnp.where(valid[..., None], jax.random.normal(keys[3], (B, S, D)), 0)
+    reach = seqpol.ring_reach(positions, valid, length, W)
+    assert [bool(reach[:, at : at + 128].any()) for at in (0, 128, 256)] == [False, True, True]
+
+    def loss(form):
+        return jax.jit(jax.value_and_grad(lambda p, x, state: jnp.sum(form(p, x, state) * ct), argnums=(0, 1, 2)))
+
+    ours = loss(lambda p, x, state: seqpol.gqa_sequence(p, cfg, x, positions, valid, (state, row, length), kind=seqpol.SLIDING)[0])
+    theirs = loss(lambda p, x, state: _banded_whole(p, cfg, x, positions, valid, (state, row, length)))
+    out = seqpol.gqa_sequence(p, cfg, x, positions, valid, (state, row, length), kind=seqpol.SLIDING)[0]
+    assert gap(out[valid], _banded_whole(p, cfg, x, positions, valid, (state, row, length))[valid]) < 1e-5
+    (value, grads), (want, want_grads) = ours(p, x, state), theirs(p, x, state)
+    assert abs(float(value) - float(want)) < 1e-5 * abs(float(want))
+    gaps = jax.tree.map(gap, grads, want_grads)
+    assert max(jax.tree.leaves(gaps)) < 1e-5, gaps
+
+
+def _primitives(jaxpr, found=None):
+    """The names of every primitive in ``jaxpr`` and in the jaxprs inside its equations."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, found)
+    return found
+
+
+def test_the_band_path_is_the_window_layers_alone(monkeypatch):
+    """A slice at a block's own slot and a choice of form by ``lax.cond`` are in the window layer's jaxpr, forward and
+    backward, and in neither of the other attention forms' with a context (full-attention grouped-query and latent
+    attention), whose rows cross blocks of 4 here as the window layer's do."""
+    _blocks_of_4(monkeypatch)
+    B, S = 2, 12
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, S, 32))
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S)) + jnp.asarray([[0], [5]])
+    valid = jnp.ones((B, S), bool)
+    band = {"dynamic_slice", "dynamic_update_slice", "cond"}
+    for sizes, weights, layer, form in ((MELLUM2_SIZES, reference_mellum2, 0, partial(seqpol.gqa_sequence, kind=seqpol.SLIDING)),
+                                        (LFM2_SIZES, reference_lfm2, 1, seqpol.gqa_sequence), (SIZES, reference, 1, seqpol.mla_sequence)):  # fmt: skip
+        cfg, p = core(sizes), weights.init_weights({"model": sizes}, 3)["layers"][str(layer)]["attn"]
+        ctx = (tuple(jnp.ones(shape) for shape in seqpol.state_shapes(cfg, B)[layer]), jnp.asarray([1, 0]), jnp.asarray([0, 5]))
+        forward = _primitives(jax.make_jaxpr(lambda x: form(p, cfg, x, positions, valid, ctx)[0])(x).jaxpr)
+        backward = _primitives(jax.make_jaxpr(jax.grad(lambda x: jnp.sum(form(p, cfg, x, positions, valid, ctx)[0] ** 2)))(x).jaxpr)
+        if sizes is MELLUM2_SIZES:
+            assert {"dynamic_slice", "cond"} <= forward and band <= backward
+        else:
+            assert not band & (forward | backward), band & (forward | backward)
 
 
 def test_yarns_table_against_a_hand_computed_case():
@@ -539,7 +647,7 @@ def test_a_sequence_from_a_snapshot_equals_the_episode_evaluated_whole(model):
         assert abs(faulty["value_loss"] - ours["value_loss"]) > 1000 * TOL
 
 
-def test_a_sequence_from_a_wrapped_rings_snapshot_equals_the_episode_evaluated_whole():
+def test_a_sequence_from_a_wrapped_rings_snapshot_equals_the_episode_evaluated_whole(query_blocks):
     """The second episode has 13 of its 19 inputs before the rollout: the window layers' rings in the snapshot have
     wrapped (they hold positions 5 to 12, entry 0 position 8), and its 6 steps stop seeing those entries one by one."""
     model = Model("mellum2")
@@ -548,6 +656,12 @@ def test_a_sequence_from_a_wrapped_rings_snapshot_equals_the_episode_evaluated_w
     assert batch["len0"].tolist() == [0, 13, 0] and snap[0][0].shape == (2, 8, 16) and snap[2][0].shape == (2, 32, 16)
     ours, _ = _program_loss(model.weights, agent, batch, snap, 0.0)
     _same_losses(ours, model.losses(aligned, {**CONSTS, "mtp_loss_coef": 0.0}))
+    # the pairs the two window layers scored, 3 rows of 6 + 8 slots in one chunk: one block of 14 queries against the
+    # row's 14 keys and the ring's 8; or blocks of 4, 4, 4 and 2 against slices of 12 keys, the ring beside them in
+    # the two blocks that hold the second episode's steps (slots 6 to 11, positions 13 to 18: all inside the band of 12)
+    assert ours["window_pairs_scored"] == 2 * 3 * {"blocks_as_they_are": 14 * (14 + 8), "blocks_of_4": 4 * 12 + 2 * 4 * (12 + 8) + 2 * 12}[query_blocks]
+    # what the band needed: the first episode's 8 real queries at positions 0 to 7, the second's 6 past the window
+    assert ours["window_pairs_scored"] >= token_policy.window_keys(batch, agent.core) == 2 * (sum(range(1, 9)) + 6 * 8)
     for fault in (window_ignored, yarn_left_out):  # the snapshot was made by the sound program: the update alone is faulty
         with fault():
             faulty, _ = _program_loss(model.weights, token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32), batch, snap, 0.0)
@@ -555,10 +669,13 @@ def test_a_sequence_from_a_wrapped_rings_snapshot_equals_the_episode_evaluated_w
 
 
 # (e) one update equals the reference's: losses, the gradient by leaf, the weights after; MTP term on and off
-@pytest.mark.parametrize("name, mtp_coef", [("glm", 0.1), ("glm", 0.0), ("lfm2", 0.0), ("mellum2", 0.0)], ids=["glm-mtp_on", "glm-mtp_off", "lfm2", "mellum2"])
-def test_one_update_equals_the_reference(name, mtp_coef):
+@pytest.mark.parametrize("name, mtp_coef, blocks_of_4", [("glm", 0.1, False), ("glm", 0.0, False), ("lfm2", 0.0, False), ("mellum2", 0.0, False),
+                                                        ("mellum2", 0.0, True)], ids=["glm-mtp_on", "glm-mtp_off", "lfm2", "mellum2", "mellum2-blocks_of_4"])  # fmt: skip
+def test_one_update_equals_the_reference(monkeypatch, name, mtp_coef, blocks_of_4):
     import optax
 
+    if blocks_of_4:  # the gradient through a window layer's slices of its own keys (no row continues a ring here)
+        _blocks_of_4(monkeypatch)
     model = Model(name)
     weights = model.weights
     agent = token_policy.TokenPolicy(model.core(), prompt_max=6, dtype=jnp.float32)
