@@ -936,35 +936,31 @@ def main(fabric, cfg: Dict[str, Any]):
     fence = DispatchFence(depth=int(cfg.algo.get("dispatch_fence_depth", 4) or 4))
     last_grad_steps = 0  # heartbeat window: train_fn invocations since last log
     placement_recorded = False
+    # A turn's work lies in spans other than the two window spans
+    # (howto/telemetry.md): ``loop/head``, ``loop/store_step``, ``train/plan``
+    # and ``loop/tail`` around them and leaves inside them, which a window span
+    # joins with a few bindings of glue
     for update in range(start_step, num_updates + 1):
-        telemetry_advance(policy_step)
-        if resil.preempt_requested():
-            # drain the dispatch queue before snapshotting: the state fn's
-            # device_get would otherwise fetch mid-flight donated buffers
-            fence.drain()
-            last_checkpoint = policy_step
-            resil.emergency_checkpoint(
-                ckpt_path_fn(policy_step),
-                ckpt_state_fn(update - 1),
-                replay_buffer=rb if cfg.buffer.checkpoint else None,
-            )
-            preempted = True
-            break
-        telemetry_mark_warm_after_warmup(update, learning_starts)
-        policy_step += num_envs * num_processes
+        with timer("loop/head"):
+            telemetry_advance(policy_step)
+            if resil.preempt_requested():
+                # drain the dispatch queue before snapshotting: the state fn's
+                # device_get would otherwise fetch mid-flight donated buffers
+                fence.drain()
+                last_checkpoint = policy_step
+                resil.emergency_checkpoint(
+                    ckpt_path_fn(policy_step),
+                    ckpt_state_fn(update - 1),
+                    replay_buffer=rb if cfg.buffer.checkpoint else None,
+                )
+                preempted = True
+                break
+            telemetry_mark_warm_after_warmup(update, learning_starts)
+            policy_step += num_envs * num_processes
 
         with timer("Time/env_interaction_time"):
-            if update <= learning_starts and cfg.checkpoint.resume_from is None:
-                real_actions = actions = np.array(envs.action_space.sample())
-                if not is_continuous:
-                    actions = np.concatenate(
-                        [
-                            np.eye(act_dim, dtype=np.float32)[act.reshape(-1)]
-                            for act, act_dim in zip(actions.reshape(len(actions_dim), -1), actions_dim)
-                        ],
-                        axis=-1,
-                    )
-            else:
+            on_policy = update > learning_starts or cfg.checkpoint.resume_from is not None
+            if on_policy:
                 if queued_forward is None:
                     # the first turn on the policy, or the first after a resume
                     queue_forward(prepare_obs(obs, cnn_keys=cnn_keys, num_envs=num_envs), ahead=False)
@@ -975,7 +971,19 @@ def main(fabric, cfg: Dict[str, Any]):
                 with timer("player/get_actions"):
                     # the fetch of a forward that the last turn queued behind its train steps
                     actions = np.asarray(pending)
-                if is_continuous:
+            with timer("player/to_env"):
+                # the action in the env's form, and in the ring's
+                if not on_policy:
+                    real_actions = actions = np.array(envs.action_space.sample())
+                    if not is_continuous:
+                        actions = np.concatenate(
+                            [
+                                np.eye(act_dim, dtype=np.float32)[act.reshape(-1)]
+                                for act, act_dim in zip(actions.reshape(len(actions_dim), -1), actions_dim)
+                            ],
+                            axis=-1,
+                        )
+                elif is_continuous:
                     real_actions = actions
                 else:
                     splits = np.cumsum(actions_dim)[:-1]
@@ -984,8 +992,7 @@ def main(fabric, cfg: Dict[str, Any]):
                     )
                     if real_actions.shape[-1] == 1 and not is_multidiscrete:
                         real_actions = real_actions[..., 0]
-
-            step_data["actions"] = np.asarray(actions, np.float32).reshape(1, num_envs, -1)
+                step_data["actions"] = np.asarray(actions, np.float32).reshape(1, num_envs, -1)
             with timer("ring/add"):
                 rb.add(step_data, validate_args=cfg.buffer.validate_args)
 
@@ -1065,16 +1072,16 @@ def main(fabric, cfg: Dict[str, Any]):
                 step_data["is_first"][:, dones_idxes] = 1.0
                 player.init_states(dones_idxes)
 
-        # the turn after this one acts on the policy, if there is one: all its
-        # forward needs is on the host (``prepared_next``) or queued (the weights)
-        acts_next = update < num_updates and (
-            update >= learning_starts or cfg.checkpoint.resume_from is not None
-        )
-
         # ---------------- training ---------------- #
-        per_rank_gradient_steps = ratio(policy_step / num_processes) if update >= learning_starts else 0
-        if per_rank_gradient_steps > 0:
+        with timer("train/plan"):
+            # the turn after this one acts on the policy, if there is one: all its
+            # forward needs is on the host (``prepared_next``) or queued (the weights)
+            acts_next = update < num_updates and (
+                update >= learning_starts or cfg.checkpoint.resume_from is not None
+            )
+            per_rank_gradient_steps = ratio(policy_step / num_processes) if update >= learning_starts else 0
             window_finite: list = []  # fused path: [chunk] bool vectors, one per dispatch
+        if per_rank_gradient_steps > 0:
             with timer("Time/train_time"):
                 if fused_k > 0:
                     # fused path: the whole window is ceil(G / K) superstep
@@ -1131,10 +1138,11 @@ def main(fabric, cfg: Dict[str, Any]):
                     )
                     window_ema_dispatches = 0
                     for batch in batches:
-                        # the window's first come from the end of the last window
-                        target_critic_params, key, train_key, ema_dispatches = queued_window or target_and_keys()
-                        queued_window = None
-                        window_ema_dispatches += ema_dispatches
+                        with timer("train/keys"):
+                            # the window's first come from the end of the last window
+                            target_critic_params, key, train_key, ema_dispatches = queued_window or target_and_keys()
+                            queued_window = None
+                            window_ema_dispatches += ema_dispatches
                         with timer("train/dispatch"):
                             (
                                 wm_params,
@@ -1184,18 +1192,20 @@ def main(fabric, cfg: Dict[str, Any]):
                         # loop (one device round trip per train block); the queue
                         # drains at log time instead
                         pending_metrics.append(metrics)
-                # the per-step program took the trees the player held: it gets
-                # the window's newest before anything touches it again. Then,
-                # behind the train steps and before the host waits for them:
-                # the next turn's forward, then the next window's first target
-                # refresh and key split, which depend on nothing newer either
-                player.update_params(wm_params, actor_params)
-                if acts_next:
-                    queue_forward(prepared_next)
-                if fused_k == 0:
-                    queued_window = target_and_keys()
-                fence.push(metrics)
-                telemetry_train_window(window_dispatches, per_rank_gradient_steps)
+                with timer("train/queue_next"):
+                    # the per-step program took the trees the player held: it gets
+                    # the window's newest before anything touches it again. Then,
+                    # behind the train steps and before the host waits for them:
+                    # the next turn's forward, then the next window's first target
+                    # refresh and key split, which depend on nothing newer either
+                    player.update_params(wm_params, actor_params)
+                    if acts_next:
+                        queue_forward(prepared_next)
+                    if fused_k == 0:
+                        queued_window = target_and_keys()
+                    fence.push(metrics)
+                    telemetry_train_window(window_dispatches, per_rank_gradient_steps)
+                    train_step += num_processes
                 if not timer.disabled:
                     # only when timing: wait so Time/train_time measures
                     # the chip, not the async dispatch. What the wait is
@@ -1204,84 +1214,86 @@ def main(fabric, cfg: Dict[str, Any]):
                     # train steps (``train/block`` is the host's wait)
                     with timer("train/block"):
                         jax.block_until_ready(wm_params)
-                train_step += num_processes
-            if fused_k > 0:
-                # one tiny fetch per window: the [chunk] finite vectors the
-                # superstep computed in-dispatch, reduced on the host
-                window_ok = resil.window_ok(
-                    all(bool(np.all(np.asarray(jax.device_get(f)))) for f in window_finite), update
-                )
-            else:
-                # the window's LAST metric vector: NaNs in params propagate
-                # to every later loss, so one fetch per window suffices
-                window_ok = not resil.finite_checks or resil.check_finite(
-                    np.asarray(jax.device_get(metrics)), update
-                )
-            if not window_ok:
-                nan_rollback(update)
-                continue
-        elif acts_next:
-            queue_forward(prepared_next)
 
-        if cumulative_per_rank_gradient_steps > 0 and not placement_recorded:
-            # once, after the first train window: where the state that the
-            # run pays for actually lives (run record: resolved.state_devices)
-            placement_recorded = True
-            telemetry_resolved(
-                "state_devices",
-                {
-                    "params": tree_devices((wm_params, actor_params, critic_params, target_critic_params)),
-                    "optimizer": tree_devices((world_opt, actor_opt, critic_opt)),
-                    "replay": rb.devices() if use_device_rb else ["host"],
-                    "player_params": tree_devices((player.wm_params, player.actor_params)),
-                },
-            )
+        with timer("loop/tail"):
+            if per_rank_gradient_steps > 0:
+                if fused_k > 0:
+                    # one tiny fetch per window: the [chunk] finite vectors the
+                    # superstep computed in-dispatch, reduced on the host
+                    window_ok = resil.window_ok(
+                        all(bool(np.all(np.asarray(jax.device_get(f)))) for f in window_finite), update
+                    )
+                else:
+                    # the window's LAST metric vector: NaNs in params propagate
+                    # to every later loss, so one fetch per window suffices
+                    window_ok = not resil.finite_checks or resil.check_finite(
+                        np.asarray(jax.device_get(metrics)), update
+                    )
+                if not window_ok:
+                    nan_rollback(update)
+                    continue
+            elif acts_next:
+                queue_forward(prepared_next)
 
-        # ---------------- logging ---------------- #
-        if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
-            if pending_metrics:
-                # stack ON DEVICE first: one transfer for the whole window
-                # instead of one round trip per train block; fused entries
-                # are already [chunk, |METRIC_ORDER|] blocks
-                stacked = jnp.concatenate(
-                    [m if m.ndim == 2 else m[None] for m in pending_metrics], axis=0
+            if cumulative_per_rank_gradient_steps > 0 and not placement_recorded:
+                # once, after the first train window: where the state that the
+                # run pays for actually lives (run record: resolved.state_devices)
+                placement_recorded = True
+                telemetry_resolved(
+                    "state_devices",
+                    {
+                        "params": tree_devices((wm_params, actor_params, critic_params, target_critic_params)),
+                        "optimizer": tree_devices((world_opt, actor_opt, critic_opt)),
+                        "replay": rb.devices() if use_device_rb else ["host"],
+                        "player_params": tree_devices((player.wm_params, player.actor_params)),
+                    },
                 )
-                for metrics_np in np.asarray(jax.device_get(stacked)):
-                    for name, value in zip(METRIC_ORDER, metrics_np):
-                        aggregator.update(name, float(value))
-                pending_metrics.clear()
-            metrics_dict = aggregator.compute()
-            logger.log_metrics(metrics_dict, policy_step)
-            telemetry_run_metrics(metrics_dict)
-            aggregator.reset()
-            if policy_step > 0:
-                logger.log_metrics(
-                    {"Params/replay_ratio": cumulative_per_rank_gradient_steps * num_processes / policy_step},
-                    policy_step,
-                )
-            log_sps_and_heartbeat(
-                logger,
-                policy_step=policy_step,
-                env_steps=(policy_step - last_log) / num_processes * cfg.env.action_repeat,
-                train_steps=train_step - last_train,
-                train_invocations=cumulative_per_rank_gradient_steps - last_grad_steps,
-            )
-            last_log = policy_step
-            last_train = train_step
-            last_grad_steps = cumulative_per_rank_gradient_steps
-            report_prequeue()
 
-        # ---------------- checkpoint ---------------- #
-        if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
-            update == num_updates and cfg.checkpoint.save_last
-        ):
-            last_checkpoint = policy_step
-            fabric.call(
-                "on_checkpoint_coupled",
-                ckpt_path=ckpt_path_fn(policy_step),
-                state=ckpt_state_fn(update),
-                replay_buffer=rb if cfg.buffer.checkpoint else None,
-            )
+            # ---------------- logging ---------------- #
+            if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
+                if pending_metrics:
+                    # stack ON DEVICE first: one transfer for the whole window
+                    # instead of one round trip per train block; fused entries
+                    # are already [chunk, |METRIC_ORDER|] blocks
+                    stacked = jnp.concatenate(
+                        [m if m.ndim == 2 else m[None] for m in pending_metrics], axis=0
+                    )
+                    for metrics_np in np.asarray(jax.device_get(stacked)):
+                        for name, value in zip(METRIC_ORDER, metrics_np):
+                            aggregator.update(name, float(value))
+                    pending_metrics.clear()
+                metrics_dict = aggregator.compute()
+                logger.log_metrics(metrics_dict, policy_step)
+                telemetry_run_metrics(metrics_dict)
+                aggregator.reset()
+                if policy_step > 0:
+                    logger.log_metrics(
+                        {"Params/replay_ratio": cumulative_per_rank_gradient_steps * num_processes / policy_step},
+                        policy_step,
+                    )
+                log_sps_and_heartbeat(
+                    logger,
+                    policy_step=policy_step,
+                    env_steps=(policy_step - last_log) / num_processes * cfg.env.action_repeat,
+                    train_steps=train_step - last_train,
+                    train_invocations=cumulative_per_rank_gradient_steps - last_grad_steps,
+                )
+                last_log = policy_step
+                last_train = train_step
+                last_grad_steps = cumulative_per_rank_gradient_steps
+                report_prequeue()
+
+            # ---------------- checkpoint ---------------- #
+            if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
+                update == num_updates and cfg.checkpoint.save_last
+            ):
+                last_checkpoint = policy_step
+                fabric.call(
+                    "on_checkpoint_coupled",
+                    ckpt_path=ckpt_path_fn(policy_step),
+                    state=ckpt_state_fn(update),
+                    replay_buffer=rb if cfg.buffer.checkpoint else None,
+                )
 
     # drain materializes the newest fence marker too: the device has
     # finished every queued train dispatch before the closing bookkeeping

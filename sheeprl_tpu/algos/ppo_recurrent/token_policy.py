@@ -143,12 +143,15 @@ class TokenPlayer:
         self.last_logits: Optional[Array] = None
         #: entries of each layer that keeps positions (a cache: ``context``; a window layer's ring: ``sliding_window``) -> how many such layers
         self._cache_sizes = Counter(layer[0][1] for i, layer in enumerate(seqpol.state_shapes(core, 1)) if seqpol.OPERATORS[core.operator(i)].key == "attn")
-        #: counters since the start: rows prefilled, tokens decoded, entries the decodes attended to (a mean over the
-        #: layers that keep positions: a window layer attends to ``min(length, sliding_window)``), rows reset
+        #: counters since the start: rows prefilled, and the ``seqpol_prefill`` calls that did it, the slots those calls
+        #: computed and the prompts' tokens among them; tokens decoded, entries the decodes attended to (a mean over the
+        #: layers that keep positions: a window layer attends to ``min(length, sliding_window)``)
         self.rows_prefilled = 0
+        self.prefill_calls = 0
+        self.prefill_slots = 0
+        self.prefill_tokens = 0
         self.tokens_decoded = 0
         self.cache_positions = 0
-        self.rows_reset = 0
 
     @property
     def cache_c(self) -> Tuple[Array, ...]:
@@ -160,7 +163,6 @@ class TokenPlayer:
         either kind of state lies at or after its first position."""
         dones = np.asarray(dones, bool).reshape(-1)
         self.lengths[dones] = 0
-        self.rows_reset += int(dones.sum())
 
     def snapshot(self) -> Tuple[Tuple[Array, ...], ...]:
         """A copy of the state as it stands: what a rollout's continuing sequences start from in the update."""
@@ -191,6 +193,9 @@ class TokenPlayer:
             self.state, _ = self._prefill(self.params, self.state, idx, toks, n_prefix)
             self.lengths[chunk] = n_tokens[chunk] - 1
             self.rows_prefilled += len(chunk)
+            self.prefill_calls += 1
+            self.prefill_slots += toks.size
+            self.prefill_tokens += int(n_prefix.sum())
 
     def act(self, tokens: np.ndarray, n_tokens: np.ndarray, key: Array, counter: int):
         """One rollout step on the observation ``tokens [E, P]``, ``n_tokens
@@ -404,16 +409,21 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
     store = RolloutStore(rollout_steps)
     env_index = np.arange(num_envs, dtype=np.int32)[:, None]
     preempted = False
+    # An update's work lies in spans other than the two window spans (howto/telemetry.md): ``loop/head``,
+    # ``update/assemble`` and ``loop/tail`` around them and leaves inside them, which a window span joins with a
+    # few bindings of glue
+    counted = ("rows_prefilled", "prefill_calls", "prefill_slots", "prefill_tokens", "tokens_decoded", "cache_positions")
     for update in range(start_update, num_updates + 1):
-        telemetry_advance(policy_step)
-        if resil.preempt_requested():
-            last_checkpoint = policy_step
-            resil.emergency_checkpoint(ckpt_path_fn(policy_step), ckpt_state_fn(update - 1))
-            preempted = True
-            break
-        buf = store.begin(update)
-        snap = player.snapshot()
-        prefilled, decoded, attended, reset = player.rows_prefilled, player.tokens_decoded, player.cache_positions, player.rows_reset
+        with timer("loop/head"):
+            telemetry_advance(policy_step)
+            if resil.preempt_requested():
+                last_checkpoint = policy_step
+                resil.emergency_checkpoint(ckpt_path_fn(policy_step), ckpt_state_fn(update - 1))
+                preempted = True
+                break
+            buf = store.begin(update)
+            snap = player.snapshot()
+            before = {k: getattr(player, k) for k in counted}
         with timer("Time/env_interaction_time"):
             for t in range(rollout_steps):
                 policy_step += num_envs
@@ -440,15 +450,18 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
                                 aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
                                 print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep['r'][i]}")
 
-        wrapped = int((player.lengths >= (agent.core.sliding_window or np.inf)).sum())
-        local_data = buf.arrays()
-        # the value of the observation the rollout stopped at: one more decode on a copy of the carry, kept out of the cache
+        with timer("update/assemble"):
+            wrapped = int((player.lengths >= (agent.core.sliding_window or np.inf)).sum())
+            local_data = buf.arrays()
         with timer("Time/train_time"):
             with timer("train/dispatch"):
-                next_values = player.peek_values(obs) * (1.0 - local_data["dones"][-1])
-                returns, advantages = gae_fn(local_data["rewards"], local_data["values"], local_data["dones"], next_values)
-                local_data.update({"returns": np.asarray(returns), "advantages": np.asarray(advantages), "next_values": next_values})
-                seqs = token_sequences(local_data, seq_steps, num_envs, batch_size)
+                with timer("update/bootstrap"):
+                    # the value of the observation the rollout stopped at: one more decode on a copy of the carry, kept out of the cache
+                    next_values = player.peek_values(obs) * (1.0 - local_data["dones"][-1])
+                with timer("update/sequences"):
+                    returns, advantages = gae_fn(local_data["rewards"], local_data["values"], local_data["dones"], next_values)
+                    local_data.update({"returns": np.asarray(returns), "advantages": np.asarray(advantages), "next_values": next_values})
+                    seqs = token_sequences(local_data, seq_steps, num_envs, batch_size)
                 n_seq = seqs["mask"].shape[0]
                 pending = []
                 for _ in range(update_epochs):
@@ -461,27 +474,27 @@ def run_token_policy(fabric: Any, cfg: Dict[str, Any], envs: Any, state: Optiona
             with timer("train/block"):
                 # stacked on the host: an update of another count of gradient steps then compiles nothing
                 metrics = np.stack(jax.device_get(pending))
-        _report(metrics, aggregator if cfg.metric.log_level > 0 else None, core=agent.core,
-                rows_prefilled=player.rows_prefilled - prefilled, tokens_decoded=player.tokens_decoded - decoded,
-                cache_positions=player.cache_positions - attended, rows_reset=player.rows_reset - reset,
-                window_keys=update_epochs * window_keys(seqs, agent.core), ring_wrapped_rows=wrapped)  # fmt: skip
-
-        if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
-            logger.log_metrics(aggregator.compute(), policy_step)
-            aggregator.reset()
-            if not timer.disabled:
-                timer.reset()
-            last_log = policy_step
-        if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
-            update == num_updates and cfg.checkpoint.save_last
-        ):
-            last_checkpoint = policy_step
-            fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path_fn(policy_step), state=ckpt_state_fn(update))
+        with timer("loop/tail"):
+            _report(metrics, aggregator if cfg.metric.log_level > 0 else None, core=agent.core,
+                    window_keys=update_epochs * window_keys(seqs, agent.core), ring_wrapped_rows=wrapped,
+                    **{k: getattr(player, k) - v for k, v in before.items()})  # fmt: skip
+            if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or update == num_updates):
+                logger.log_metrics(aggregator.compute(), policy_step)
+                aggregator.reset()
+                if not timer.disabled:
+                    timer.reset()
+                last_log = policy_step
+            if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
+                update == num_updates and cfg.checkpoint.save_last
+            ):
+                last_checkpoint = policy_step
+                fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path_fn(policy_step), state=ckpt_state_fn(update))
     return preempted
 
 
-def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, rows_prefilled: int, tokens_decoded: int,
-            cache_positions: float, rows_reset: int, window_keys: int, ring_wrapped_rows: int) -> None:  # fmt: skip
+def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, rows_prefilled: int, prefill_calls: int,
+            prefill_slots: int, prefill_tokens: int, tokens_decoded: int, cache_positions: float, window_keys: int,
+            ring_wrapped_rows: int) -> None:  # fmt: skip
     """One update's losses into the aggregator and its counters into ``telemetry.jsonl``."""
     mean = dict(zip(METRICS, metrics.mean(0)))
     total = dict(zip(METRICS, metrics.sum(0)))
@@ -502,12 +515,15 @@ def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, 
         real_positions=float(total["real_positions"]),
         padded_positions=float(total["padded_positions"]),
         rows_prefilled=int(rows_prefilled),
+        # the rollout's ``seqpol_prefill`` executions, the slots they computed (``prefill_rows`` x the prompt's slots a
+        # call), and the prompts' tokens written among them: the useful share of a fixed-shape prefill
+        prefill_calls=int(prefill_calls),
+        prefill_slots=int(prefill_slots),
+        prefill_tokens=int(prefill_tokens),
         tokens_decoded=int(tokens_decoded),
         # the rows' lengths summed over the decodes: what each layer that keeps positions attended to (a window
         # layer's ring ``min(length, sliding_window)``; the mean over such layers where they differ)
         cache_positions=float(cache_positions),
-        # convolution states put back to nothing: a row's reset clears one in every convolution layer
-        conv_state_resets=int(rows_reset) * sum(core.operator(i) == seqpol.CONV for i in range(core.num_hidden_layers)),
         # over the update's real queries, the keys inside each one's window, summed over the window layers and the epochs
         window_keys=int(window_keys),
         # the pairs of a query and a key that the window layers' blocks scored, with the ring or without, over the

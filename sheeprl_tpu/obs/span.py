@@ -7,30 +7,39 @@ over this class) and, when run telemetry is configured, additionally:
 - wraps the block in ``jax.profiler.TraceAnnotation(name)`` so the section
   shows up by the same name in the XLA/Perfetto trace, and
 - emits one ``span`` JSON event per close to the per-process
-  ``telemetry.jsonl`` (name, t_start, t_mono_ns, dur, step, process_index,
-  attrs). ``t_mono_ns`` is ``time.monotonic_ns()`` at entry: the clock a
-  device trace is aligned to, which a wall-clock step cannot move.
+  ``telemetry.jsonl`` (name, parent, t_start, t_mono_ns, dur, step,
+  process_index, attrs). ``t_mono_ns`` is ``time.monotonic_ns()`` at entry:
+  the clock a device trace is aligned to, which a wall-clock step cannot
+  move. ``parent`` is the name of the innermost span open on the same thread
+  when this one began (``None`` at the top), so a reader takes a span's self
+  time as its duration less what its children cover.
 
-Spans nest: the Dreamer-V3 loop puts leaf spans (``player/get_actions``,
-``ring/add``, ``env/step``, ...; howto/telemetry.md has the vocabulary) inside
-its two window spans. A span never waits for the device: it times the host,
+Spans nest: the loops put leaf spans (``player/get_actions``, ``ring/add``,
+``env/step``, ...; howto/telemetry.md has the vocabulary) inside and beside
+their two window spans. A span never waits for the device: it times the host,
 and the device's own time is read from the profiler's trace by program name.
 
 With telemetry off the hot path is byte-for-byte the old timer plus a single
-module-global read, so ``metric.telemetry.enabled=False`` costs nothing.
+module-global read, so ``metric.telemetry.enabled=False`` costs nothing: the
+stack of open spans is kept only while telemetry is on.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 from contextlib import ContextDecorator
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from sheeprl_tpu.utils.metric import Metric, SumMetric, make_metric
 
 
 class TimerError(Exception):
     pass
+
+
+#: per thread, the spans open on it while telemetry is on, innermost last
+_open = threading.local()
 
 
 class span(ContextDecorator):
@@ -54,6 +63,8 @@ class span(ContextDecorator):
         self._wall_start: Optional[float] = None
         self._mono_start_ns: Optional[int] = None
         self._annotation = None
+        self._stack: Optional[List["span"]] = None
+        self._parent: Optional[str] = None
         if not span.disabled and name is not None and name not in span.timers:
             span.timers[name] = make_metric(metric) if metric is not None else SumMetric()
 
@@ -85,6 +96,10 @@ class span(ContextDecorator):
 
         tel = get_telemetry()
         if tel is not None:
+            stack = _open.__dict__.setdefault("stack", [])
+            self._parent = stack[-1].name if stack else None
+            stack.append(self)
+            self._stack = stack
             self._wall_start = time.time()
             self._mono_start_ns = time.monotonic_ns()
             self._annotation = tel.trace_annotation(self.name)
@@ -108,7 +123,11 @@ class span(ContextDecorator):
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
             self._annotation = None
+        if self._stack is not None:
+            self._stack.remove(self)  # the innermost, where spans close as they nest
+            self._stack = None
         if tel is not None and elapsed is not None:
-            tel.emit_span(self.name, self._wall_start, elapsed, self.attrs, t_mono_ns=self._mono_start_ns)
+            tel.emit_span(self.name, self._wall_start, elapsed, self.attrs, t_mono_ns=self._mono_start_ns, parent=self._parent)
         self._wall_start = None
         self._mono_start_ns = None
+        self._parent = None

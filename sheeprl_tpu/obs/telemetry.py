@@ -55,6 +55,8 @@ _FLUSH_EVERY_SECONDS = 5.0
 _ENV_STEP_RESERVOIR = 8192
 _FLIGHTREC_EVENTS = 256
 _TRACE_PATH_RESERVOIR = 8192
+#: ``emit_span``'s default: a span event from outside ``span`` states no parent
+_NO_PARENT = object()
 
 
 def _pct(values: list, q: float) -> Optional[float]:
@@ -299,12 +301,16 @@ class RunTelemetry:
         dur: float,
         attrs: Mapping[str, Any],
         t_mono_ns: Optional[int] = None,
+        parent: Any = _NO_PARENT,
     ) -> None:
         fields: Dict[str, Any] = {"t_start": t_start, "dur": dur}
         if t_mono_ns is not None:
             # the span's start on time.monotonic_ns(): the clock that env
             # stamps and a device trace share (t_start is the wall clock)
             fields["t_mono_ns"] = int(t_mono_ns)
+        if parent is not _NO_PARENT:
+            # the innermost span open on the same thread when this one began (None at the top)
+            fields["parent"] = parent
         if attrs:
             fields["attrs"] = dict(attrs)
         self.emit("span", name=name, **fields)

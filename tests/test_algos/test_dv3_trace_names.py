@@ -140,3 +140,66 @@ def test_the_rssm_scans_own_backward_stays_under_its_scope(lowered):
         assert "/transpose(jvp(dv3/wm/rssm_scan))/" in path, path
     assert any("/jvp(dv3/wm/rssm_scan)/jvp()/while" in p for p in paths), "the probed forward loop"
     assert all("dv3/" in p for p in paths if "jvp()" in p), "an inner gradient's op outside every scope"
+
+
+# --------------------------------------------------------------------------- #
+# the loop's spans: every turn's work lies in a span other than the two window spans
+# --------------------------------------------------------------------------- #
+
+WINDOW_SPANS = ("Time/env_interaction_time", "Time/train_time")
+#: span -> the parent it names (the loop's new spans)
+DV3_LOOP_SPANS = {"loop/head": None, "player/to_env": "Time/env_interaction_time", "train/plan": None, "train/keys": "Time/train_time",
+                  "train/queue_next": "Time/train_time", "loop/tail": None}  # fmt: skip
+
+
+def loop_iterations(events):
+    """``(wall, covered)`` seconds of each loop iteration, from a ``loop/head``
+    to the end of the ``loop/tail`` that follows it: its time, and the self
+    time (a span's duration less its children's, found by ``parent``:
+    ``perfbench/span_tree.py``) of every span inside it but the two window spans."""
+    from perfbench import span_tree
+
+    spans = span_tree.of_events(events)
+    own = span_tree.self_seconds(spans)
+    out = []
+    for head in (s for s in spans if s.name == "loop/head"):
+        tail = next((t for t in spans if t.name == "loop/tail" and t.start >= head.end), None)
+        if tail is not None:
+            covered = sum(o for s, o in zip(spans, own) if head.start <= s.start and s.end <= tail.end + 1e3 and s.name not in WINDOW_SPANS)
+            out.append(((tail.end - head.start) / 1e9, covered))
+    return out
+
+
+def test_the_loops_spans_name_their_parents_and_cover_each_turn(tmp_path, monkeypatch):
+    """A tiny run with telemetry on, on the device ring as the cells run it:
+    two random turns, then six that train (two gradient steps each). Every new
+    span is there under the parent it names, and the self time of the spans
+    but the two window spans covers the turns that train to within a few
+    percent (one of five may read less: a pause of the process can fall
+    between two spans)."""
+    import json
+    import os
+
+    from sheeprl_tpu.cli import run
+    from tests.test_algos.test_dreamer_v3 import dv3_args
+
+    monkeypatch.chdir(tmp_path)
+    args = [a for a in dv3_args(tmp_path) if a not in ("dry_run=True", "algo.learning_starts=0", "algo.run_test=True", "checkpoint.save_last=True")]
+    run(args + ["algo.total_steps=16", "algo.learning_starts=4", "algo.run_test=False", "checkpoint.save_last=False", "fabric.devices=1", "buffer.device=True",
+                "metric.telemetry.enabled=True", "metric.telemetry.poll_interval=0.0"])  # fmt: skip
+    (path,) = [os.path.join(root, f) for root, _, files in os.walk(tmp_path) for f in files if f == "telemetry.jsonl"]
+    events = [json.loads(line) for line in open(path) if line.strip()]
+    spans = [e for e in events if e["event"] == "span" and "t_mono_ns" in e]
+    for name, parent in DV3_LOOP_SPANS.items():
+        found = [e for e in spans if e["name"] == name]
+        assert found and all(e["parent"] == parent for e in found), (name, {e["parent"] for e in found})
+    assert len([e for e in spans if e["name"] == "loop/head"]) == len([e for e in spans if e["name"] == "loop/tail"]) == 8
+    assert {e["parent"] for e in spans if e["name"] in ("player/get_actions", "env/step")} == {"Time/env_interaction_time"}
+    assert {e["parent"] for e in spans if e["name"] == "ring/add"} <= {"Time/env_interaction_time", "loop/store_step"}  # the reset add
+    assert {e["parent"] for e in spans if e["name"] in ("replay/draw", "train/dispatch", "train/block")} == {"Time/train_time"}
+    turns = loop_iterations(events)
+    assert len(turns) == 8
+    # the turns that train, past the first's compiles: what no span covers is what the spans cost to open and close,
+    # some 10 to 80 us a span on a CPU shared with XLA's threads, against turns of 7 ms and more here
+    shares = [covered / wall for wall, covered in turns[-5:]]
+    assert sorted(shares)[1] > 0.9, shares

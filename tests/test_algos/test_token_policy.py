@@ -237,11 +237,13 @@ def _play(model, steps=12, prompt=(2, 6)):
     key = jax.random.PRNGKey(0)
     inputs = [[], [], []]  # every token each row's policy has been fed, episode by episode
     obs_tokens, n_tokens = np.zeros((3, prompt[1]), np.int32), np.zeros((3,), np.int32)
+    prefixes = []  # the tokens of each prompt that a prefill writes: all but its last
 
     def reset(row, n=None):
         n = int(rng.integers(prompt[0], prompt[1] + 1)) if n is None else n
         obs_tokens[row, :n], n_tokens[row] = rng.integers(0, VOCAB, n), n
         inputs[row] = [int(t) for t in obs_tokens[row, :n]]
+        prefixes.append(n - 1)
 
     for row in range(3):
         reset(row)
@@ -263,7 +265,9 @@ def _play(model, steps=12, prompt=(2, 6)):
             else:
                 obs_tokens[row, 0], n_tokens[row] = int(actions[row]), 1
                 inputs[row].append(int(actions[row]))
-    assert player.rows_prefilled == 4 and player.tokens_decoded == 3 * steps and player.rows_reset == 2
+    # the three first prompts in two calls of two rows, row 1's second in a third; row 2's second has no prefix
+    assert player.rows_prefilled == 4 and player.tokens_decoded == 3 * steps
+    assert (player.prefill_calls, player.prefill_slots, player.prefill_tokens) == (3, 3 * 2 * prompt[1], sum(prefixes))
     return worst_logits, worst_values
 
 
@@ -785,6 +789,41 @@ def test_the_other_recipes_train_through_the_same_main(tmp_path, monkeypatch, ca
     assert seen == ["seqpol_prefill", "seqpol_decode"]
     out = capsys.readouterr().out
     assert sum("reward_env_" in line for line in out.splitlines()) > 50
+
+
+def test_the_loops_spans_cover_each_update_and_count_the_prefill(tmp_path, monkeypatch):
+    """``exp=ppo_recurrent_mellum2_12b`` at the tiny size with telemetry on, four updates: every new span under the
+    parent it names, the self time of the spans but the two window spans covering each update to within a few percent
+    (one of the three timed may read less: a pause of the process can fall between two spans), and the counters of
+    ``seqpol/update`` as the prefill's shape bounds them (2 rows of the observation's 2 slots a call; prompts of 1 or 2
+    tokens)."""
+    import json
+
+    from tests.test_algos.test_dv3_trace_names import loop_iterations
+
+    monkeypatch.chdir(tmp_path)
+    run([*tiny_args(tmp_path, "ppo_recurrent_mellum2_12b", MELLUM2_SIZES), "algo.total_steps=512", "metric.telemetry.enabled=True",
+         "metric.telemetry.poll_interval=0.0"])  # fmt: skip
+    (path,) = [os.path.join(root, f) for root, _, files in os.walk(tmp_path) for f in files if f == "telemetry.jsonl"]
+    events = [json.loads(line) for line in open(path) if line.strip()]
+    spans = [e for e in events if e["event"] == "span" and "t_mono_ns" in e]
+    parents = {"loop/head": None, "update/assemble": None, "update/bootstrap": "train/dispatch", "update/sequences": "train/dispatch",
+               "train/dispatch": "Time/train_time", "train/block": "Time/train_time", "loop/tail": None, "player/prefill": "Time/env_interaction_time"}  # fmt: skip
+    for name, parent in parents.items():
+        found = [e for e in spans if e["name"] == name]
+        assert found and {e["parent"] for e in found} == {parent}, (name, {e["parent"] for e in found})
+        assert name == "player/prefill" or len(found) == 4, (name, len(found))
+    updates = loop_iterations(events)
+    assert len(updates) == 4
+    shares = [covered / wall for wall, covered in updates[1:]]  # past the first update's compiles
+    assert sorted(shares)[1] > 0.9, shares
+    counters = [e for e in events if e["event"] == "counters" and e["name"] == "seqpol/update"]
+    assert len(counters) == 4 and not any("conv_state_resets" in e for e in counters)
+    for e in counters:
+        assert e["prefill_slots"] == e["prefill_calls"] * 2 * 2
+        assert e["prefill_calls"] <= e["rows_prefilled"] <= 2 * e["prefill_calls"] and e["rows_prefilled"] > 0
+        assert e["rows_prefilled"] == e["prefill_tokens"] <= e["prefill_slots"]  # a prompt of 2 tokens prefills 1
+        assert 0 < e["window_keys"] <= e["window_pairs_scored"]
 
 
 def test_the_lstm_recipe_leaves_with_77_on_sigterm(tmp_path, monkeypatch):
