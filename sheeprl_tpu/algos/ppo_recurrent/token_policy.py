@@ -21,12 +21,13 @@ What a sequence core supplies, for either kind:
   ``build_sequences`` zero on the padding.
 
 Three named programs: ``seqpol_prefill`` (the prompts of the rows that reset,
-``prefill_rows`` at a time, so its cost follows the resets and not
-``num_envs``), ``seqpol_decode`` (one token for all rows through the state:
-sampling, log-probability, value) and ``seqpol_train_step`` (one gradient step
+at most ``prefill_rows`` a call, each call the smallest rung of
+:attr:`TokenPlayer.rungs` that holds its rows, so its cost follows the resets
+and not ``num_envs``), ``seqpol_decode`` (one token for all rows through the
+state: sampling, log-probability, value) and ``seqpol_train_step`` (one gradient step
 on one minibatch of ``per_rank_batch_size`` sequences; an update dispatches as
 many as its rollout's sequences fill, ``update_epochs`` times). Every shape is
-static.
+static, and the player compiles each of its prefill's sizes when it is built.
 """
 
 from __future__ import annotations
@@ -141,6 +142,13 @@ class TokenPlayer:
                            for layer in seqpol.state_shapes(core, self.num_envs))  # fmt: skip
         self.lengths = np.zeros((self.num_envs,), np.int32)
         self.last_logits: Optional[Array] = None
+        #: the rows of one ``seqpol_prefill`` call: the powers of two under ``prefill_rows``, then ``prefill_rows``
+        self.rungs = tuple(1 << k for k in range(self.prefill_rows.bit_length()) if 1 << k < self.prefill_rows) + (self.prefill_rows,)
+        # every rung compiled now, with every row at ``num_envs`` so that each write is dropped: the first step with
+        # that many resets may come at any time
+        for rung in self.rungs:
+            self.state, _ = self._prefill(self.params, self.state, np.full((rung,), self.num_envs, np.int32),
+                                          np.zeros((rung, agent.prompt_max), np.int32), np.zeros((rung,), np.int32))  # fmt: skip
         #: entries of each layer that keeps positions (a cache: ``context``; a window layer's ring: ``sliding_window``) -> how many such layers
         self._cache_sizes = Counter(layer[0][1] for i, layer in enumerate(seqpol.state_shapes(core, 1)) if seqpol.OPERATORS[core.operator(i)].key == "attn")
         #: counters since the start: rows prefilled, and the ``seqpol_prefill`` calls that did it, the slots those calls
@@ -180,15 +188,19 @@ class TokenPlayer:
 
     def prefill(self, tokens: np.ndarray, n_tokens: np.ndarray) -> None:
         """The rows whose observation holds more than one token (a prompt) get
-        all but its last written into their state, ``prefill_rows`` a call."""
+        all but its last written into their state: ``prefill_rows`` rows a
+        call while more are left, then one call of the smallest rung that
+        holds the rest. A call computes its rung's rows x the observation's
+        slots, whatever the rows and prompts (``prefill_slots``)."""
         rows = np.nonzero(n_tokens > 1)[0]
         for at in range(0, len(rows), self.prefill_rows):
             chunk = rows[at : at + self.prefill_rows]
-            idx = np.full((self.prefill_rows,), self.num_envs, np.int32)
+            rung = next(r for r in self.rungs if r >= len(chunk))
+            idx = np.full((rung,), self.num_envs, np.int32)
             idx[: len(chunk)] = chunk
-            toks = np.zeros((self.prefill_rows, tokens.shape[1]), np.int32)
+            toks = np.zeros((rung, tokens.shape[1]), np.int32)
             toks[: len(chunk)] = tokens[chunk]
-            n_prefix = np.zeros((self.prefill_rows,), np.int32)
+            n_prefix = np.zeros((rung,), np.int32)
             n_prefix[: len(chunk)] = n_tokens[chunk] - 1
             self.state, _ = self._prefill(self.params, self.state, idx, toks, n_prefix)
             self.lengths[chunk] = n_tokens[chunk] - 1
@@ -515,8 +527,8 @@ def _report(metrics: np.ndarray, aggregator: Any, *, core: seqpol.SeqPolConfig, 
         real_positions=float(total["real_positions"]),
         padded_positions=float(total["padded_positions"]),
         rows_prefilled=int(rows_prefilled),
-        # the rollout's ``seqpol_prefill`` executions, the slots they computed (``prefill_rows`` x the prompt's slots a
-        # call), and the prompts' tokens written among them: the useful share of a fixed-shape prefill
+        # the rollout's ``seqpol_prefill`` executions, the slots they computed (a call's rung rows x the observation's
+        # slots), and the prompts' tokens written among them: the useful share of the slots a prefill computes
         prefill_calls=int(prefill_calls),
         prefill_slots=int(prefill_slots),
         prefill_tokens=int(prefill_tokens),

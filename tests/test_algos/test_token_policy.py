@@ -265,9 +265,10 @@ def _play(model, steps=12, prompt=(2, 6)):
             else:
                 obs_tokens[row, 0], n_tokens[row] = int(actions[row]), 1
                 inputs[row].append(int(actions[row]))
-    # the three first prompts in two calls of two rows, row 1's second in a third; row 2's second has no prefix
+    # the three first prompts in a call of two rows and a call of one, row 1's second in a third of one; row 2's
+    # second has no prefix
     assert player.rows_prefilled == 4 and player.tokens_decoded == 3 * steps
-    assert (player.prefill_calls, player.prefill_slots, player.prefill_tokens) == (3, 3 * 2 * prompt[1], sum(prefixes))
+    assert (player.prefill_calls, player.prefill_slots, player.prefill_tokens) == (3, 4 * prompt[1], sum(prefixes))
     return worst_logits, worst_values
 
 
@@ -275,6 +276,49 @@ def _play(model, steps=12, prompt=(2, 6)):
 def test_prefill_then_decode_across_a_reset_equals_the_reference(model):
     worst_logits, worst_values = _play(model)
     assert worst_logits < TOL and worst_values < TOL
+
+
+@pytest.mark.parametrize("resetting", [1, 2, 3, 5, 8])
+def test_a_prefill_sized_to_the_rows_that_reset_leaves_what_one_full_call_leaves(model, resetting):
+    """With ``prefill_rows`` 8, ``resetting`` of 10 rows that hold an episode each take a new prompt: the calls sized to
+    them leave every array of the state (a latent or a key-value cache, a ring, a convolution state) as one call of 8
+    rows leaves it, the next decode's logits with it, and compile nothing after the player was built."""
+    from sheeprl_tpu.obs.recompile import CompileWatchdog
+
+    rng = np.random.default_rng(resetting)
+    E, P = 10, 6
+    agent = token_policy.TokenPolicy(model.core(), prompt_max=P, dtype=jnp.float32)
+    player = token_policy.TokenPlayer(agent, model.weights, num_envs=E, prefill_rows=8)
+    assert player.rungs == (1, 2, 4, 8)
+    player.snapshot()  # compiled here: the loop takes its first at an update's head
+    compiled = []
+    dog = CompileWatchdog(lambda kind, **fields: compiled.append(fields.get("name")))
+    dog.start()
+    try:
+        player.prefill(rng.integers(0, VOCAB, (E, P)).astype(np.int32), rng.integers(2, P + 1, E).astype(np.int32))  # 8 rows, then 2
+        rows = np.sort(rng.choice(E, resetting, replace=False))
+        tokens, n_tokens = rng.integers(0, VOCAB, (E, P)).astype(np.int32), np.ones((E,), np.int32)
+        n_tokens[rows] = rng.integers(2, P + 1, resetting)
+        before = player.snapshot()
+        calls = player.prefill_calls
+        player.prefill(tokens, n_tokens)
+    finally:
+        dog.stop()
+    assert dog.compiles == 0, compiled
+    rung = next(r for r in player.rungs if r >= resetting)
+    assert (player.prefill_calls - calls, player.rows_prefilled) == (1, E + resetting)
+    assert player.prefill_slots == (8 + 2 + rung) * P
+    idx = np.full((8,), E, np.int32)
+    idx[:resetting] = rows
+    toks, n_prefix = np.zeros((8, P), np.int32), np.zeros((8,), np.int32)
+    toks[:resetting], n_prefix[:resetting] = tokens[rows], n_tokens[rows] - 1
+    full, _ = player._prefill(player.params, before, idx, toks, n_prefix)
+    for sized_layer, full_layer in zip(player.state, full):
+        for sized, whole in zip(sized_layer, full_layer):
+            assert gap(sized, whole) < TOL
+    current = tokens[np.arange(E), n_tokens - 1]
+    outs = [player._decode(player.params, state, current, player.lengths.copy(), jax.random.PRNGKey(0), np.uint32(0)) for state in (player.snapshot(), full)]
+    assert gap(outs[0][3], outs[1][3]) < TOL
 
 
 def test_a_prompt_longer_than_the_window_is_prefilled_then_decoded():
@@ -795,8 +839,8 @@ def test_the_loops_spans_cover_each_update_and_count_the_prefill(tmp_path, monke
     """``exp=ppo_recurrent_mellum2_12b`` at the tiny size with telemetry on, four updates: every new span under the
     parent it names, the self time of the spans but the two window spans covering each update to within a few percent
     (one of the three timed may read less: a pause of the process can fall between two spans), and the counters of
-    ``seqpol/update`` as the prefill's shape bounds them (2 rows of the observation's 2 slots a call; prompts of 1 or 2
-    tokens)."""
+    ``seqpol/update`` as the prefill's shape bounds them (calls of 2 rows, then one of 1 for an odd one left, each row
+    the observation's 2 slots; prompts of 1 or 2 tokens)."""
     import json
 
     from tests.test_algos.test_dv3_trace_names import loop_iterations
@@ -820,7 +864,7 @@ def test_the_loops_spans_cover_each_update_and_count_the_prefill(tmp_path, monke
     counters = [e for e in events if e["event"] == "counters" and e["name"] == "seqpol/update"]
     assert len(counters) == 4 and not any("conv_state_resets" in e for e in counters)
     for e in counters:
-        assert e["prefill_slots"] == e["prefill_calls"] * 2 * 2
+        assert e["prefill_slots"] == e["rows_prefilled"] * 2  # with ``prefill_rows`` 2 every rung is full
         assert e["prefill_calls"] <= e["rows_prefilled"] <= 2 * e["prefill_calls"] and e["rows_prefilled"] > 0
         assert e["rows_prefilled"] == e["prefill_tokens"] <= e["prefill_slots"]  # a prompt of 2 tokens prefills 1
         assert 0 < e["window_keys"] <= e["window_pairs_scored"]
